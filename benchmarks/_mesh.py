@@ -61,8 +61,10 @@ def run_with_spawn_retry(cmd, *, attempts: int = 3, backoff_s: float = 0.5,
 def forced_device_env(n: int, base: dict = None) -> dict:
     """A copy of ``base`` (default ``os.environ``) whose ``XLA_FLAGS``
     forces an ``n``-device CPU platform — for a *child* process only; the
-    caller's environment is never touched."""
+    caller's environment is never touched.  ``JAX_PLATFORMS=cpu`` keeps
+    the child off an attached TPU, which belongs to one process."""
     env = dict(os.environ if base is None else base)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = \

@@ -17,7 +17,7 @@ from repro.configs import get_reduced
 def main():
     n = len(jax.devices())
     model_par = min(4, n)
-    from repro.launch.mesh import axis_types_kw, mesh_context
+    from repro.launch.mesh import axis_types_kw
     mesh = jax.make_mesh((n // model_par, model_par), ("data", "model"),
                          **axis_types_kw(2))
     print(f"devices={n}, mesh=({n // model_par}×{model_par})")
@@ -27,7 +27,7 @@ def main():
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.standard_normal((V, D)), jnp.float32)
     ids = jnp.asarray(rng.integers(0, V, (B, S)), jnp.int32)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         tbl = jax.device_put(table, NamedSharding(mesh, P("model", None)))
         emb = ee.lookup(tbl, ids, mesh=mesh, vocab_axis="model",
                         strategy="masked_psum", data_axes=("data",))

@@ -53,7 +53,7 @@ def main():
 
     plan = make_plan(res3)
     print(f"\nTPU KernelPlan: {plan}")
-    out = run_pallas(res3, inputs, interpret=True)
+    out = run_pallas(res3, inputs)
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-4)
     print("Pallas DAE kernel output matches the reference ✓")
 

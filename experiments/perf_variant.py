@@ -24,7 +24,7 @@ from repro.launch.dryrun import OUT_DIR  # noqa: F401  (sets XLA_FLAGS)
 import jax
 
 from repro.configs import get_config
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_bundle
 from repro.roofline.analysis import collective_bytes_from_hlo
 
@@ -48,7 +48,7 @@ def main():
         cfg = dataclasses.replace(cfg, **overrides)
     mesh = make_production_mesh()
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         b = build_bundle(cfg, mesh, shape, remat="none", cost_mode=True)
         lo = jax.jit(b.fn, in_shardings=b.in_shardings).lower(*b.args)
         ca = lo.cost_analysis() or {}

@@ -53,7 +53,7 @@ from .cost_model import FusionBudget
 from .pipeline import ProgramCompileResult, compile_cache_key
 
 __all__ = ["AotCache", "artifact_meta", "artifact_stats",
-           "aot_supported", "load_artifact", "reset_artifact_stats",
+           "load_artifact", "reset_artifact_stats",
            "runtime_fingerprint", "save_artifact"]
 
 #: bump on any incompatible change to the on-disk layout
@@ -100,27 +100,48 @@ def runtime_fingerprint() -> dict:
             "device_count": len(devs)}
 
 
-def aot_supported() -> bool:
-    """Whether the installed jax can (de)serialize compiled executables.
-    When False the artifact still carries the compile payload — boot saves
-    the PassManager, not the XLA compile (graceful trace-on-load)."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
 # ---------------------------------------------------------------------------
 # AotCache: per-kernel-call memo of lowered executables
 # ---------------------------------------------------------------------------
+
+def _placement(a) -> str:
+    """Picklable identity of an argument's sharding ('' for a host array,
+    which lands on the default device)."""
+    import jax
+    s = getattr(a, "sharding", None)
+    if s is None:
+        return ""
+    if isinstance(s, jax.sharding.NamedSharding):
+        return (f"named{s.mesh.axis_names}{s.mesh.device_ids.tolist()}"
+                f"{s.spec}")
+    return f"{type(s).__name__}{sorted(d.id for d in s.device_set)}"
+
+
+def _execution_devices(leaves) -> list:
+    """The devices, in mesh order, that an executable running these
+    arguments spans: a mesh's devices, else the devices the arguments sit
+    on (host arrays: the default device).  A deserialized executable must
+    be loaded onto exactly these — by default it spans every device."""
+    import jax
+    shardings = [s for s in (getattr(a, "sharding", None) for a in leaves)
+                 if s is not None]
+    for s in shardings:
+        if isinstance(s, jax.sharding.NamedSharding):
+            return list(s.mesh.devices.flat)
+    if shardings:
+        return sorted(set().union(*(s.device_set for s in shardings)),
+                      key=lambda d: d.id)
+    return jax.devices()[:1]
+
 
 class AotCache:
     """Memoizes ``fn.lower(*args, **static).compile()`` per call-site key
     and hydrates lazily from serialized payloads loaded off an artifact.
 
     A key is (kernel name, sorted static kwargs, abstract signature of
-    the array arguments) — exactly what jit specializes on — so the cache
+    the array arguments: shape, dtype and placement) — exactly what jit
+    specializes on; an executable compiled for one sharding cannot run
+    arguments laid out by another — so the cache
     holds one executable per kernel specialization, the same population a
     warm in-process jit cache would.  ``payloads()`` exports every held
     executable back to serialized form for :func:`save_artifact`.
@@ -138,7 +159,8 @@ class AotCache:
         return (str(treedef),
                 tuple((tuple(np.shape(a)),
                        np.dtype(getattr(a, "dtype",
-                                        np.asarray(a).dtype)).str)
+                                        np.asarray(a).dtype)).str,
+                       _placement(a))
                       for a in leaves))
 
     def call(self, name: str, fn, static: dict, *args, **kwargs):
@@ -149,7 +171,7 @@ class AotCache:
                self._sig(args, kwargs))
         exe = self._compiled.get(key)
         if exe is None:
-            exe = self._hydrate(key)
+            exe = self._hydrate(key, args, kwargs)
         if exe is None:
             exe = fn.lower(*args, **kwargs, **static).compile()
             self._compiled[key] = exe
@@ -159,13 +181,17 @@ class AotCache:
             self.stats["hits"] += 1
         return exe(*args, **kwargs)
 
-    def _hydrate(self, key):
+    def _hydrate(self, key, args: tuple, kwargs: dict):
         blob = self._blobs.get(key)
         if blob is None:
             return None
         try:
+            import jax
             from jax.experimental import serialize_executable as se
-            exe = se.deserialize_and_load(*pickle.loads(blob))
+            devices = _execution_devices(jax.tree_util.tree_leaves(
+                (args, kwargs)))
+            exe = se.deserialize_and_load(*pickle.loads(blob),
+                                          execution_devices=devices)
         except Exception:   # noqa: BLE001 — any skew → live compile
             self.stats["fallbacks"] += 1
             _STATS["aot_fallbacks"] += 1
@@ -181,8 +207,6 @@ class AotCache:
         blobs) for :func:`save_artifact`.  Unserializable executables are
         skipped — the artifact stays loadable, those keys re-trace."""
         out = dict(self._blobs)
-        if not aot_supported():
-            return out
         from jax.experimental import serialize_executable as se
         for key, exe in self._compiled.items():
             if key in out:
@@ -200,11 +224,11 @@ class AotCache:
 
 def artifact_meta(program, *, opt_level: str, vlen: int = 128,
                   budget: Optional[FusionBudget] = None, hot_rows=None,
-                  backend: str = "pallas", interpret=None) -> dict:
+                  backend: str = "pallas") -> dict:
     """The identity an artifact is saved under and validated against at
-    load: the compile-cache key rendered JSON-stable.  ``backend`` and
-    ``interpret`` are informational — the compile payload is
-    backend-agnostic IR; AOT blobs self-select by their call keys."""
+    load: the compile-cache key rendered JSON-stable.  ``backend`` is
+    informational — the compile payload is backend-agnostic IR; AOT blobs
+    self-select by their call keys."""
     budget = budget or FusionBudget()
     sig = hashlib.sha256(repr(program.signature()).encode()).hexdigest()
     return {"identity": {"signature_sha": sig,
@@ -213,7 +237,6 @@ def artifact_meta(program, *, opt_level: str, vlen: int = 128,
                          "budget": repr(budget),
                          "hot_spec": _jsonable(canonical_hot(hot_rows))},
             "backend": backend,
-            "interpret": None if interpret is None else bool(interpret),
             "program": program.name}
 
 

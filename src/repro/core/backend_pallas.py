@@ -82,7 +82,7 @@ def _run(aot, name, fn, static: dict, *args, **kw):
     return aot.call(name, fn, static, *args, **kw)
 
 
-def execute(res: CompileResult, inputs: dict, interpret: bool = True,
+def execute(res: CompileResult, inputs: dict,
             max_lookups: Optional[int] = None, aot=None):
     """Run the compiled op through the Pallas DAE kernels.
 
@@ -94,7 +94,7 @@ def execute(res: CompileResult, inputs: dict, interpret: bool = True,
     """
     op = res.op
     plan = make_plan(res)
-    interp = kops.default_interpret() if interpret is None else bool(interpret)
+    interp = kops.default_interpret()
     if op.kind == "gather":
         assert plan.store_stream or opt_level_index(res.opt_level) < 3
         idxs = jnp.asarray(inputs["idxs"])
@@ -137,8 +137,7 @@ def execute(res: CompileResult, inputs: dict, interpret: bool = True,
                 seg_base=seg_base)
 
 
-def execute_program(pres: ProgramCompileResult, inputs: dict,
-                    interpret: bool = True) -> dict:
+def execute_program(pres: ProgramCompileResult, inputs: dict) -> dict:
     """Run a compiled program on the Pallas backend.
 
     ``inputs`` maps op name -> concrete inputs.  Fused units execute ONE
@@ -150,11 +149,9 @@ def execute_program(pres: ProgramCompileResult, inputs: dict,
     for unit in pres.units:
         if unit.group is None:
             outs[unit.names[0]] = execute(unit.result,
-                                          inputs[unit.names[0]],
-                                          interpret=interpret)
+                                          inputs[unit.names[0]])
         else:
-            fused = execute(unit.result, fuse_inputs(unit.group, inputs),
-                            interpret=interpret)
+            fused = execute(unit.result, fuse_inputs(unit.group, inputs))
             outs.update(split_outputs(unit.group, fused))
     return outs
 

@@ -36,9 +36,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from .jax_compat import shard_map
 from .ops import EmbeddingOp, EmbeddingProgram, single_op_program
 
 

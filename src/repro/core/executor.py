@@ -316,8 +316,7 @@ class ProgramExecutor:
     differs (the jax backend's reference kernels take host CSR streams).
     """
 
-    def __init__(self, compiled: ProgramCompileResult,
-                 interpret: Optional[bool] = None, depth: int = 2,
+    def __init__(self, compiled: ProgramCompileResult, depth: int = 2,
                  backend: str = "pallas", mesh=None,
                  shard_axis: str = "model", hot_rows=None,
                  exchange: Optional[str] = None,
@@ -336,8 +335,8 @@ class ProgramExecutor:
             assert service_pool is not None, \
                 "service='disagg' requires a service_pool"
         self.compiled = compiled
-        self.interpret = (kops.default_interpret() if interpret is None
-                          else interpret)
+        # Pallas kernels run compiled on a TPU and interpreted elsewhere
+        self.interpret = kops.default_interpret()
         self.depth = depth
         self.backend = backend
         self.shards = sp.shard_count(mesh, shard_axis)
@@ -912,8 +911,7 @@ class ProgramExecutor:
         :mod:`repro.core.artifact`)."""
         if self.backend == "jax":
             return bj.execute(u.res.op, ins, aot=aot)
-        return bp.execute(u.res, ins, interpret=self.interpret,
-                          max_lookups=ml, aot=aot)
+        return bp.execute(u.res, ins, max_lookups=ml, aot=aot)
 
     def _txn_defer(self, outs: dict, dev: dict, run) -> None:
         """Stage a gather-kind unit's per-step host arrays on the wave's
@@ -1097,7 +1095,6 @@ class ProgramExecutor:
                 self.compiled.program, host,
                 opt_level=self.compiled.opt_level, vlen=self.compiled.vlen,
                 backend=self.backend, index_policy=self.index_policy,
-                interpret=self.interpret,
                 hot_spec={n: tuple(int(i) for i in v)
                           for n, v in self._svc_hot.items()} or None)
             self.stats["table_stacks"] += 1
@@ -1686,7 +1683,7 @@ _EXECUTOR_CACHE = BoundedLru(16)
 
 
 def executor_for(program: EmbeddingProgram, opt_level: str = "O3",
-                 vlen: int = 128, interpret: Optional[bool] = None,
+                 vlen: int = 128,
                  budget: Optional[FusionBudget] = None,
                  depth: int = 2, backend: str = "pallas",
                  mesh=None, shard_axis: str = "model",
@@ -1742,7 +1739,6 @@ def executor_for(program: EmbeddingProgram, opt_level: str = "O3",
     part of the executor-cache key — the artifact changes where a compile
     comes from, never what it computes."""
     # canonicalize defaults so explicit-default calls hit the same entry
-    interpret = kops.default_interpret() if interpret is None else interpret
     shards = sp.shard_count(mesh, shard_axis)
     # disaggregated clients keep their hot-slab spec even on one shard
     # (it's the local-serving slab, not the sharded hot/cold plan) — and
@@ -1768,7 +1764,7 @@ def executor_for(program: EmbeddingProgram, opt_level: str = "O3",
     if budget.shards != shards:
         budget = dataclasses.replace(budget, shards=shards)
     hot_spec = ap.canonical_hot(hot_rows)
-    key = (program.signature(), opt_level, vlen, interpret, budget, depth,
+    key = (program.signature(), opt_level, vlen, budget, depth,
            backend, mesh, shard_axis if mesh is not None else None,
            hot_spec, exchange, bool(replicate_outputs), index_policy,
            service, degrade_policy if service == "disagg" else None,
@@ -1785,7 +1781,7 @@ def executor_for(program: EmbeddingProgram, opt_level: str = "O3",
         from . import artifact as art
         ameta = art.artifact_meta(program, opt_level=opt_level, vlen=vlen,
                                   budget=budget, hot_rows=hot_rows,
-                                  backend=backend, interpret=interpret)
+                                  backend=backend)
         loaded = art.load_artifact(artifact_dir, ameta)
         if loaded is not None:
             compiled, payloads = loaded
@@ -1801,7 +1797,7 @@ def executor_for(program: EmbeddingProgram, opt_level: str = "O3",
     if compiled is None:
         compiled = compile_program(program, opt_level, vlen=vlen,
                                    budget=budget, hot_rows=hot_rows)
-    ex = ProgramExecutor(compiled, interpret=interpret, depth=depth,
+    ex = ProgramExecutor(compiled, depth=depth,
                          backend=backend, mesh=mesh, shard_axis=shard_axis,
                          hot_rows=hot_rows if shards > 1 else service_hot,
                          exchange=exchange,

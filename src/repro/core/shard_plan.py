@@ -56,13 +56,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels import ops as kops
 from ..launch.sharding import (leading_axis_sharding, replicated_sharding,
                                table_row_sharding)
 from .access_plan import AccessPlan
-from .jax_compat import shard_map
 
 _ADD_IDENT = {"add": 0.0, "max": -np.inf, "min": np.inf}
 
@@ -85,27 +85,40 @@ def shard_stack_tables(parts: list, plan: AccessPlan, mesh,
     """Device-side sharded stacking of one fused unit per its AccessPlan:
     each slot's cold tail is striped over the shards (ceil-split, padded),
     its hot slab is replicated into every shard's local table, and the
-    ``(S·L·blk, E)`` result is placed row-sharded over ``axis`` — each
-    device materializes only its own ``(L·blk, E)`` slice."""
-    s, blk = plan.shards, plan.blk
-    cold_stripes, hot_stripes = [], []
+    ``(S·L·blk, E)`` result is placed row-sharded over ``axis``.  Shard
+    ``k``'s local table is built and placed one shard at a time, so no
+    device ever holds the whole stack (a table set sized to fill the
+    mesh's memory does not fit one device)."""
+    parts = [jnp.asarray(p) for p in parts]
+    local = plan.local_rows * plan.blk
+    shape = (plan.shards * local, parts[0].shape[1])
+    sharding = table_row_sharding(mesh, axis)
+    owners = {}                     # shard -> devices holding its rows
+    for d, idx in sharding.addressable_devices_indices_map(shape).items():
+        owners.setdefault((idx[0].start or 0) // local, []).append(d)
+    arrays = []
+    for k, devices in sorted(owners.items()):
+        block = _local_table(parts, plan, k)
+        arrays += [jax.device_put(block, d) for d in devices]
+    return jax.make_array_from_single_device_arrays(shape, sharding, arrays)
+
+
+def _local_table(parts: list, plan: AccessPlan, k: int) -> jax.Array:
+    """Shard ``k``'s ``(L·blk, E)`` table: every slot's ``k``-th cold
+    stripe (padded to the slot's capacity), then every hot slab."""
+    cold, hot = [], []
     for slot, p in zip(plan.slots, parts):
-        p = jnp.asarray(p)
-        emb = p.shape[1]
+        rows = slot.cap * plan.blk
         if slot.hot_rows:
-            cold = jnp.take(p, plan.phys_rows(slot.cold_ids), axis=0)
-            hot = jnp.take(p, plan.phys_rows(slot.hot_ids), axis=0)
+            ids = slot.cold_ids[k * slot.cap:(k + 1) * slot.cap]
+            stripe = jnp.take(p, plan.phys_rows(ids), axis=0)
+            hot.append(jnp.take(p, plan.phys_rows(slot.hot_ids), axis=0))
         else:
-            cold, hot = p, None
-        pad = s * slot.cap * blk - cold.shape[0]
-        if pad:
-            cold = jnp.pad(cold, ((0, pad), (0, 0)))
-        cold_stripes.append(cold.reshape(s, slot.cap * blk, emb))
-        if hot is not None:
-            hot_stripes.append(jnp.broadcast_to(hot[None], (s,) + hot.shape))
-    glob = jnp.concatenate(cold_stripes + hot_stripes, axis=1).reshape(
-        s * plan.local_rows * blk, cold_stripes[0].shape[-1])
-    return jax.device_put(glob, table_row_sharding(mesh, axis))
+            stripe = p[k * rows:(k + 1) * rows]
+        if stripe.shape[0] < rows:
+            stripe = jnp.pad(stripe, ((0, rows - stripe.shape[0]), (0, 0)))
+        cold.append(stripe)
+    return jnp.concatenate(cold + hot)
 
 
 def compute_spill(pair_counts: np.ndarray, max_fraction: float,

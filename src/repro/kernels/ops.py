@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernel layer.
 
-`interpret` defaults to True on CPU hosts (this container) and False when a
-real TPU backend is present — the kernels are *targets* for TPU v5e and
-*validated* under the Pallas interpreter.
+Kernels run compiled for the chip when JAX's backend is a TPU and under the
+Pallas interpreter everywhere else (``default_interpret``); an explicit
+``interpret`` is for tests that pin the interpreter.
 """
 from __future__ import annotations
 

@@ -28,8 +28,9 @@ from pathlib import Path
 import jax
 
 from ..configs import get_config, list_archs
-from ..roofline.analysis import collective_bytes_from_hlo, roofline_terms
-from .mesh import make_production_mesh, mesh_context
+from ..roofline.analysis import (TARGET_KIND, collective_bytes_from_hlo,
+                                 roofline_terms)
+from .mesh import make_production_mesh
 from .steps import SHAPES, build_bundle, shape_applicable
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -65,7 +66,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, remat: str = "dots",
     t0 = time.time()
     try:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multipod"))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             bundle = build_bundle(cfg, mesh, shape, remat=remat)
             jitted = jax.jit(bundle.fn, in_shardings=bundle.in_shardings)
             lowered = jitted.lower(*bundle.args)
@@ -135,7 +136,8 @@ def run_cell(arch: str, shape: str, mesh_kind: str, remat: str = "dots",
                     flops=rec["flops_per_device"],
                     bytes_accessed=rec["bytes_per_device"],
                     collective_bytes=rec["coll_bytes_per_device"],
-                    n_chips=1)  # all quantities are per-device already
+                    n_chips=1,  # all quantities are per-device already
+                    device_kind=TARGET_KIND)
             rec["status"] = "ok"
     except Exception as e:  # noqa: BLE001 — record the failure, keep sweeping
         rec["status"] = "error"

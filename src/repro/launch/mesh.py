@@ -11,18 +11,9 @@ import jax
 
 
 def axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh`` across jax versions:
-    ``jax.sharding.AxisType`` only exists from jax 0.5 on; older versions
-    already default to the Auto semantics we want, so omit the kwarg."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n_axes}
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` where it exists
-    (jax ≥ 0.6); the Mesh object itself is the context manager before."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """``axis_types=`` kwarg for ``jax.make_mesh``: every axis Auto (the
+    compiler propagates shardings; no explicit-sharding typing)."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,6 +27,7 @@ from ..configs import get_config, get_reduced
 from ..models import LM
 from ..runtime.faults import FaultInjector, FaultSpec
 from ..runtime.server import DecodeServer, Request
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -86,6 +87,7 @@ def main():
                     help="1-based call ordinals of the site to fire at")
     ap.add_argument("--chaos-seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     lm = LM(cfg)

@@ -19,6 +19,7 @@ from ..configs import get_config, get_reduced
 from ..data.pipeline import DataConfig, SyntheticTokens
 from ..models import LM, ShardCtx
 from ..runtime.trainer import Trainer, TrainerConfig, run_supervised
+from .compile_cache import enable_compile_cache
 from .mesh import data_axes_of, make_host_mesh
 
 
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--compress", action="store_true")
     ap.add_argument("--deadline-s", type=float, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = make_host_mesh(args.model_parallel) \

@@ -18,9 +18,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..core.jax_compat import shard_map
 from ..core.ops import EmbeddingOp
 from .common import ModelConfig, dense_init, _ACTS
 

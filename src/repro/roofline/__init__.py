@@ -1,5 +1,5 @@
-from .analysis import (HW, collective_bytes_from_hlo, model_flops,
+from .analysis import (PEAKS, peaks, collective_bytes_from_hlo, model_flops,
                        roofline_terms)
 
-__all__ = ["HW", "collective_bytes_from_hlo", "model_flops",
+__all__ = ["PEAKS", "peaks", "collective_bytes_from_hlo", "model_flops",
            "roofline_terms"]

@@ -13,12 +13,35 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e-class hardware constants (per chip), per the assignment.
+
 @dataclasses.dataclass(frozen=True)
-class HW:
-    peak_flops: float = 197e12        # bf16 FLOP/s
-    hbm_bw: float = 819e9             # B/s
-    ici_bw: float = 50e9              # B/s per link
+class Peaks:
+    """Published per-chip peaks of one TPU generation."""
+    peak_flops: float                 # bf16 FLOP/s
+    hbm_bw: float                     # HBM B/s
+    ici_bw: float                     # chip-to-chip B/s per link
+    source: str
+
+
+#: Peaks keyed by ``jax.Device.device_kind``.  A device that is not listed
+#: has no peaks: :func:`peaks` raises rather than guessing.
+PEAKS = {
+    # 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links
+    "TPU v5 lite": Peaks(197e12, 819e9, 50e9,
+                         "Google Cloud documentation, \"TPU v5e\""),
+}
+
+#: The chip the dry-run meshes model (a v5e pod).
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to repro.roofline.analysis.PEAKS with their source") from None
 
 
 _DTYPE_BYTES = {
@@ -100,7 +123,8 @@ def _tensor_bytes(t: str) -> int:
 
 def roofline_terms(*, flops: float, bytes_accessed: float,
                    collective_bytes: float, n_chips: int,
-                   hw: HW = HW()) -> dict:
+                   device_kind: str) -> dict:
+    hw = peaks(device_kind)
     compute_s = flops / (n_chips * hw.peak_flops)
     memory_s = bytes_accessed / (n_chips * hw.hbm_bw)
     coll_s = collective_bytes / (n_chips * hw.ici_bw)

@@ -10,8 +10,8 @@ from pathlib import Path
 
 from ..configs import get_config
 from ..launch.steps import SHAPES
-from .analysis import (HW, analytic_bytes_per_device, analytic_flops,
-                       model_flops, roofline_terms)
+from .analysis import (TARGET_KIND, analytic_bytes_per_device, analytic_flops,
+                       model_flops, peaks, roofline_terms)
 
 DRYRUN = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -64,11 +64,11 @@ def build_rows(mesh="single"):
                 flops=af / n,
                 bytes_accessed=rec.get("bytes_scanned", 0.0) * max(scale, 1),
                 collective_bytes=rec.get("collective_bytes", 0.0),
-                n_chips=1)
+                n_chips=1, device_kind=TARGET_KIND)
         r = rec["roofline"]
         hlo_total = rec.get("flops", 0.0)
         ab = analytic_bytes_per_device(cfg, seq, batch, kind)
-        mem_an = ab / HW().hbm_bw
+        mem_an = ab / peaks(TARGET_KIND).hbm_bw
         # verdict uses the analytic production-path memory: the HLO memory
         # number is an upper bound inflated by cost-mode dense attention
         # (and trip-scaling for fallback cells) — both are reported

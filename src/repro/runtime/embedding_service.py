@@ -225,7 +225,7 @@ class EmbeddingService:
         if aot_dir is not None:
             ameta = art.artifact_meta(
                 program, opt_level=meta["opt_level"], vlen=meta["vlen"],
-                backend=meta["backend"], interpret=meta["interpret"])
+                backend=meta["backend"])
             loaded = art.load_artifact(aot_dir, ameta)
             if loaded is not None:
                 compiled, payloads = loaded
@@ -238,7 +238,7 @@ class EmbeddingService:
             compiled = compile_program(program, meta["opt_level"],
                                        vlen=meta["vlen"])
         self.executor = ProgramExecutor(
-            compiled, interpret=meta["interpret"], depth=2,
+            compiled, depth=2,
             backend=meta["backend"], index_policy=meta["index_policy"])
         if aot_dir is not None:
             self.executor.attach_artifact(aot_dir, ameta, payloads,
@@ -466,6 +466,15 @@ class _Replica:
 _POOL_IDS = itertools.count(1)
 
 
+def _holds_tpu() -> bool:
+    """Whether this process has initialized a TPU backend (and so holds
+    the chip's lock).  Never initializes a backend itself."""
+    import jax
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized() and \
+        jax.default_backend() == "tpu"
+
+
 class ServicePool:
     """N embedding-service replicas behind one fault-tolerant dispatch.
 
@@ -485,6 +494,12 @@ class ServicePool:
                  auto_respawn: bool = True, faults=None,
                  crash_at: Optional[dict] = None, chaos_seed: int = 0):
         assert replicas >= 1, replicas
+        if _holds_tpu():
+            raise RuntimeError(
+                "ServicePool spawns replica processes that each need the "
+                "accelerator, but this process already holds the TPU (a "
+                "TPU belongs to one process); start the pool before this "
+                "process touches JAX, or serve in-process")
         self.pool_id = next(_POOL_IDS)
         self._own_dir = warm_dir is None
         self.warm_dir = Path(warm_dir) if warm_dir else \
@@ -729,10 +744,10 @@ class ServicePool:
     # -- data plane: bind / update / steps ---------------------------------
 
     def _bind_meta(self, program, tables, *, opt_level, vlen, backend,
-                   index_policy, interpret, hot_spec=None) -> dict:
+                   index_policy, hot_spec=None) -> dict:
         return {"program": program_to_spec(program), "opt_level": opt_level,
                 "vlen": vlen, "backend": backend,
-                "index_policy": index_policy, "interpret": bool(interpret),
+                "index_policy": index_policy,
                 "table_ops": sorted(tables),
                 "hot_spec": ({n: sorted(int(i) for i in ids)
                               for n, ids in dict(hot_spec).items()}

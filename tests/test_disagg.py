@@ -63,6 +63,16 @@ def pool():
 # Transport
 # ---------------------------------------------------------------------------
 
+def test_pool_refuses_to_spawn_when_this_process_holds_the_tpu(
+        monkeypatch, tmp_path):
+    """Replicas would block on the chip's lock: fail at once instead."""
+    from repro.runtime import embedding_service as es
+    monkeypatch.setattr(es, "_holds_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="already holds the TPU"):
+        ServicePool(2, warm_dir=tmp_path)
+    assert not any(tmp_path.iterdir())
+
+
 def test_framing_roundtrip_bit_identical():
     a, b = socket.socketpair()
     arrays = {"f32": np.random.default_rng(0).normal(size=(7, 3)).astype(

@@ -70,7 +70,7 @@ def test_executor_matches_jax_backend():
     prog = _mixed_program()
     pres = compile_program(prog, "O3", vlen=4, use_cache=False)
     ins = make_program_inputs(prog, seed=3)
-    want = backend_pallas.execute_program(pres, ins, interpret=True)
+    want = backend_pallas.execute_program(pres, ins)
     got = ProgramExecutor(pres).step(ins)
     for n in dict(prog.ops):
         np.testing.assert_allclose(np.asarray(got[n]), np.asarray(want[n]),
@@ -455,7 +455,7 @@ def test_mixed_weighted_unweighted_upcast():
     ins = make_program_inputs(prog, seed=4)
     want = program_reference(prog, ins)
     pres = compile_program(prog, "O3", vlen=4, use_cache=False)
-    outs = backend_pallas.execute_program(pres, ins, interpret=True)
+    outs = backend_pallas.execute_program(pres, ins)
     for n in want:
         np.testing.assert_allclose(np.asarray(outs[n]), want[n],
                                    rtol=1e-4, atol=1e-4)

@@ -86,6 +86,50 @@ def test_fusedmm(b, avg, e):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("weighted", [False, True])
+def test_sls_split_launches(monkeypatch, weighted):
+    """A step whose scalar-prefetched CSR streams overflow one launch's SMEM
+    budget is split into segment chunks; the pooled rows are unchanged."""
+    from repro.kernels import rowdma
+    monkeypatch.setattr(rowdma, "SMEM_BUDGET", 1280)
+    b, n, e = 80, 57, 128
+    ptrs, idxs = _csr(b, n, 5)
+    base = RNG.integers(0, 3, b).astype(np.int32)
+    table = RNG.standard_normal((n + 3, e)).astype(np.float32)
+    w = RNG.standard_normal(len(idxs)).astype(np.float32) if weighted \
+        else None
+    seg = ref.csr_to_lookups(ptrs)
+    want = ref.sls(table, idxs + base[seg], seg, w, num_segments=b)
+    got = ops.sls(jnp.asarray(table), jnp.asarray(ptrs), jnp.asarray(idxs),
+                  None if w is None else jnp.asarray(w), num_segments=b,
+                  max_lookups=max_lookups_of(ptrs),
+                  seg_base=jnp.asarray(base), interpret=True)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_fusedmm_split_launches(monkeypatch):
+    from repro.kernels import rowdma
+    monkeypatch.setattr(rowdma, "SMEM_BUDGET", 512)
+    b, e = 37, 128
+    ptrs, idxs = _csr(b, b, 4)
+    x = RNG.standard_normal((b, e)).astype(np.float32)
+    want = ref.fusedmm(x, idxs, ref.csr_to_lookups(ptrs), num_segments=b)
+    got = ops.fusedmm(jnp.asarray(x), jnp.asarray(ptrs), jnp.asarray(idxs),
+                      num_segments=b, max_lookups=max_lookups_of(ptrs),
+                      interpret=True)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def test_block_gather_split_launches(monkeypatch):
+    from repro.kernels import rowdma
+    monkeypatch.setattr(rowdma, "SMEM_BUDGET", 64)
+    table = RNG.standard_normal((29, 128)).astype(np.float32)
+    idxs = RNG.integers(0, 29, 45).astype(np.int32)
+    got = ops.block_gather(jnp.asarray(table), jnp.asarray(idxs),
+                           interpret=True)
+    np.testing.assert_array_equal(got, ref.block_gather(table, idxs))
+
+
 @pytest.mark.parametrize("bh,s,d,causal", [(2, 256, 64, True),
                                            (3, 128, 128, False),
                                            (1, 512, 64, True)])
@@ -116,6 +160,6 @@ def test_compiler_pallas_backend_matches_reference():
         res = compile_op(op, "O3")
         plan = make_plan(res)
         assert plan.col_tile % 128 == 0
-        got = execute(res, ins, interpret=True)
+        got = execute(res, ins)
         np.testing.assert_allclose(np.asarray(got), reference(op, ins),
                                    rtol=1e-4, atol=1e-4)

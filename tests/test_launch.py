@@ -3,6 +3,7 @@ and an end-to-end dry-run cell on a tiny in-process mesh (subprocess)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,8 @@ from repro.launch.mesh import axis_types_kw
 from repro.launch.steps import SHAPES, make_batch_struct, shape_applicable
 from repro.roofline.analysis import (analytic_flops, collective_bytes_from_hlo,
                                      model_flops, roofline_terms)
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 def test_param_specs_structure():
@@ -88,12 +91,19 @@ def test_collective_parser_stablehlo_region():
 
 def test_roofline_terms_bottleneck():
     r = roofline_terms(flops=197e12, bytes_accessed=0.0, collective_bytes=0.0,
-                       n_chips=1)
+                       n_chips=1, device_kind="TPU v5 lite")
     assert r["bottleneck"] == "compute"
     assert abs(r["compute_s"] - 1.0) < 1e-9
     r2 = roofline_terms(flops=0.0, bytes_accessed=819e9,
-                        collective_bytes=0.0, n_chips=1)
+                        collective_bytes=0.0, n_chips=1,
+                        device_kind="TPU v5 lite")
     assert r2["bottleneck"] == "memory"
+
+
+def test_peaks_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(flops=1.0, bytes_accessed=0.0, collective_bytes=0.0,
+                       n_chips=1, device_kind="cpu")
 
 
 def test_model_flops_sane():
@@ -113,13 +123,13 @@ def test_dryrun_cell_tiny_mesh():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from repro.configs import get_reduced
-        from repro.launch.mesh import axis_types_kw, mesh_context
+        from repro.launch.mesh import axis_types_kw
         from repro.launch.steps import build_bundle
         import repro.launch.steps as steps
         steps.SHAPES = {"train_4k": (32, 8, "train")}
         mesh = jax.make_mesh((2, 4), ("data", "model"), **axis_types_kw(2))
         cfg = get_reduced("gemma3-4b")
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             b = build_bundle(cfg, mesh, "train_4k", remat="none")
             c = jax.jit(b.fn, in_shardings=b.in_shardings
                         ).lower(*b.args).compile()
@@ -132,5 +142,42 @@ def test_dryrun_cell_tiny_mesh():
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True,
                        env={**__import__("os").environ, "PYTHONPATH": "src"},
-                       cwd="/root/repo", timeout=600)
+                       cwd=str(REPO), timeout=600)
     assert "TINY_DRYRUN_OK" in r.stdout, r.stderr[-2000:]
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_dir_wins(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, compiled programs land there and
+    the helper sets no other directory (subprocess: fresh jax config)."""
+    code = textwrap.dedent("""
+        import jax, jax.numpy as jnp
+        from repro.launch.compile_cache import enable_compile_cache
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        print("DIR", enable_compile_cache(),
+              jax.config.jax_compilation_cache_dir)
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+    """)
+    env = {**__import__("os").environ, "PYTHONPATH": "src",
+           "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=str(REPO), timeout=300)
+    assert f"DIR {tmp_path} {tmp_path}" in r.stdout, r.stderr[-2000:]
+    assert any(tmp_path.iterdir()), "no cache entry was written"
+
+
+def test_forced_device_env_keeps_children_off_the_chip():
+    from benchmarks._mesh import forced_device_env
+    env = forced_device_env(2, base={"JAX_PLATFORMS": "tpu"})
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "--xla_force_host_platform_device_count=2" in env["XLA_FLAGS"]

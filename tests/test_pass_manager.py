@@ -142,7 +142,7 @@ def test_fusion_backends_match_reference(lvl):
     want = program_reference(prog, ins)
     pres = compile_program(prog, lvl, vlen=4, use_cache=False)
     # Pallas backend: one batched kernel launch for the fused unit
-    outs = backend_pallas.execute_program(pres, ins, interpret=True)
+    outs = backend_pallas.execute_program(pres, ins)
     for n in want:
         np.testing.assert_allclose(np.asarray(outs[n]), want[n],
                                    rtol=1e-4, atol=1e-4)

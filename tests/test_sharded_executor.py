@@ -513,6 +513,42 @@ def test_shard_count_helper():
 # End-to-end on a real 2-device mesh (subprocess; test_launch pattern)
 # ---------------------------------------------------------------------------
 
+def test_shard_stack_tables_matches_plan_layout_on_a_mesh(run_on_mesh):
+    """The device stack equals the plan's numpy oracle on a (data 2,
+    model 2) mesh, hot slab or not, and every device holds only its own
+    shard's rows (replicated over ``data``)."""
+    code = """
+        import jax
+        import numpy as np
+        from repro.core import access_plan as ap, shard_plan as sp
+        from repro.core.ops import EmbeddingOp, EmbeddingProgram
+        from repro.core.passes import fuse_program
+        from repro.launch.mesh import axis_types_kw
+        mesh = jax.make_mesh((2, 2), ("data", "model"), **axis_types_kw(2))
+        prog = EmbeddingProgram("g", (
+            ("a", EmbeddingOp("sls", 4, 10, 8, avg_lookups=3)),
+            ("b", EmbeddingOp("sls", 3, 7, 8, avg_lookups=2)),
+        ))
+        (group,), _ = fuse_program(prog)
+        rng = np.random.default_rng(0)
+        parts = [rng.standard_normal((10, 8)).astype(np.float32),
+                 rng.standard_normal((7, 8)).astype(np.float32)]
+        for hot in (None, {"a": (2, 7), "b": (0,)}):
+            plan = ap.plan_for_group(group, shards=2, hot_rows=hot)
+            got = sp.shard_stack_tables(parts, plan, mesh, "model")
+            want = plan.stack_np(parts)
+            np.testing.assert_array_equal(np.asarray(got), want)
+            L = plan.local_rows
+            assert len(got.addressable_shards) == 4
+            for s in got.addressable_shards:
+                k = s.index[0].start // L
+                np.testing.assert_array_equal(np.asarray(s.data),
+                                              want[k * L:(k + 1) * L])
+        print("STACK_OK")
+    """
+    run_on_mesh(code, devices=4, sentinel="STACK_OK")
+
+
 def test_sharded_executor_two_devices(run_on_mesh):
     code = """
         import jax

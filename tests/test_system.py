@@ -1,5 +1,7 @@
 """End-to-end system behaviour: training convergence, checkpoint/restart,
 failure injection + supervised restart, straggler watchdog, decode server."""
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -12,6 +14,8 @@ from repro.models import LM
 from repro.runtime.server import DecodeServer, Request
 from repro.runtime.trainer import (InjectedFailure, StragglerTimeout,
                                    Trainer, TrainerConfig, run_supervised)
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 def _mk(tmp_path, arch="stablelm-3b", steps=24, **kw):
@@ -116,5 +120,5 @@ def test_elastic_checkpoint_reshard(tmp_path):
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True,
                        env={**__import__("os").environ,
-                            "PYTHONPATH": "src"}, cwd="/root/repo")
+                            "PYTHONPATH": "src"}, cwd=str(REPO))
     assert "ELASTIC_OK" in r.stdout, r.stderr[-2000:]
