@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..kernels import ops as kops
 from . import access_plan as ap
 from . import backend_jax as bj
@@ -97,10 +98,11 @@ class StepHandle:
     def result(self) -> dict:
         if self.faults is not None:
             self.faults.fire("result", step=self.index)
-        if self.pending is not None:
-            fn, self.pending = self.pending, None
-            self.outputs.update(fn())
-        jax.block_until_ready(self.outputs)
+        with tracing.span("result", self.index):
+            if self.pending is not None:
+                fn, self.pending = self.pending, None
+                self.outputs.update(fn())
+            jax.block_until_ready(self.outputs)
         self.done = True
         return self.outputs
 
@@ -236,7 +238,8 @@ class BufferPool:
                 self._count_bytes(spec, 1)
             else:                     # full ring: drain the oldest owner
                 turn = (entry["turn"] + 1) % n
-                entry["owners"][turn].result()
+                with tracing.span("submit.wait"):
+                    entry["owners"][turn].result()
                 self.stats["forced_drains"] += 1
         entry["turn"] = turn
         entry["owners"][turn] = None
@@ -636,23 +639,24 @@ class ProgramExecutor:
         ids from ``ptrs`` on the host anyway)."""
         plan = u.plan
         op = plan.op
-        parts, nnz, max_seg = plan.csr_parts(inputs)
-        cap = plan.lattice.lookup_capacity(nnz)
-        ml = plan.lattice.grid_capacity(max_seg)
         need_vals = plan.need_vals
-        spec = {"ptrs": ((op.num_segments + 1,), np.int32),
-                "idxs": ((cap,), np.int32)}
-        if need_vals:
-            spec["vals"] = ((cap,), np.dtype(op.dtype))
-        buf = self._scratch_for(idx, (cap, ml), spec)
-        plan.pack_csr(buf, parts, inputs)
-        if self.backend == "jax":
-            ins = {"table": u.table, "roff": plan.roff,
-                   "ptrs": buf["ptrs"], "idxs": buf["idxs"][:nnz]}
+        with tracing.span("submit.marshal"):
+            parts, nnz, max_seg = plan.csr_parts(inputs)
+            cap = plan.lattice.lookup_capacity(nnz)
+            ml = plan.lattice.grid_capacity(max_seg)
+            spec = {"ptrs": ((op.num_segments + 1,), np.int32),
+                    "idxs": ((cap,), np.int32)}
             if need_vals:
-                ins["vals"] = buf["vals"][:nnz]
-            return ins, ml
-        buf["idxs"][nnz:cap] = 0          # pad rows must stay in bounds
+                spec["vals"] = ((cap,), np.dtype(op.dtype))
+            buf = self._scratch_for(idx, (cap, ml), spec)
+            plan.pack_csr(buf, parts, inputs)
+            if self.backend == "jax":
+                ins = {"table": u.table, "roff": plan.roff,
+                       "ptrs": buf["ptrs"], "idxs": buf["idxs"][:nnz]}
+                if need_vals:
+                    ins["vals"] = buf["vals"][:nnz]
+                return ins, ml
+            buf["idxs"][nnz:cap] = 0      # pad rows must stay in bounds
         dev = {"table": u.table, "roff": u.roff,
                "ptrs": self._put(buf["ptrs"]),
                "idxs": self._put(buf["idxs"])}
@@ -665,13 +669,15 @@ class ProgramExecutor:
         ``host_syncs`` (the executor's per-step transfer-issue stat)."""
         self._fire("transfer")
         self.stats["host_syncs"] += 1
-        return jax.device_put(arr)
+        with tracing.span("submit.put"):
+            return jax.device_put(arr)
 
     def _marshal_gather(self, idx: int, u: _UnitState, inputs: dict):
         plan = u.plan
         n = plan.num_segments
-        buf = self._scratch_for(idx, (), {"idxs": ((n,), np.int32)})
-        plan.pack_gather(buf, inputs)
+        with tracing.span("submit.marshal"):
+            buf = self._scratch_for(idx, (), {"idxs": ((n,), np.int32)})
+            plan.pack_gather(buf, inputs)
         if self.backend == "jax":
             return {"table": u.table, "roff": plan.roff,
                     "idxs": buf["idxs"]}, None
@@ -689,7 +695,8 @@ class ProgramExecutor:
         fewer of these per step)."""
         self._fire("transfer")
         self.stats["host_syncs"] += 1
-        return sp.put_sharded(arr, self.mesh, self.shard_axis)
+        with tracing.span("submit.put"):
+            return sp.put_sharded(arr, self.mesh, self.shard_axis)
 
     def _shard_fn(self, idx: int, u: _UnitState, bucket: tuple):
         """Memoized jit(shard_map) callable per (unit, capacity bucket) —
@@ -756,38 +763,41 @@ class ProgramExecutor:
         plan = u.plan
         op = plan.op
         need_vals = plan.need_vals
-        routed = plan.route_csr(inputs)
-        s, cap, ml = self.shards, routed["cap"], routed["max_lookups"]
-        spec = {"ptrs": ((s, op.num_segments + 1), np.int32),
-                "idxs": ((s, cap), np.int32)}
-        if need_vals:
-            spec["vals"] = ((s, cap), np.dtype(op.dtype))
-        buf = self._scratch_for(idx, (cap, ml), spec)
-        buf["ptrs"][:] = routed["ptrs"]
-        bounds = routed["bounds"]
-        for o in range(s):
-            n = bounds[o + 1] - bounds[o]
-            buf["idxs"][o, :n] = routed["idxs"][bounds[o]:bounds[o + 1]]
-            buf["idxs"][o, n:] = 0        # pad rows must stay in bounds
+        with tracing.span("submit.marshal"):
+            routed = plan.route_csr(inputs)
+            s, cap, ml = self.shards, routed["cap"], routed["max_lookups"]
+            spec = {"ptrs": ((s, op.num_segments + 1), np.int32),
+                    "idxs": ((s, cap), np.int32)}
             if need_vals:
-                buf["vals"][o, :n] = routed["vals"][bounds[o]:bounds[o + 1]]
-                buf["vals"][o, n:] = 0
-        # only the cold tail is exchanged; hot lookups were absorbed by the
-        # replicated slab (local lookup on a round-robin shard)
-        self.stats["exchange_index_bytes"] += \
-            routed["cold_nnz"] * (8 if need_vals else 4)
-        self._note_hot_cold(routed["hot_nnz"], routed["cold_nnz"])
-        # next step's round-robin hot assignment starts at the shard whose
-        # routed bucket was lightest this step
-        if self.adaptive is not None:
-            plan.rr_start = int(np.argmin(routed["nnz"]))
-        self._count_row_bytes(op, 1, plan)
+                spec["vals"] = ((s, cap), np.dtype(op.dtype))
+            buf = self._scratch_for(idx, (cap, ml), spec)
+            buf["ptrs"][:] = routed["ptrs"]
+            bounds = routed["bounds"]
+            for o in range(s):
+                a, b = bounds[o], bounds[o + 1]
+                n = b - a
+                buf["idxs"][o, :n] = routed["idxs"][a:b]
+                buf["idxs"][o, n:] = 0    # pad rows must stay in bounds
+                if need_vals:
+                    buf["vals"][o, :n] = routed["vals"][a:b]
+                    buf["vals"][o, n:] = 0
+            # only the cold tail is exchanged; hot lookups were absorbed by
+            # the replicated slab (local lookup on a round-robin shard)
+            self.stats["exchange_index_bytes"] += \
+                routed["cold_nnz"] * (8 if need_vals else 4)
+            self._note_hot_cold(routed["hot_nnz"], routed["cold_nnz"])
+            # next step's round-robin hot assignment starts at the shard
+            # whose routed bucket was lightest this step
+            if self.adaptive is not None:
+                plan.rr_start = int(np.argmin(routed["nnz"]))
+            self._count_row_bytes(op, 1, plan)
         args = [u.table, u.roff, self._put_sharded(buf["ptrs"]),
                 self._put_sharded(buf["idxs"])]
         if need_vals:
             args.append(self._put_sharded(buf["vals"]))
         fn = self._shard_fn(idx, u, ("csr", cap, ml, need_vals))
-        return fn(*args)
+        with tracing.span("submit.dispatch"):
+            return fn(*args)
 
     def _run_csr_collective(self, idx: int, u: _UnitState, inputs: dict):
         """Fused CSR unit over S vocab shards, collective exchange: the
@@ -799,67 +809,74 @@ class ProgramExecutor:
         plan = u.plan
         op = plan.op
         need_vals = plan.need_vals
-        routed = plan.route_csr_collective(inputs)
-        s, cap, ml = self.shards, routed["cap"], routed["max_lookups"]
-        spec = {"ints": ((s, s, 2, cap), np.int32)}
-        if need_vals:
-            spec["vals"] = ((s, s, cap), np.dtype(op.dtype))
-        buf = self._scratch_for(idx, ("coll", cap, ml), spec)
-        plan.fill_lattice(routed, buf["ints"],
-                          buf["vals"] if need_vals else None)
-        # wire volume: only off-diagonal (src != owner) lookups actually
-        # cross a link in the all_to_all; hot lookups are always diagonal.
-        # Each wire lookup carries its segment id + local index (+ val):
-        # 8 (12 weighted) bytes — matching the gather path's seg+idx count
-        self.stats["exchange_index_bytes"] += \
-            routed["wire_nnz"] * (12 if need_vals else 8)
-        self._note_hot_cold(routed["hot_nnz"], routed["cold_nnz"])
-        self.stats["spilled_lookups"] += routed.get("spilled_nnz", 0)
-        # feedback for the NEXT step: when one source's diagonal bucket is
-        # overloaded, spill a bounded fraction of its hot lookups to the
-        # least-loaded peer (the slab is replicated — owner choice is free)
-        if self.adaptive is not None:
-            plan.spill = sp.compute_spill(routed["pair_counts"],
-                                          self.adaptive.spill_fraction,
-                                          self.adaptive.spill_overload)
-        self._count_row_bytes(op, 1, plan)
+        with tracing.span("submit.marshal"):
+            routed = plan.route_csr_collective(inputs)
+            s, cap, ml = self.shards, routed["cap"], routed["max_lookups"]
+            spec = {"ints": ((s, s, 2, cap), np.int32)}
+            if need_vals:
+                spec["vals"] = ((s, s, cap), np.dtype(op.dtype))
+            buf = self._scratch_for(idx, ("coll", cap, ml), spec)
+            plan.fill_lattice(routed, buf["ints"],
+                              buf["vals"] if need_vals else None)
+            # wire volume: only off-diagonal (src != owner) lookups
+            # actually cross a link in the all_to_all; hot lookups are
+            # always diagonal.  Each wire lookup carries its segment id +
+            # local index (+ val): 8 (12 weighted) bytes — matching the
+            # gather path's seg+idx count
+            self.stats["exchange_index_bytes"] += \
+                routed["wire_nnz"] * (12 if need_vals else 8)
+            self._note_hot_cold(routed["hot_nnz"], routed["cold_nnz"])
+            self.stats["spilled_lookups"] += routed.get("spilled_nnz", 0)
+            # feedback for the NEXT step: when one source's diagonal bucket
+            # is overloaded, spill a bounded fraction of its hot lookups to
+            # the least-loaded peer (the slab is replicated — owner choice
+            # is free)
+            if self.adaptive is not None:
+                plan.spill = sp.compute_spill(routed["pair_counts"],
+                                              self.adaptive.spill_fraction,
+                                              self.adaptive.spill_overload)
+            self._count_row_bytes(op, 1, plan)
         args = [u.table, u.roff, self._put_sharded(buf["ints"])]
         if need_vals:
             args.append(self._put_sharded(buf["vals"]))
         fn = self._shard_fn(idx, u, ("csr", cap, ml, need_vals))
-        return fn(*args)
+        with tracing.span("submit.dispatch"):
+            return fn(*args)
 
     def _run_gather_sharded(self, idx: int, u: _UnitState, inputs: dict):
         plan = u.plan
         n = plan.num_segments
         blk = plan.op.block_rows
         s = self.shards
-        if self.exchange == "collective":
-            routed = plan.route_gather_collective(inputs)
-            cap = routed["cap"]
-            spec = {"ints": ((s, s, 2, cap), np.int32)}
-            buf = self._scratch_for(idx, ("gather-coll", cap), spec)
-            plan.fill_lattice(routed, buf["ints"])
-            self.stats["exchange_index_bytes"] += \
-                routed["wire_segments"] * 8   # seg + idx word
-            args = [u.table, u.roff, self._put_sharded(buf["ints"])]
-            bucket = ("gather-coll", cap)
-        else:
-            routed = plan.route_gather(inputs)
-            spec = {"idxs": ((s, n), np.int32),
-                    "mask": ((s, n), np.float32)}
-            buf = self._scratch_for(idx, ("gather",), spec)
-            buf["idxs"][:] = routed["idxs"]
-            buf["mask"][:] = routed["mask"]
-            self.stats["exchange_index_bytes"] += \
-                routed["cold_segments"] * 8   # idx + mask word
-            args = [u.table, u.roff, self._put_sharded(buf["idxs"]),
-                    self._put_sharded(buf["mask"])]
-            bucket = ("gather",)
-        self._note_hot_cold(routed["hot_segments"], routed["cold_segments"])
-        self._count_row_bytes(plan.op, blk, plan)
+        with tracing.span("submit.marshal"):
+            if self.exchange == "collective":
+                routed = plan.route_gather_collective(inputs)
+                cap = routed["cap"]
+                spec = {"ints": ((s, s, 2, cap), np.int32)}
+                buf = self._scratch_for(idx, ("gather-coll", cap), spec)
+                plan.fill_lattice(routed, buf["ints"])
+                self.stats["exchange_index_bytes"] += \
+                    routed["wire_segments"] * 8   # seg + idx word
+                sent = ("ints",)
+                bucket = ("gather-coll", cap)
+            else:
+                routed = plan.route_gather(inputs)
+                spec = {"idxs": ((s, n), np.int32),
+                        "mask": ((s, n), np.float32)}
+                buf = self._scratch_for(idx, ("gather",), spec)
+                buf["idxs"][:] = routed["idxs"]
+                buf["mask"][:] = routed["mask"]
+                self.stats["exchange_index_bytes"] += \
+                    routed["cold_segments"] * 8   # idx + mask word
+                sent = ("idxs", "mask")
+                bucket = ("gather",)
+            self._note_hot_cold(routed["hot_segments"],
+                                routed["cold_segments"])
+            self._count_row_bytes(plan.op, blk, plan)
+        args = [u.table, u.roff] + [self._put_sharded(buf[k]) for k in sent]
         fn = self._shard_fn(idx, u, bucket)
-        return fn(*args)
+        with tracing.span("submit.dispatch"):
+            return fn(*args)
 
     def _marshal_single(self, idx: int, u: _UnitState, inputs: dict):
         """Singleton unit: device-transfer the per-step operands, bucketing
@@ -874,28 +891,31 @@ class ProgramExecutor:
             return {"table": u.table,
                     "idxs": self._put(np.asarray(ins["idxs"])),
                     "vals": self._put(np.asarray(ins["vals"]))}, 1
-        if op.index_format == "lengths" and "ptrs" not in ins:
-            ptrs = np.zeros(op.num_segments + 1, np.int64)
-            np.cumsum(ins["lens"], out=ptrs[1:])
-        else:
-            ptrs = np.asarray(ins["ptrs"], np.int64)
-        nnz = int(ptrs[-1])
-        cap = u.plan.lattice.lookup_capacity(nnz)
-        ml = u.plan.lattice.grid_capacity(int(np.diff(ptrs).max(initial=0)))
         key = "x" if op.kind == "fusedmm" else "table"
         need_vals = u.plan.need_vals and "vals" in ins
-        spec = {"ptrs": ((op.num_segments + 1,), np.int32),
-                "idxs": ((cap,), np.int32)}
-        if need_vals:
-            spec["vals"] = ((cap,), np.dtype(op.dtype))
-        buf = self._scratch_for(idx, (cap, ml), spec)
-        buf["ptrs"][:] = ptrs
-        buf["idxs"][:nnz] = ins["idxs"]
-        buf["idxs"][nnz:cap] = 0
+        with tracing.span("submit.marshal"):
+            if op.index_format == "lengths" and "ptrs" not in ins:
+                ptrs = np.zeros(op.num_segments + 1, np.int64)
+                np.cumsum(ins["lens"], out=ptrs[1:])
+            else:
+                ptrs = np.asarray(ins["ptrs"], np.int64)
+            nnz = int(ptrs[-1])
+            cap = u.plan.lattice.lookup_capacity(nnz)
+            ml = u.plan.lattice.grid_capacity(
+                int(np.diff(ptrs).max(initial=0)))
+            spec = {"ptrs": ((op.num_segments + 1,), np.int32),
+                    "idxs": ((cap,), np.int32)}
+            if need_vals:
+                spec["vals"] = ((cap,), np.dtype(op.dtype))
+            buf = self._scratch_for(idx, (cap, ml), spec)
+            buf["ptrs"][:] = ptrs
+            buf["idxs"][:nnz] = ins["idxs"]
+            buf["idxs"][nnz:cap] = 0
+            if need_vals:
+                buf["vals"][:nnz] = ins["vals"]
         dev = {key: u.table, "ptrs": self._put(buf["ptrs"]),
                "idxs": self._put(buf["idxs"])}
         if need_vals:
-            buf["vals"][:nnz] = ins["vals"]
             dev["vals"] = self._put(buf["vals"])
         return dev, ml
 
@@ -909,9 +929,10 @@ class ProgramExecutor:
         bodies cannot invoke an AOT-compiled callable mid-trace, so they
         keep the plain jit path (trace-on-load fallback, see
         :mod:`repro.core.artifact`)."""
-        if self.backend == "jax":
-            return bj.execute(u.res.op, ins, aot=aot)
-        return bp.execute(u.res, ins, max_lookups=ml, aot=aot)
+        with tracing.span("submit.dispatch"):
+            if self.backend == "jax":
+                return bj.execute(u.res.op, ins, aot=aot)
+            return bp.execute(u.res, ins, max_lookups=ml, aot=aot)
 
     def _txn_defer(self, outs: dict, dev: dict, run) -> None:
         """Stage a gather-kind unit's per-step host arrays on the wave's
@@ -954,8 +975,9 @@ class ProgramExecutor:
         object on clean streams, so the hardened steady state is
         bit-identical to an unhardened executor."""
         fallback = u.unit.names[0] if u.group is None else None
-        hardened, oob, dropped = u.plan.harden_step(
-            inputs, self.index_policy, fallback_name=fallback)
+        with tracing.span("submit.harden"):
+            hardened, oob, dropped = u.plan.harden_step(
+                inputs, self.index_policy, fallback_name=fallback)
         self.stats["oob_lookups"] += oob
         self.stats["dropped_lookups"] += dropped
         return hardened
@@ -988,7 +1010,9 @@ class ProgramExecutor:
                                 else np.asarray(v) for k, v in ins.items()}
                         self._txn_defer(outs, norm, self._unit_run(u))
                         continue
-                    outs[name] = bj.execute(u.res.op, ins, aot=self.aot)
+                    with tracing.span("submit.dispatch"):
+                        outs[name] = bj.execute(u.res.op, ins,
+                                                aot=self.aot)
                     continue
                 dev, ml = self._marshal_single(idx, u, uin)
                 outs[u.unit.names[0]] = self._execute(u, dev, ml,
@@ -1015,9 +1039,11 @@ class ProgramExecutor:
             else:
                 dev, ml = self._marshal_csr(idx, u, uin)
                 fused = self._execute(u, dev, ml, aot=self.aot)
-            for name, mop, off in zip(u.group.members, u.group.member_ops,
-                                      u.group.seg_offsets):
-                outs[name] = fused[off:off + mop.num_segments]
+            with tracing.span("submit.split"):
+                for name, mop, off in zip(u.group.members,
+                                          u.group.member_ops,
+                                          u.group.seg_offsets):
+                    outs[name] = fused[off:off + mop.num_segments]
         return outs
 
     def submit(self, inputs: dict, txn: Optional[TransferBatch] = None
@@ -1031,36 +1057,38 @@ class ProgramExecutor:
         stage their streams on the shared :class:`TransferBatch` and their
         dispatch is deferred to its flush; the handle's outputs materialize
         then.  Sharded executors route their own exchange and ignore it."""
-        self._fire("dispatch")
-        while len(self._inflight) >= self.depth:
-            self._inflight.popleft().result()
-        self._slots_packed = []
-        if self._adapt_counts:
-            self._adapt_observe(inputs)
+        with tracing.span("submit", self._steps):
+            self._fire("dispatch")
+            with tracing.span("submit.wait"):
+                while len(self._inflight) >= self.depth:
+                    self._inflight.popleft().result()
+            self._slots_packed = []
+            if self._adapt_counts:
+                self._adapt_observe(inputs)
+                if self.service == "disagg":
+                    self._note_svc_traffic(inputs)
             if self.service == "disagg":
-                self._note_svc_traffic(inputs)
-        if self.service == "disagg":
-            outs, pending = self._submit_disagg(inputs)
-        else:
-            pending = None
-            self._txn = txn if self.shards == 1 else None
-            try:
-                outs = self._dispatch(inputs)
-            finally:
-                self._txn = None
-        h = StepHandle(outs, self._steps, faults=self.faults,
-                       pending=pending)
-        for entry, turn in self._slots_packed:
-            entry["owners"][turn] = h     # slot busy until h resolves
-        self._steps += 1
-        self.stats["steps"] += 1
-        self._inflight.append(h)
-        self.stats["max_inflight"] = max(self.stats["max_inflight"],
-                                         len(self._inflight))
-        self._win_tick()
-        if self.adaptive is not None:
-            self._adapt_tick()
-        return h
+                outs, pending = self._submit_disagg(inputs)
+            else:
+                pending = None
+                self._txn = txn if self.shards == 1 else None
+                try:
+                    outs = self._dispatch(inputs)
+                finally:
+                    self._txn = None
+            h = StepHandle(outs, self._steps, faults=self.faults,
+                           pending=pending)
+            for entry, turn in self._slots_packed:
+                entry["owners"][turn] = h     # slot busy until h resolves
+            self._steps += 1
+            self.stats["steps"] += 1
+            self._inflight.append(h)
+            self.stats["max_inflight"] = max(self.stats["max_inflight"],
+                                             len(self._inflight))
+            self._win_tick()
+            if self.adaptive is not None:
+                self._adapt_tick()
+            return h
 
     def step(self, inputs: dict) -> dict:
         """Synchronous convenience: submit + block on this step's result."""
@@ -1501,7 +1529,6 @@ class PipelineGroup:
             "submitted": {n: 0 for n in self.names},
             "in_flight": {n: 0 for n in self.names},
             "max_in_flight": {n: 0 for n in self.names},
-            "group_drains": 0,
             "waves": 0,
             "batched_arrays": 0,
             "resets": 0,
@@ -1533,7 +1560,6 @@ class PipelineGroup:
             n0, h0 = self._inflight.popleft()
             h0.result()
             self.stats["in_flight"][n0] -= 1
-            self.stats["group_drains"] += 1
         h = self._by_name[name].submit(inputs)
         self._inflight.append((name, h))
         st = self.stats
@@ -1561,7 +1587,6 @@ class PipelineGroup:
             n0, h0 = self._inflight.popleft()
             h0.result()
             self.stats["in_flight"][n0] -= 1
-            self.stats["group_drains"] += 1
         txn = TransferBatch()
         handles = {}
         for name, inputs in wave.items():
@@ -1655,7 +1680,6 @@ class PipelineGroup:
             "submitted": dict(self.stats["submitted"]),
             "in_flight": dict(self.stats["in_flight"]),
             "max_in_flight": dict(self.stats["max_in_flight"]),
-            "group_drains": self.stats["group_drains"],
             "waves": self.stats["waves"],
             "batched_arrays": self.stats["batched_arrays"],
             "resets": self.stats["resets"],

@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core.access_plan import INDEX_POLICIES
 from .faults import EmberFault, WaveTimeout
 
@@ -435,16 +436,49 @@ class DecodeServer:
     def step(self) -> int:
         """One serving iteration: admit → one wave (chunked prefill and/or
         decode) → retire + recycle + same-iteration admit.  Returns the
-        number of active slots afterwards."""
+        number of active slots afterwards.
+
+        Spans (:mod:`repro.tracing`), keyed by the wave number: ``wave``
+        around the iteration, with ``wave.admit``, ``wave.dispatch``,
+        ``wave.sync`` (the host waiting on the wave's argmax) and
+        ``wave.emit`` inside it."""
+        with tracing.span("wave", self.waves):
+            retired = np.zeros(self.slots, bool)
+            with tracing.span("wave.admit"):
+                wave = self._assemble(retired)
+            if wave is None:
+                self._recycle(retired)
+                return self._n_active()
+            c, tokens, lens, emits = wave
+            with tracing.span("wave.dispatch"):
+                logits, t0 = self._run_wave(tokens, lens, retired)
+            if logits is None:
+                return self._n_active()
+            with tracing.span("wave.sync"):
+                nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            # the wave's time through its sync, not just its dispatch
+            dt = time.perf_counter() - t0
+            self._ewma_wave_s = dt if self._ewma_wave_s is None else \
+                0.7 * self._ewma_wave_s + 0.3 * dt
+            with tracing.span("wave.emit"):
+                self._emit(c, lens, nxt, emits, retired)
+            return self._n_active()
+
+    def _n_active(self) -> int:
+        return sum(r is not None for r in self.active)
+
+    def _assemble(self, retired: np.ndarray):
+        """Admit, then lay out this wave's tokens: ``(chunk, tokens, lens,
+        emits)``, or None when no slot has a token to feed (slots that ran
+        out of cache room are marked in ``retired``)."""
         self._admit()
         if not any(r is not None for r in self.active):
-            return 0
+            return None
         c = self.prefill_chunk \
             if any(p.size for p in self._prompt_left) else 1
         tokens = np.zeros((self.slots, c), np.int32)
         lens = np.zeros(self.slots, np.int32)
         emits = np.zeros(self.slots, bool)   # slot emits a token this wave
-        retired = np.zeros(self.slots, bool)
         for i, req in enumerate(self.active):
             if req is None:
                 continue
@@ -467,10 +501,16 @@ class DecodeServer:
                 lens[i] = 1
                 emits[i] = True
         if lens.sum() == 0:
-            self._recycle(retired)
-            return sum(r is not None for r in self.active)
-        # --- the guarded wave body: LM step + pipeline feed, under the
-        # watchdog deadline, retried after a typed fault ------------------
+            return None
+        return c, tokens, lens, emits
+
+    def _run_wave(self, tokens: np.ndarray, lens: np.ndarray,
+                  retired: np.ndarray):
+        """The guarded wave body: LM step + pipeline feed, under the
+        watchdog deadline, retried after a typed fault.  Returns the
+        wave's (unsynced) logits and the time its last attempt started,
+        or ``(None, None)`` once the retries are spent: the slots it
+        served have then failed and recycled."""
         tokens_j, lens_j = jnp.asarray(tokens), jnp.asarray(lens)
         t0 = time.perf_counter()
         lm_done = False     # the LM wave donates its caches: NEVER re-run
@@ -491,7 +531,7 @@ class DecodeServer:
                         raise WaveTimeout(
                             f"wave {self.waves} took {el * 1e3:.1f}ms > "
                             f"deadline {self.wave_deadline_s * 1e3:.1f}ms")
-                break
+                return logits, t0
             except EmberFault as e:
                 # typed faults only: anything else is a bug and propagates
                 self.serve_stats["wave_faults"] += 1
@@ -509,14 +549,15 @@ class DecodeServer:
                         self._finish(i, req, retired, status="failed",
                                      error=err)
                     self._recycle(retired)
-                    return sum(r is not None for r in self.active)
+                    return None, None
                 attempt += 1
                 self.serve_stats["wave_retries"] += 1
                 t0 = time.perf_counter()   # the retry gets a fresh budget
-        dt = time.perf_counter() - t0
-        self._ewma_wave_s = dt if self._ewma_wave_s is None else \
-            0.7 * self._ewma_wave_s + 0.3 * dt
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+
+    def _emit(self, c: int, lens: np.ndarray, nxt: np.ndarray,
+              emits: np.ndarray, retired: np.ndarray) -> None:
+        """After the wave: count it, expire lapsed slots, append each
+        emitting slot's token, retire finished requests and recycle."""
         self._pos += lens
         self.waves += 1
         self.serve_stats["waves"] += 1
@@ -558,7 +599,6 @@ class DecodeServer:
         # after the finish pass, so a drive whose requests all retire on
         # the final wave still arms the estimate before draining
         self._update_capacity()
-        return sum(r is not None for r in self.active)
 
     def _update_capacity(self) -> None:
         """Live capacity estimate under ``capacity_rps="auto"``: each wave
