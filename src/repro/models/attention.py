@@ -9,11 +9,13 @@ makes the 32k-prefill and 500k shapes compile inside HBM.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .common import ModelConfig, apply_rope, dense_init, rope_freqs
 
@@ -186,21 +188,104 @@ def attn_forward(p, x, cfg: ModelConfig, *, positions, causal=True,
     return o.reshape(b, s, h * hd) @ p["wo"]
 
 
-def slot_update(cache, new, pos):
-    """Per-slot cache write: ``cache`` (B,S,...), ``new`` (B,1,...) rows land
-    at each slot's own position ``pos`` (B,) — the vmapped analogue of the
-    single shared-position ``dynamic_update_slice`` that continuous batching
-    needs once every slot carries its own counter."""
-    zeros = (0,) * (cache.ndim - 2)
-    return jax.vmap(
-        lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p,) + zeros)
-    )(cache, new, pos)
+def _default_device():
+    return jax.devices()[0]
 
 
-def attn_decode(p, x, cfg: ModelConfig, cache, *, window=None):
-    """x (B,1,D); cache dict {k,v:(B,Smax,Hkv,hd), len:(B,) per-slot
-    position counters} (self-attn).  A scalar ``len`` (legacy whole-batch
-    caches) broadcasts through the same per-slot path bit-identically."""
+def _keep_layout(leaf):
+    """``leaf``, held in the layout the default device gives its shape.
+
+    Inside the serving wave's nested scans a row written at a dynamic
+    position would lead the compiler to lay the whole carried cache out for
+    the row and to relayout it at the wave's entry and exit: a TPU keeps a
+    stablelm-3b K/V leaf, head dim 80, with its sequence axis minor."""
+    device = _default_device()
+    try:
+        layout = device.client.get_default_layout(
+            jnp.dtype(leaf.dtype), tuple(leaf.shape), device)
+    except jax.errors.JaxRuntimeError:
+        return leaf
+    return with_layout_constraint(leaf, Layout.from_pjrt_layout(layout))
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerCache:
+    """One layer's decode cache, as a view into the cache tree it lives in.
+
+    With ``layer`` set the leaves of ``tree`` are layer-stacked (leading
+    axis = layer, as a scanned stack carries them) and this view is row
+    ``layer`` of each; with ``layer=None`` they are the layer's own.
+    ``active`` (B,) bool, or None for every slot, marks the slots this
+    micro-step feeds: an inactive slot's cache stays bit-identical.
+
+    :meth:`write` picks how to write by the cache's structure: a positional
+    cache (one with a per-slot ``len`` counter over a sequence axis — K/V,
+    int8 K/V with scales, the MLA latent) takes one row per slot at its own
+    position, in place, and keeps its device's layout (:func:`_keep_layout`);
+    a fixed-size recurrent state is written whole."""
+    tree: dict
+    layer: Optional[jax.Array] = None
+    active: Optional[jax.Array] = None
+
+    def __getitem__(self, name):
+        leaf = self.tree[name]
+        if self.layer is None:
+            return leaf
+        return jax.lax.dynamic_index_in_dim(leaf, self.layer, 0,
+                                            keepdims=False)
+
+    def __contains__(self, name):
+        return name in self.tree
+
+    def read(self) -> dict:
+        return {name: self[name] for name in self.tree}
+
+    def child(self, name) -> "LayerCache":
+        return dataclasses.replace(self, tree=self.tree[name])
+
+    def with_child(self, name, child: "LayerCache") -> "LayerCache":
+        return dataclasses.replace(self, tree={**self.tree,
+                                               name: child.tree})
+
+    def write(self, values: dict) -> "LayerCache":
+        """Positional cache: ``values[name]`` (B,1,...) is each slot's new
+        row, written at its position ``len`` (an inactive slot writes back
+        the row it holds there), and ``len`` advances for active slots.
+        Recurrent state: ``values`` is the whole new state."""
+        tree = dict(self.tree)
+        lead = () if self.layer is None else (self.layer,)
+        if "len" in self.tree:
+            length = self["len"]
+            b = next(iter(values.values())).shape[0]
+            pos = jnp.broadcast_to(length, (b,))
+            for name, rows in values.items():
+                leaf = tree[name]
+                for slot in range(b):
+                    start = lead + (slot, pos[slot]) + (0,) * (rows.ndim - 2)
+                    row = rows[slot:slot + 1]
+                    row = row.reshape((1,) * len(lead) + row.shape)
+                    if self.active is not None:
+                        row = jnp.where(self.active[slot], row,
+                                        jax.lax.dynamic_slice(leaf, start,
+                                                              row.shape))
+                    leaf = jax.lax.dynamic_update_slice(leaf, row, start)
+                tree[name] = _keep_layout(leaf)
+            values = {"len": length + 1}
+        for name, new in values.items():
+            if self.active is not None:
+                new = jnp.where(self.active.reshape(
+                    self.active.shape + (1,) * (new.ndim - 1)), new,
+                    self[name])
+            tree[name] = new if self.layer is None else \
+                jax.lax.dynamic_update_index_in_dim(tree[name], new,
+                                                    self.layer, 0)
+        return dataclasses.replace(self, tree=tree)
+
+
+def attn_decode(p, x, cfg: ModelConfig, cache: LayerCache, *, window=None):
+    """x (B,1,D); ``cache`` a :class:`LayerCache` over {k,v:(B,Smax,Hkv,hd),
+    len:(B,) per-slot position counters} (self-attn).  The new K/V rows are
+    written first, then attention reads the layer's cache."""
     b = x.shape[0]
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     pos = jnp.broadcast_to(cache["len"], (b,))
@@ -214,21 +299,15 @@ def attn_decode(p, x, cfg: ModelConfig, cache, *, window=None):
     if "k_scale" in cache:   # int8 quantized cache
         kq, ks = _quant_kv(k)
         vq, vs = _quant_kv(v)
-        k_cache = slot_update(cache["k"], kq, pos)
-        v_cache = slot_update(cache["v"], vq, pos)
-        ks_c = slot_update(cache["k_scale"], ks, pos)
-        vs_c = slot_update(cache["v_scale"], vs, pos)
-        kd = _dequant_kv(k_cache, ks_c, x.dtype)
-        vd = _dequant_kv(v_cache, vs_c, x.dtype)
-        o = decode_attention(q, kd, vd, pos + 1, window=window)
-        new_cache = {"k": k_cache, "v": v_cache, "k_scale": ks_c,
-                     "v_scale": vs_c, "len": cache["len"] + 1}
-        return o.reshape(b, 1, h * hd) @ p["wo"], new_cache
-    k_cache = slot_update(cache["k"], k, pos)
-    v_cache = slot_update(cache["v"], v, pos)
-    o = decode_attention(q, k_cache, v_cache, pos + 1, window=window)
-    new_cache = {"k": k_cache, "v": v_cache, "len": cache["len"] + 1}
-    return o.reshape(b, 1, h * hd) @ p["wo"], new_cache
+        cache = cache.write({"k": kq, "v": vq, "k_scale": ks,
+                             "v_scale": vs})
+        kd = _dequant_kv(cache["k"], cache["k_scale"], x.dtype)
+        vd = _dequant_kv(cache["v"], cache["v_scale"], x.dtype)
+    else:
+        cache = cache.write({"k": k, "v": v})
+        kd, vd = cache["k"], cache["v"]
+    o = decode_attention(q, kd, vd, pos + 1, window=window)
+    return o.reshape(b, 1, h * hd) @ p["wo"], cache
 
 
 def init_kv_cache(cfg: ModelConfig, batch, max_len, dtype):
@@ -300,7 +379,7 @@ def mla_forward(p, x, cfg: ModelConfig, *, positions, dense=False):
     return o.reshape(b, s, h * hd) @ p["wo"]
 
 
-def mla_decode(p, x, cfg: ModelConfig, cache):
+def mla_decode(p, x, cfg: ModelConfig, cache: LayerCache):
     """MLA decode caches the *latent* c (B,S,r) + k_rope — the 5-10× KV
     memory reduction that makes deepseek decode_32k fit."""
     b = x.shape[0]
@@ -314,8 +393,8 @@ def mla_decode(p, x, cfg: ModelConfig, cache):
                           cfg.rope_theta)
     qr = apply_rope(qr, cos, sin)
     kr = apply_rope(kr, cos, sin)
-    c_cache = slot_update(cache["c"], c.reshape(b, 1, r), pos)
-    kr_cache = slot_update(cache["kr"], kr.reshape(b, 1, rd), pos)
+    cache = cache.write({"c": c.reshape(b, 1, r), "kr": kr.reshape(b, 1, rd)})
+    c_cache, kr_cache = cache["c"], cache["kr"]
     # absorbed attention: score = qn·(c W_uk) + qr·kr
     kn = jnp.einsum("bsr,rhd->bshd", c_cache,
                     p["w_uk"].reshape(r, h, hd))
@@ -326,8 +405,7 @@ def mla_decode(p, x, cfg: ModelConfig, cache):
     pr = jax.nn.softmax(sc.astype(jnp.float32), axis=-1)
     v = jnp.einsum("bsr,rhd->bshd", c_cache, p["w_uv"].reshape(r, h, hd))
     o = jnp.einsum("bhqs,bshd->bqhd", pr.astype(v.dtype), v)
-    new_cache = {"c": c_cache, "kr": kr_cache, "len": cache["len"] + 1}
-    return o.reshape(b, 1, h * hd) @ p["wo"], new_cache
+    return o.reshape(b, 1, h * hd) @ p["wo"], cache
 
 
 def init_mla_cache(cfg: ModelConfig, batch, max_len, dtype):

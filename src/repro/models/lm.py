@@ -162,7 +162,11 @@ def init_block_cache(kind: str, cfg: ModelConfig, batch, max_len, dtype):
     raise ValueError(kind)
 
 
-def block_decode(kind: str, p, x, cfg: ModelConfig, cache, ctx: dict):
+def block_decode(kind: str, p, x, cfg: ModelConfig, cache: attn.LayerCache,
+                 ctx: dict):
+    """One decode micro-step of one block.  ``cache`` is the block's
+    :class:`~repro.models.attention.LayerCache`; returns (x, cache) with the
+    cache written through it."""
     eps = cfg.norm_eps
     if kind in ("dense", "dense_local", "moe", "mla", "shared_attn"):
         window = cfg.sliding_window if kind == "dense_local" else None
@@ -187,24 +191,16 @@ def block_decode(kind: str, p, x, cfg: ModelConfig, cache, ctx: dict):
         else:
             h = gated_mlp(h, p["mlp"], cfg.act)
         return x + h, cache
-    if kind == "mamba":
-        h, cache = ssm_mod.mamba_decode(p["mamba"],
-                                        rms_norm(x, p["norm1"], eps), cfg,
-                                        cache)
-        return x + h, cache
-    if kind == "mlstm":
-        h, cache = xlstm_mod.mlstm_decode(p["mlstm"],
-                                          rms_norm(x, p["norm1"], eps), cfg,
-                                          cache)
-        return x + h, cache
-    if kind == "slstm":
-        h, cache = xlstm_mod.slstm_decode(p["slstm"],
-                                          rms_norm(x, p["norm1"], eps), cfg,
-                                          cache)
-        return x + h, cache
+    recurrent = {"mamba": ssm_mod.mamba_decode,
+                 "mlstm": xlstm_mod.mlstm_decode,
+                 "slstm": xlstm_mod.slstm_decode}
+    if kind in recurrent:
+        h, state = recurrent[kind](p[kind], rms_norm(x, p["norm1"], eps), cfg,
+                                   cache.read())
+        return x + h, cache.write(state)
     if kind == "xdec":
         h = rms_norm(x, p["norm1"], eps)
-        h, self_c = attn.attn_decode(p["attn"], h, cfg, cache["self"])
+        h, self_c = attn.attn_decode(p["attn"], h, cfg, cache.child("self"))
         x = x + h
         h = rms_norm(x, p["norm_x"], eps)
         x = x + attn.attn_forward(p["xattn"], h, cfg,
@@ -212,7 +208,7 @@ def block_decode(kind: str, p, x, cfg: ModelConfig, cache, ctx: dict):
                                   causal=False, kv=ctx["enc_out"])
         h = rms_norm(x, p["norm2"], eps)
         return x + gated_mlp(h, p["mlp"], cfg.act), \
-            {"self": self_c, "enc_out": None}
+            cache.with_child("self", self_c)
     raise ValueError(kind)
 
 
@@ -514,7 +510,18 @@ class LM:
         slots feed a zero token and keep their caches (incl. the per-slot
         ``len`` counter) bit-identical — the property that makes
         prompt-chunked prefill equal whole-prompt prefill regardless of how
-        a wave's slots are staggered."""
+        a wave's slots are staggered.
+
+        The cache is written in place: the layer-stacked ``caches["scan"]``
+        rides in the layer scan's carry (the scan's inputs are the layer
+        params and index), and each layer writes through a
+        :class:`~repro.models.attention.LayerCache` view.  A positional
+        cache (K/V, int8 K/V, MLA latent) takes one row per slot at
+        ``(layer, slot, len)`` — an inactive slot writes back the row it
+        holds — before attention reads the layer; a recurrent state (mamba,
+        mLSTM, sLSTM) is small and written whole under the mask.  A
+        row-written leaf keeps its device's layout.  No op copies or
+        selects over a whole layer's positional cache."""
         cfg = self.cfg
         sh = self.shard
         if active is not None:
@@ -533,34 +540,30 @@ class LM:
             ctx["enc_out"] = batch_ctx["enc_out"]
         pattern = cfg.block_pattern
 
-        def keep_old(old, new):
-            if active is None:
-                return new
-            return jax.tree.map(
-                lambda o, n: jnp.where(
-                    active.reshape((active.shape[0],) + (1,) * (n.ndim - 1)),
-                    n, o), old, new)
-
-        def super_step(h, xs):
-            layer_params, layer_cache = xs
-            new_caches = []
+        def super_step(carry, xs):
+            h, stack = carry
+            layer_params, layer = xs
+            stack = list(stack)
             for i, kind in enumerate(pattern):
-                h, nc = block_decode(kind, layer_params[i], h, cfg,
-                                     layer_cache[i], ctx)
-                new_caches.append(keep_old(layer_cache[i], nc))
-            return h, tuple(new_caches)
+                h, view = block_decode(
+                    kind, layer_params[i], h, cfg,
+                    attn.LayerCache(stack[i], layer, active), ctx)
+                stack[i] = view.tree
+            return (h, tuple(stack)), None
 
         if cfg.n_super:
-            x, new_scan = jax.lax.scan(
-                super_step, x, (params["scan"], caches["scan"]),
+            (x, new_scan), _ = jax.lax.scan(
+                super_step, (x, caches["scan"]),
+                (params["scan"], jnp.arange(cfg.n_super)),
                 unroll=cfg.n_super if self.shard.cost_mode else 1)
         else:
             new_scan = ()
         new_rest = []
         for i, kind in enumerate(cfg.remainder_pattern):
-            x, nc = block_decode(kind, params["rest"][i], x, cfg,
-                                 caches["rest"][i], ctx)
-            new_rest.append(keep_old(caches["rest"][i], nc))
+            x, view = block_decode(
+                kind, params["rest"][i], x, cfg,
+                attn.LayerCache(caches["rest"][i], None, active), ctx)
+            new_rest.append(view.tree)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = ee.logits(x, params["embed"])[..., :cfg.vocab_size]
         return logits, {"scan": new_scan, "rest": tuple(new_rest)}
@@ -575,6 +578,12 @@ class LM:
         ``active = t < lens`` mask, splitting a prompt across waves of any
         chunk size replays the *same* micro-step sequence as one big wave —
         prompt-chunked prefill is bit-identical to whole-prompt prefill.
+
+        The micro-step scan carries ``caches``, in their device's layout,
+        and each micro-step writes one row per slot per layer into them in
+        place (:meth:`decode_step`): with ``caches`` donated a wave copies
+        no whole cache, not even at its entry or exit, and the only
+        whole-layer access is decode attention's read.
 
         Returns ``(logits (B,1,V) at each slot's last valid token, caches)``.
         """

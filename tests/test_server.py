@@ -270,34 +270,138 @@ def test_chunked_prefill_bit_identical(arch):
             np.testing.assert_array_equal(np.asarray(lw), np.asarray(lc))
 
 
-def test_wave_step_matches_decode_step_replay():
+def _decode_cases():
+    from repro.configs import list_archs
+    return [pytest.param(a, "model", id=a) for a in list_archs()] + \
+        [pytest.param("stablelm-3b", "int8", id="stablelm-3b-int8")]
+
+
+@pytest.mark.parametrize("arch,kv", _decode_cases())
+def test_wave_step_matches_decode_step_replay(arch, kv):
     """wave_step IS the fused masked decode loop: replaying the same
     tokens through per-step decode_step calls (the legacy serving path)
-    produces bit-identical logits and caches."""
-    cfg = get_reduced("stablelm-3b")
+    produces bit-identical logits and caches, for every architecture's
+    cache kind (K/V, int8 K/V, MLA latent, recurrent state, cross-attn
+    decoder) — and a slot the wave does not feed keeps every cache leaf
+    bit-identical, written in place or not."""
+    import dataclasses
+    cfg = dataclasses.replace(get_reduced(arch), kv_cache_dtype=kv)
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))
-    b, L = 2, 6
+    b, L = 3, 6
+    ctx = None
+    if cfg.enc_layers:
+        ctx = {"enc_out": jax.random.normal(jax.random.PRNGKey(3),
+                                            (b, 16, cfg.d_model))}
+    wave = jax.jit(lm.wave_step)
+    # a warm cache, so an untouched slot has state to keep
+    warm = jax.random.randint(jax.random.PRNGKey(1), (b, 3), 0,
+                              cfg.vocab_size)
+    _, start = wave(params, warm, jnp.array([3, 2, 3], jnp.int32),
+                    lm.init_caches(b, 16), ctx)
     toks = jax.random.randint(jax.random.PRNGKey(2), (b, L), 0,
                               cfg.vocab_size)
-    lens = jnp.array([6, 4], jnp.int32)
-    lg_wave, cache_wave = jax.jit(lm.wave_step)(
-        params, toks, lens, lm.init_caches(b, 16))
-    caches = lm.init_caches(b, 16)
+    lens = jnp.array([6, 4, 0], jnp.int32)
+    lg_wave, cache_wave = wave(params, toks, lens, start, ctx)
+    caches = start
     step = jax.jit(lm.decode_step)
     lg_by_slot = [None] * b
     for t in range(L):
-        lg, caches = step(params, toks[:, t:t + 1], caches, None,
+        lg, caches = step(params, toks[:, t:t + 1], caches, ctx,
                           jnp.asarray(t < np.asarray(lens)))
         for i in range(b):
             if t == int(lens[i]) - 1:
                 lg_by_slot[i] = lg[i]
     for i in range(b):
-        np.testing.assert_array_equal(np.asarray(lg_by_slot[i]),
-                                      np.asarray(lg_wave[i]))
-    for lw, lc in zip(jax.tree.leaves(cache_wave),
-                      jax.tree.leaves(caches)):
+        if lg_by_slot[i] is not None:
+            np.testing.assert_array_equal(np.asarray(lg_by_slot[i]),
+                                          np.asarray(lg_wave[i]))
+    assert jax.tree.structure(cache_wave) == jax.tree.structure(start)
+    for lw, lc in zip(jax.tree.leaves(cache_wave), jax.tree.leaves(caches)):
         np.testing.assert_array_equal(np.asarray(lw), np.asarray(lc))
+    # batch is axis 1 of scan-stacked leaves (layer first), 0 of the rest
+    for part, axis in (("scan", 1), ("rest", 0)):
+        for lw, l0 in zip(jax.tree.leaves(cache_wave[part]),
+                          jax.tree.leaves(start[part])):
+            np.testing.assert_array_equal(np.take(np.asarray(lw), 2, axis),
+                                          np.take(np.asarray(l0), 2, axis))
+
+
+@pytest.mark.parametrize("arch,kv", _decode_cases())
+def test_wave_step_is_independent_of_cache_layout(arch, kv, monkeypatch):
+    """The wave holds every cache leaf it writes in its device's layout (on
+    a TPU, K/V with head dim 80 keep the sequence axis minor).  Held with
+    every non-leading axis reversed instead, two ragged waves give the same
+    logits and caches as in the CPU's row-major layout.  (MLA up-projects
+    its latent cache with a matmul whose summation order the compiler picks
+    by the operand's layout: equal there to rounding.)"""
+    import dataclasses
+    from jax.experimental.layout import Layout, with_layout_constraint
+    from repro.models import attention
+    cfg = dataclasses.replace(get_reduced(arch), kv_cache_dtype=kv)
+    lm = LM(cfg)
+    params = lm.init(jax.random.PRNGKey(0))
+    b = 3
+    ctx = None
+    if cfg.enc_layers:
+        ctx = {"enc_out": jax.random.normal(jax.random.PRNGKey(3),
+                                            (b, 16, cfg.d_model))}
+    waves = [(jax.random.randint(jax.random.PRNGKey(1), (b, 6), 0,
+                                 cfg.vocab_size),
+              jnp.array([6, 3, 5], jnp.int32)),
+             (jax.random.randint(jax.random.PRNGKey(2), (b, 6), 0,
+                                 cfg.vocab_size),
+              jnp.array([4, 0, 6], jnp.int32))]
+
+    def serve():
+        caches, out = lm.init_caches(b, 16), []
+        wave = jax.jit(lm.wave_step)
+        for toks, lens in waves:
+            lg, caches = wave(params, toks, lens, caches, ctx)
+            out.append(lg)
+        return out, caches
+
+    def reversed_layout(leaf):
+        order = (0,) + tuple(reversed(range(1, leaf.ndim)))
+        return with_layout_constraint(leaf, Layout(order))
+
+    want = serve()
+    monkeypatch.setattr(attention, "_keep_layout", reversed_layout)
+    got = serve()
+    tol = 1e-5 if "mla" in cfg.block_pattern else 0
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_allclose(np.asarray(w), np.asarray(g), rtol=tol,
+                                   atol=tol)
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+def test_wave_step_writes_cache_in_place(kv):
+    """Compile-time guard against whole-cache copies in the serving wave:
+    the donated cache is written a row at a time, so the compiler's temp
+    buffer does not grow with the cache.  Four slots of 64 positions, an
+    8-token wave, the reduced stablelm-3b at 2 and at 8 layers: from 2 to 8
+    the temp grows by under a quarter of what the cache grows by (a wave
+    that slices, selects or copies the whole cache grows it by as much as
+    the cache or more).  Depth is the axis, not the whole cache: the CPU
+    compiler materialises one layer's K and V as attention's operands, half
+    the cache at 2 layers, the same bytes at any depth."""
+    import dataclasses
+
+    def temp_and_cache(layers):
+        cfg = dataclasses.replace(get_reduced("stablelm-3b"),
+                                  num_layers=layers, kv_cache_dtype=kv)
+        lm = LM(cfg)
+        params = jax.eval_shape(lm.init, jax.random.PRNGKey(0))
+        caches = jax.eval_shape(lambda: lm.init_caches(4, 64))
+        compiled = jax.jit(lm.wave_step, donate_argnums=(3,)).lower(
+            params, jax.ShapeDtypeStruct((4, 8), jnp.int32),
+            jax.ShapeDtypeStruct((4,), jnp.int32), caches).compile()
+        return (compiled.memory_analysis().temp_size_in_bytes,
+                sum(x.size * x.dtype.itemsize
+                    for x in jax.tree.leaves(caches)))
+
+    (t2, c2), (t8, c8) = temp_and_cache(2), temp_and_cache(8)
+    assert t8 - t2 < (c8 - c2) / 4, (t2, t8, c2, c8)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,12 @@
-"""The Pallas kernels compile for a TPU v5e at the widths the system serves.
+"""The Pallas kernels and the serving wave compile for a TPU v5e at the
+widths the system serves.
 
-Nothing runs: each kernel is lowered and compiled for a described (not
+Nothing runs: each program is lowered and compiled for a described (not
 attached) v5e chip, which is what the chip's compiler would accept or
-refuse.  Each must lower to a ``tpu_custom_call`` (a Mosaic kernel, not the
-interpreter) and must not stage a copy of its table: the compiler's temp
-buffer stays a small fraction of the table's bytes.
+refuse.  Each kernel must lower to a ``tpu_custom_call`` (a Mosaic kernel,
+not the interpreter) and must not stage a copy of its table: the
+compiler's temp buffer stays a small fraction of the table's bytes.  The
+serving wave must write its KV cache in place.
 """
 import jax
 import jax.numpy as jnp
@@ -99,3 +101,47 @@ def test_fusedmm_compiles_for_v5e(one_chip):
     text, temp = _compile(step, s((nodes, width), jnp.float32),
                           s((nodes + 1,), jnp.int32), s((nnz,), jnp.int32))
     _check(text, temp, nodes * width * 4)
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+def test_serving_wave_writes_cache_in_place_on_v5e(topo, one_chip,
+                                                   monkeypatch, kv):
+    """stablelm-3b widths (2 of its 32 layers), 4 slots x 2048 positions,
+    an 8-token prefill wave over a donated cache in the chip's default
+    layout (sequence minor: head dim 80 is no multiple of 128): every op
+    that yields a whole layer-stacked cache leaf is a row write in place —
+    no relayout copy at the wave's entry or exit, no whole-layer slice,
+    select or copy."""
+    import dataclasses
+    import re
+    from repro.configs import get_config
+    from repro.models import LM
+    from repro.models import attention
+    # the layouts the wave adapts to are the described chip's
+    monkeypatch.setattr(attention, "_default_device",
+                        lambda: topo.devices[0])
+    cfg = dataclasses.replace(get_config("stablelm-3b"), num_layers=2,
+                              kv_cache_dtype=kv)
+    lm = LM(cfg)
+    slots, max_len = 4, 2048
+    on_chip = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on_chip(jax.eval_shape(lm.init, jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(lambda: lm.init_caches(slots, max_len)))
+    text = jax.jit(lm.wave_step, donate_argnums=(3,)).lower(
+        params, on_chip(jax.ShapeDtypeStruct((slots, 8), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        caches).compile().as_text()
+    # a layer-stacked leaf with a sequence axis
+    stack = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[(" +
+                       f"{cfg.n_super},{slots}," + r"[\d,]+)\]\S* ([\w-]+)\(")
+    ops = [(m.group(2), m.group(0)) for m in map(stack.match,
+                                                  text.splitlines())
+           if m and str(max_len) in m.group(1).split(",")]
+    moved = [op for op, _ in ops
+             if op not in ("parameter", "get-tuple-element", "tuple")]
+    assert "dynamic-update-slice" in moved, moved
+    assert set(moved) == {"dynamic-update-slice"}, \
+        [line for op, line in ops if op not in
+         ("parameter", "get-tuple-element", "tuple", "dynamic-update-slice")]
