@@ -36,15 +36,15 @@ def main():
 
         # 2) MoE dispatch = the SLS-class embedding op, with EP all-to-all.
         # capacity_factor=8 → no token drops, so the EP layout must agree
-        # bit-for-bit with the single-device reference (at production
-        # capacity 1.25 the two layouts drop *different* tokens — expected).
+        # with the single-device layer, which is dropless (at production
+        # capacity 1.25 the all-to-all path drops tokens — expected).
         import dataclasses
         cfg = dataclasses.replace(get_reduced("qwen3-moe-235b-a22b"),
                                   capacity_factor=8.0)
         p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (B, 16, cfg.d_model))
-        ref, _ = moe_mod.moe_ffn(p, x, cfg, mesh=None)
-        out, aux = moe_mod.moe_ffn(
+        ref = moe_mod.moe_ffn(p, x, cfg, mesh=None)[0]
+        out, aux, _ = moe_mod.moe_ffn(
             p, jax.device_put(x, NamedSharding(mesh, P("data", None, None))),
             cfg, mesh=mesh)
         print(f"EP MoE dispatch (all-to-all over {model_par} expert shards): "

@@ -42,7 +42,7 @@ def param_spec(path, leaf) -> P:
     stacked = names[0] in ("scan", "enc_scan")
     in_moe = "moe" in names and "shared" not in names
 
-    if name == "embed":
+    if name in ("embed", "lm_head"):
         base = ("model", None)
     elif name in REPL or leaf.ndim <= 1:
         base = (None,) * (leaf.ndim - (1 if stacked else 0))

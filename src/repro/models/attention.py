@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from .common import ModelConfig, apply_rope, dense_init, rope_freqs
+from .common import (ModelConfig, apply_rope, dense_init, init_rms,
+                     rms_norm, rope_freqs, yarn_mscale, yarn_rope)
 
 NEG_INF = -1e30
 
@@ -26,7 +27,7 @@ NEG_INF = -1e30
 # Blockwise multi-query/grouped attention (training & prefill)
 # ---------------------------------------------------------------------------
 
-def dense_attention(q, k, v, *, causal=True, window=None):
+def dense_attention(q, k, v, *, causal=True, window=None, scale=None):
     """Plain O(S²)-memory attention. COST-MODE / small-shape path: flop-
     identical to the blockwise path but scan-free, so XLA cost analysis
     counts every block (scan bodies are counted once, see roofline docs)."""
@@ -36,7 +37,7 @@ def dense_attention(q, k, v, *, causal=True, window=None):
     g = h // hkv
     qg = q.reshape(b, sq, hkv, g, d)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
-                   preferred_element_type=jnp.float32) * d ** -0.5
+                   preferred_element_type=jnp.float32) * (scale or d ** -0.5)
     qp = jnp.arange(sq)[:, None]
     kp = jnp.arange(sk)[None, :]
     mask = jnp.ones((sq, sk), bool)
@@ -53,23 +54,26 @@ def dense_attention(q, k, v, *, causal=True, window=None):
 
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None, chunk: int = 512,
-                        banded: bool = True, dense: bool = False):
+                        banded: bool = True, dense: bool = False,
+                        scale: Optional[float] = None):
     """q (B,Sq,H,D); k,v (B,Sk,Hkv,D); GQA via head grouping. -> (B,Sq,H,D)
 
     ``banded=True`` with a window slides a static band of KV chunks along
     the diagonal (computes only ceil(window/chunk)+1 chunks per q chunk)
     instead of masking the full row — the O(S·w) sliding-window path.
-    ``dense=True`` switches to the scan-free cost-mode path.
+    ``dense=True`` switches to the scan-free cost-mode path.  ``scale``
+    defaults to ``D^-0.5``.
     """
     if dense:
-        return dense_attention(q, k, v, causal=causal, window=window)
+        return dense_attention(q, k, v, causal=causal, window=window,
+                               scale=scale)
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     dv = v.shape[-1]                        # MLA: value dim ≠ qk dim
     g = h // hkv
     assert sq % chunk == 0 and sk % chunk == 0, (sq, sk, chunk)
     nq, nk = sq // chunk, sk // chunk
-    scale = d ** -0.5
+    scale = scale or d ** -0.5
 
     qc = q.reshape(b, nq, chunk, hkv, g, d)
     kc = k.reshape(b, nk, chunk, hkv, d)
@@ -352,6 +356,7 @@ def init_mla(key, cfg: ModelConfig, dtype):
     return {
         "wq": dense_init(ks[0], (d, h * (hd + rd)), dtype),
         "w_dkv": dense_init(ks[1], (d, r), dtype),
+        "kv_norm": init_rms(None, r, dtype),
         "w_uk": dense_init(ks[2], (r, h * hd), dtype),
         "w_uv": dense_init(ks[3], (r, h * hd), dtype),
         "w_kr": dense_init(ks[4], (d, rd), dtype),
@@ -359,52 +364,82 @@ def init_mla(key, cfg: ModelConfig, dtype):
     }
 
 
-def mla_forward(p, x, cfg: ModelConfig, *, positions, dense=False):
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: ``(hd + rd)^-0.5`` times YaRN's
+    ``mscale(factor, mscale_all_dim)²``."""
+    return (cfg.hd + cfg.rope_head_dim) ** -0.5 * \
+        yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+
+
+def _mla_project(p, x, cfg: ModelConfig, positions):
+    """x (B,S,D) -> q_nope (B,S,H,hd), roped q_pe (B,S,H,rd), the normed
+    latent c (B,S,r) and the roped shared k_pe (B,S,rd).
+
+    Rope rotates interleaved pairs ``(2i, 2i+1)``; DeepSeek permutes each
+    rope head to ``(0, 2, 4, …, 1, 3, 5, …)`` and rotates halves, which is
+    the same rotation with the dims permuted alike in q and k, so every
+    score ``q_pe · k_pe`` is the same."""
     b, s, _ = x.shape
     h, hd, rd = cfg.num_heads, cfg.hd, cfg.rope_head_dim
     q = (x @ p["wq"]).reshape(b, s, h, hd + rd)
-    qn, qr = q[..., :hd], q[..., hd:]
-    c = x @ p["w_dkv"]                                 # (b,s,r) latent KV
+    c = rms_norm(x @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)
+    kr = (x @ p["w_kr"]).reshape(b, s, 1, rd)
+    cos, sin = yarn_rope(positions, cfg, rd)
+    qr = apply_rope(q[..., hd:], cos, sin)
+    kr = apply_rope(kr, cos, sin)
+    return q[..., :hd], qr, c, kr.reshape(b, s, rd)
+
+
+def mla_forward(p, x, cfg: ModelConfig, *, positions, dense=False):
+    """Prefill and training: the latent is up-projected to per-head K and V
+    and attention runs in the heads' own dims."""
+    b, s, _ = x.shape
+    h, hd, rd = cfg.num_heads, cfg.hd, cfg.rope_head_dim
+    qn, qr, c, kr = _mla_project(p, x, cfg, positions)
     kn = (c @ p["w_uk"]).reshape(b, s, h, hd)
     v = (c @ p["w_uv"]).reshape(b, s, h, hd)
-    kr = (x @ p["w_kr"]).reshape(b, s, 1, rd)
-    cos, sin = rope_freqs(positions, rd, cfg.rope_theta)
-    qr = apply_rope(qr, cos, sin)
-    kr = apply_rope(kr, cos, sin)
     qf = jnp.concatenate([qn, qr], axis=-1)
-    kf = jnp.concatenate([kn, jnp.broadcast_to(kr, (b, s, h, rd))], axis=-1)
+    kf = jnp.concatenate(
+        [kn, jnp.broadcast_to(kr[:, :, None], (b, s, h, rd))], axis=-1)
     from .common import pick_chunk
     chunk = pick_chunk(s, min(cfg.attn_chunk, s))
-    o = blockwise_attention(qf, kf, v, causal=True, chunk=chunk, dense=dense)
+    o = blockwise_attention(qf, kf, v, causal=True, chunk=chunk, dense=dense,
+                            scale=mla_scale(cfg))
     return o.reshape(b, s, h * hd) @ p["wo"]
 
 
 def mla_decode(p, x, cfg: ModelConfig, cache: LayerCache):
-    """MLA decode caches the *latent* c (B,S,r) + k_rope — the 5-10× KV
-    memory reduction that makes deepseek decode_32k fit."""
+    """One decode micro-step of MLA in latent space.  The cache holds only
+    the latent ``c`` (B,Smax,r) and the shared ``k_pe`` (B,Smax,rd), one
+    ``r + rd``-wide row per position, written through ``cache``.  W_UK is
+    absorbed into the query and W_UV into the output, so attention reads
+    each cached row once and never expands it into per-head K or V:
+
+        q_lat = q_nope · W_UKᵀ            (B,H,r)
+        score = q_lat · c + q_pe · k_pe
+        o     = (softmax(score) · c) · W_UV
+    """
     b = x.shape[0]
-    h, hd, rd, r = cfg.num_heads, cfg.hd, cfg.rope_head_dim, cfg.kv_lora_rank
+    h, hd, r = cfg.num_heads, cfg.hd, cfg.kv_lora_rank
     pos = jnp.broadcast_to(cache["len"], (b,))
-    q = (x @ p["wq"]).reshape(b, 1, h, hd + rd)
-    qn, qr = q[..., :hd], q[..., hd:]
-    c = x @ p["w_dkv"]
-    kr = (x @ p["w_kr"]).reshape(b, 1, 1, rd)
-    cos, sin = rope_freqs(pos[:, None].astype(jnp.float32), rd,
-                          cfg.rope_theta)
-    qr = apply_rope(qr, cos, sin)
-    kr = apply_rope(kr, cos, sin)
-    cache = cache.write({"c": c.reshape(b, 1, r), "kr": kr.reshape(b, 1, rd)})
+    qn, qr, c, kr = _mla_project(p, x, cfg, pos[:, None])
+    cache = cache.write({"c": c, "kr": kr})
     c_cache, kr_cache = cache["c"], cache["kr"]
-    # absorbed attention: score = qn·(c W_uk) + qr·kr
-    kn = jnp.einsum("bsr,rhd->bshd", c_cache,
-                    p["w_uk"].reshape(r, h, hd))
-    sc = (jnp.einsum("bqhd,bshd->bhqs", qn, kn) +
-          jnp.einsum("bqhd,bsd->bhqs", qr, kr_cache)) * (hd + rd) ** -0.5
+    f32 = jnp.float32
+    q_lat = jnp.einsum("bhd,rhd->bhr", qn[:, 0],
+                       p["w_uk"].reshape(r, h, hd), preferred_element_type=f32)
+    sc = jnp.einsum("bhr,bsr->bhs", q_lat.astype(c_cache.dtype), c_cache,
+                    preferred_element_type=f32) + \
+        jnp.einsum("bhd,bsd->bhs", qr[:, 0], kr_cache,
+                   preferred_element_type=f32)
+    sc = sc * mla_scale(cfg)
     mask = jnp.arange(c_cache.shape[1])[None, :] <= pos[:, None]
-    sc = jnp.where(mask[:, None, None, :], sc, NEG_INF)
-    pr = jax.nn.softmax(sc.astype(jnp.float32), axis=-1)
-    v = jnp.einsum("bsr,rhd->bshd", c_cache, p["w_uv"].reshape(r, h, hd))
-    o = jnp.einsum("bhqs,bshd->bqhd", pr.astype(v.dtype), v)
+    sc = jnp.where(mask[:, None, :], sc, NEG_INF)
+    pr = jax.nn.softmax(sc, axis=-1)
+    o_lat = jnp.einsum("bhs,bsr->bhr", pr.astype(c_cache.dtype), c_cache,
+                       preferred_element_type=f32)
+    o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype),
+                   p["w_uv"].reshape(r, h, hd))
     return o.reshape(b, 1, h * hd) @ p["wo"], cache
 
 

@@ -5,7 +5,9 @@ of *super-blocks* (``block_pattern``) repeated ``num_layers //
 len(pattern)`` times via ``jax.lax.scan`` (keeping HLO size O(1) in depth —
 required for 94-layer dry-runs and the right structure at cluster scale),
 plus an unscanned remainder when the depth is not a multiple of the
-pattern.
+pattern.  ``first_k_dense`` leading layers (DeepSeek's
+``first_k_dense_replace``) come before the scan, unscanned: MLA attention
+with a dense MLP of width ``d_ff``.
 
 Block kinds:
 
@@ -14,6 +16,7 @@ Block kinds:
 ``dense_local``same, sliding-window attention
 ``moe``        GQA attention + mixture-of-experts FFN (EP dispatch)
 ``mla``        DeepSeek MLA attention (compressed KV) + MoE FFN
+``mla_dense``  DeepSeek MLA attention + gated MLP (a leading dense layer)
 ``mlstm``      xLSTM mLSTM block (matrix memory, chunked linear attention)
 ``slstm``      xLSTM sLSTM block (scalar memory, recurrent scan)
 ``mamba``      Mamba2 SSD block (chunked state-space scan)
@@ -25,6 +28,7 @@ Block kinds:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -54,9 +58,21 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalise the top-k router weights
+    experts_held: int = 0            # experts 0..held-1 live here; 0 = all
+    # leading layers with a dense MLP (width d_ff) before the block pattern
+    first_k_dense: int = 0
+    tie_embeddings: bool = True      # False: a separate lm_head
     # mla
     kv_lora_rank: int = 0
     rope_head_dim: int = 64
+    # YaRN rope scaling (DeepSeek-V2 ``rope_scaling``); factor 1 is none
+    rope_factor: float = 1.0
+    rope_original_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # ssm / xlstm
     ssm_state: int = 64
     ssm_chunk: int = 256
@@ -93,12 +109,23 @@ class ModelConfig:
 
     @property
     def n_super(self) -> int:
-        return self.num_layers // len(self.block_pattern)
+        return (self.num_layers - self.first_k_dense) // \
+            len(self.block_pattern)
 
     @property
     def remainder_pattern(self) -> Tuple[str, ...]:
-        r = self.num_layers % len(self.block_pattern)
+        r = (self.num_layers - self.first_k_dense) % len(self.block_pattern)
         return self.block_pattern[:r]
+
+    @property
+    def lead_pattern(self) -> Tuple[str, ...]:
+        """Block kinds of the leading dense layers (DeepSeek's: MLA
+        attention and a dense MLP)."""
+        return ("mla_dense",) * self.first_k_dense
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 # ---------------------------------------------------------------------------
@@ -130,6 +157,44 @@ def rope_freqs(positions, head_dim, theta, rotary_pct=1.0):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """DeepSeek's ``yarn_get_mscale``: 0.1·m·ln(s) + 1 (1 for s ≤ 1)."""
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int):
+    """Inverse frequencies of ``dim`` rotary dims under YaRN, as DeepSeek's
+    ``DeepseekV2YarnRotaryEmbedding`` makes them: the pairs below
+    ``yarn_find_correction_range``'s ``low`` keep ``freq``, those above
+    ``high`` take ``freq / factor``, with a linear ramp between."""
+    freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, dim, 2,
+                                                dtype=jnp.float32) / dim))
+    if cfg.rope_factor <= 1:
+        return freq
+
+    def corr(rotations):
+        return dim * math.log(cfg.rope_original_len /
+                              (rotations * 2 * math.pi)) / \
+            (2 * math.log(cfg.rope_theta))
+    low = max(math.floor(corr(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(corr(cfg.rope_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) /
+                    (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return freq / cfg.rope_factor * (1.0 - keep) + freq * keep
+
+
+def yarn_rope(positions, cfg: ModelConfig, dim: int):
+    """positions (..., S) -> (cos, sin) (..., S, dim/2) under YaRN, scaled
+    by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    ang = positions[..., None].astype(jnp.float32) * yarn_inv_freq(cfg, dim)
+    m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / \
+        yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
 def apply_rope(x, cos, sin, rotary_pct=1.0):
     """x (..., S, H, D); cos/sin (..., S, rot/2)."""
     d = x.shape[-1]
@@ -149,7 +214,6 @@ _ACTS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu}
 
 def pick_chunk(s: int, preferred: int) -> int:
     """Largest chunk ≤ preferred that divides s (gcd fallback)."""
-    import math
     return preferred if s % preferred == 0 else math.gcd(s, preferred)
 
 

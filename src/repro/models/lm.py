@@ -7,6 +7,9 @@ structure at cluster scale.  Heterogeneous stacks (gemma3's 5 local : 1
 global, zamba2's 5 mamba : 1 shared-attention) are expressed by the pattern;
 depths not divisible by the pattern get an unscanned remainder stack.
 
+Leading dense layers (``cfg.first_k_dense``, DeepSeek's layer 0) form an
+unscanned ``lead`` stack before the scan, in params and caches alike.
+
 Zamba2's *shared* attention block (one set of weights reused at every
 occurrence) lives outside the scanned params and enters the scan body by
 closure — parameter sharing that scan's per-step slicing cannot express.
@@ -52,6 +55,11 @@ def init_block(kind: str, key, cfg: ModelConfig, dtype):
                 "attn": attn.init_mla(ks[1], cfg, dtype),
                 "norm2": init_rms(ks[2], cfg.d_model, dtype),
                 "moe": moe_mod.init_moe(ks[3], cfg, dtype)}
+    if kind == "mla_dense":
+        return {"norm1": init_rms(ks[0], cfg.d_model, dtype),
+                "attn": attn.init_mla(ks[1], cfg, dtype),
+                "norm2": init_rms(ks[2], cfg.d_model, dtype),
+                "mlp": init_mlp(ks[3], cfg.d_model, cfg.d_ff, dtype)}
     if kind == "mamba":
         return {"norm1": init_rms(ks[0], cfg.d_model, dtype),
                 "mamba": ssm_mod.init_mamba(ks[1], cfg, dtype)}
@@ -81,12 +89,13 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, ctx: dict):
     """Full-sequence forward. Returns (x, aux_loss)."""
     eps = cfg.norm_eps
     aux = jnp.zeros((), jnp.float32)
-    if kind in ("dense", "dense_local", "enc_dense", "moe", "mla"):
+    if kind in ("dense", "dense_local", "enc_dense", "moe", "mla",
+                "mla_dense"):
         window = cfg.sliding_window if kind == "dense_local" else None
         causal = kind != "enc_dense"
         h = rms_norm(x, p["norm1"], eps)
         dense = ctx.get("cost_mode", False)
-        if kind == "mla":
+        if kind in ("mla", "mla_dense"):
             h = attn.mla_forward(p["attn"], h, cfg,
                                  positions=ctx["positions"], dense=dense)
         else:
@@ -96,9 +105,10 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, ctx: dict):
         x = x + h
         h = rms_norm(x, p["norm2"], eps)
         if kind in ("moe", "mla"):
-            h, aux = moe_mod.moe_ffn(p["moe"], h, cfg, mesh=ctx.get("mesh"),
-                                     ep_axis=ctx.get("ep_axis"),
-                                     data_axes=ctx.get("data_axes", ()))
+            h, aux, _ = moe_mod.moe_ffn(
+                p["moe"], h, cfg, mesh=ctx.get("mesh"),
+                ep_axis=ctx.get("ep_axis"),
+                data_axes=ctx.get("data_axes", ()))
         else:
             h = gated_mlp(h, p["mlp"], cfg.act)
         return x + h, aux
@@ -146,7 +156,7 @@ def init_block_cache(kind: str, cfg: ModelConfig, batch, max_len, dtype):
         alloc = min(max_len, win) if win else max_len
         return attn.init_kv_cache(cfg, batch, alloc if False else max_len,
                                   dtype)
-    if kind == "mla":
+    if kind in ("mla", "mla_dense"):
         return attn.init_mla_cache(cfg, batch, max_len, dtype)
     if kind == "mamba":
         return ssm_mod.init_mamba_cache(cfg, batch, dtype)
@@ -165,13 +175,16 @@ def init_block_cache(kind: str, cfg: ModelConfig, batch, max_len, dtype):
 def block_decode(kind: str, p, x, cfg: ModelConfig, cache: attn.LayerCache,
                  ctx: dict):
     """One decode micro-step of one block.  ``cache`` is the block's
-    :class:`~repro.models.attention.LayerCache`; returns (x, cache) with the
-    cache written through it."""
+    :class:`~repro.models.attention.LayerCache`; returns (x, cache, counts)
+    with the cache written through it, and an MoE block's expert counters
+    (:func:`~repro.models.moe.moe_share`) over the slots the cache view
+    marks active (None for other blocks)."""
     eps = cfg.norm_eps
-    if kind in ("dense", "dense_local", "moe", "mla", "shared_attn"):
+    if kind in ("dense", "dense_local", "moe", "mla", "mla_dense",
+                "shared_attn"):
         window = cfg.sliding_window if kind == "dense_local" else None
         h = rms_norm(x, p["norm1"], eps)
-        if kind == "mla":
+        if kind in ("mla", "mla_dense"):
             h, cache = attn.mla_decode(p["attn"], h, cfg, cache)
         elif kind == "shared_attn":
             h, cache = attn.attn_decode(ctx["shared_params"]["attn"], h, cfg,
@@ -182,22 +195,24 @@ def block_decode(kind: str, p, x, cfg: ModelConfig, cache: attn.LayerCache,
                                         window=window)
         x = x + h
         h = rms_norm(x, p["norm2"], eps)
+        counts = None
         if kind in ("moe", "mla"):
-            h, _ = moe_mod.moe_ffn(p["moe"], h, cfg, mesh=ctx.get("mesh"),
-                                   ep_axis=ctx.get("ep_axis"),
-                                   data_axes=ctx.get("data_axes", ()))
+            h, _, counts = moe_mod.moe_ffn(
+                p["moe"], h, cfg, mesh=ctx.get("mesh"),
+                ep_axis=ctx.get("ep_axis"),
+                data_axes=ctx.get("data_axes", ()), active=cache.active)
         elif kind == "shared_attn":
             h = gated_mlp(h, ctx["shared_params"]["mlp"], cfg.act)
         else:
             h = gated_mlp(h, p["mlp"], cfg.act)
-        return x + h, cache
+        return x + h, cache, counts
     recurrent = {"mamba": ssm_mod.mamba_decode,
                  "mlstm": xlstm_mod.mlstm_decode,
                  "slstm": xlstm_mod.slstm_decode}
     if kind in recurrent:
         h, state = recurrent[kind](p[kind], rms_norm(x, p["norm1"], eps), cfg,
                                    cache.read())
-        return x + h, cache.write(state)
+        return x + h, cache.write(state), None
     if kind == "xdec":
         h = rms_norm(x, p["norm1"], eps)
         h, self_c = attn.attn_decode(p["attn"], h, cfg, cache.child("self"))
@@ -208,7 +223,7 @@ def block_decode(kind: str, p, x, cfg: ModelConfig, cache: attn.LayerCache,
                                   causal=False, kv=ctx["enc_out"])
         h = rms_norm(x, p["norm2"], eps)
         return x + gated_mlp(h, p["mlp"], cfg.act), \
-            cache.with_child("self", self_c)
+            cache.with_child("self", self_c), None
     raise ValueError(kind)
 
 
@@ -233,6 +248,19 @@ class LM:
         self.cfg = cfg
         self.shard = shard or ShardCtx()
 
+    @property
+    def has_experts(self) -> bool:
+        """Whether a block routes over experts (an MoE model)."""
+        cfg = self.cfg
+        pattern = tuple(cfg.block_pattern) + tuple(cfg.remainder_pattern)
+        return bool(cfg.num_experts) and any(k in ("moe", "mla")
+                                             for k in pattern)
+
+    def head(self, params):
+        """The output head's (vocab, D) matrix: the embedding when tied."""
+        return params["embed"] if self.cfg.tie_embeddings \
+            else params["lm_head"]
+
     # ---- Ember program compilation ----
     def embedding_program(self, batch: int, seq: int):
         """All irregular lookups of one (batch, seq) step as one
@@ -241,8 +269,7 @@ class LM:
         cfg = self.cfg
         tokens = batch * seq
         extra = []
-        pattern = tuple(cfg.block_pattern) + tuple(cfg.remainder_pattern)
-        if cfg.num_experts and any(k in ("moe", "mla") for k in pattern):
+        if self.has_experts:
             extra.append(("moe_dispatch",
                           moe_mod.dispatch_op(cfg, tokens)))
         return ee.model_embedding_program(
@@ -276,8 +303,7 @@ class LM:
         cfg = self.cfg
         members = [executor_for(self.decode_embed_program(batch, seq),
                                 opt_level, depth=depth, **kw)]
-        pattern = tuple(cfg.block_pattern) + tuple(cfg.remainder_pattern)
-        if cfg.num_experts and any(k in ("moe", "mla") for k in pattern):
+        if self.has_experts:
             members.append(executor_for(
                 moe_mod.undispatch_program(cfg, batch * seq), opt_level,
                 depth=depth, **kw))
@@ -347,6 +373,14 @@ class LM:
         params["rest"] = tuple(
             init_block(kind, jax.random.fold_in(keys[2], i), cfg, dtype)
             for i, kind in enumerate(cfg.remainder_pattern))
+        if cfg.first_k_dense:
+            params["lead"] = tuple(
+                init_block(kind, jax.random.fold_in(keys[6], i), cfg, dtype)
+                for i, kind in enumerate(cfg.lead_pattern))
+        if not cfg.tie_embeddings:
+            params["lm_head"] = (jax.random.normal(
+                keys[7], (cfg.padded_vocab, cfg.d_model), jnp.float32)
+                * cfg.d_model ** -0.5).astype(dtype)
         if "shared_attn" in pattern or "shared_attn" in cfg.remainder_pattern:
             params["shared"] = {
                 "attn": attn.init_attn(keys[3], cfg, dtype),
@@ -400,6 +434,10 @@ class LM:
     def _stack(self, params, x, ctx):
         cfg = self.cfg
         pattern = cfg.block_pattern
+        lead_aux = jnp.zeros((), jnp.float32)
+        for i, kind in enumerate(cfg.lead_pattern):
+            x, a = block_apply(kind, params["lead"][i], x, cfg, ctx)
+            lead_aux = lead_aux + a
 
         def super_step(carry, layer_params):
             h, aux = carry
@@ -411,7 +449,7 @@ class LM:
         step = self._maybe_remat(
             lambda c, lp: super_step(c, lp))
         (x, aux), _ = jax.lax.scan(
-            step, (x, jnp.zeros((), jnp.float32)), params["scan"],
+            step, (x, lead_aux), params["scan"],
             unroll=cfg.n_super if self.shard.cost_mode else 1)
         for i, kind in enumerate(cfg.remainder_pattern):
             x, a = block_apply(kind, params["rest"][i], x, cfg, ctx)
@@ -463,13 +501,13 @@ class LM:
         x, aux = self.forward(params, batch)
         labels = batch["labels"]
         if sh.mesh is not None:
-            ce = ee.xent_vocab_parallel(x, params["embed"], labels,
+            ce = ee.xent_vocab_parallel(x, self.head(params), labels,
                                         mesh=sh.mesh,
                                         vocab_axis=sh.model_axis,
                                         data_axes=self._batch_axes(
                                             labels.shape[0]))
         else:
-            lg = ee.logits(x, params["embed"])
+            lg = ee.logits(x, self.head(params))
             ce = jnp.mean(jax.nn.logsumexp(lg, -1) -
                           jnp.take_along_axis(lg, labels[..., None],
                                               -1)[..., 0])
@@ -492,6 +530,10 @@ class LM:
             "rest": tuple(init_block_cache(k, cfg, batch, max_len, dtype)
                           for k in cfg.remainder_pattern),
         }
+        if cfg.first_k_dense:
+            caches["lead"] = tuple(
+                init_block_cache(k, cfg, batch, max_len, dtype)
+                for k in cfg.lead_pattern)
         return caches
 
     def prefill(self, params, batch: dict, caches):
@@ -521,12 +563,20 @@ class LM:
         holds — before attention reads the layer; a recurrent state (mamba,
         mLSTM, sLSTM) is small and written whole under the mask.  A
         row-written leaf keeps its device's layout.  No op copies or
-        selects over a whole layer's positional cache."""
+        selects over a whole layer's positional cache.  The leading dense
+        layers (``caches["lead"]``) and the remainder run unscanned."""
+        return self._decode(params, tokens_new, caches, batch_ctx,
+                            active)[:2]
+
+    def _decode(self, params, tokens_new, caches, batch_ctx, active):
+        """:meth:`decode_step`, plus the micro-step's expert counters summed
+        over the MoE layers (:func:`~repro.models.moe.moe_share`'s, over
+        the active slots), or None for a model without experts."""
         cfg = self.cfg
         sh = self.shard
         if active is not None:
-            # zero the fed token so inactive slots contribute a deterministic
-            # input to batch-coupled ops (MoE capacity contention)
+            # zero the fed token so inactive slots compute on a
+            # deterministic input (their results are discarded)
             tokens_new = jnp.where(active[:, None], tokens_new, 0)
         if sh.mesh is not None and sh.use_shard_map_embed:
             x = ee.lookup(params["embed"], tokens_new, mesh=sh.mesh,
@@ -539,34 +589,46 @@ class LM:
         if cfg.enc_layers:
             ctx["enc_out"] = batch_ctx["enc_out"]
         pattern = cfg.block_pattern
+        counts = jnp.zeros((2,), jnp.int32) if self.has_experts else None
+
+        def unscanned(part, kinds, x, counts):
+            new = []
+            for i, kind in enumerate(kinds):
+                x, view, c = block_decode(
+                    kind, params[part][i], x, cfg,
+                    attn.LayerCache(caches[part][i], None, active), ctx)
+                new.append(view.tree)
+                if c is not None:
+                    counts = counts + c
+            return x, tuple(new), counts
 
         def super_step(carry, xs):
-            h, stack = carry
+            h, stack, counts = carry
             layer_params, layer = xs
             stack = list(stack)
             for i, kind in enumerate(pattern):
-                h, view = block_decode(
+                h, view, c = block_decode(
                     kind, layer_params[i], h, cfg,
                     attn.LayerCache(stack[i], layer, active), ctx)
                 stack[i] = view.tree
-            return (h, tuple(stack)), None
+                if c is not None:
+                    counts = counts + c
+            return (h, tuple(stack), counts), None
 
+        x, lead, counts = unscanned("lead", cfg.lead_pattern, x, counts)
+        new = {"scan": ()}
         if cfg.n_super:
-            (x, new_scan), _ = jax.lax.scan(
-                super_step, (x, caches["scan"]),
+            (x, new["scan"], counts), _ = jax.lax.scan(
+                super_step, (x, caches["scan"], counts),
                 (params["scan"], jnp.arange(cfg.n_super)),
                 unroll=cfg.n_super if self.shard.cost_mode else 1)
-        else:
-            new_scan = ()
-        new_rest = []
-        for i, kind in enumerate(cfg.remainder_pattern):
-            x, view = block_decode(
-                kind, params["rest"][i], x, cfg,
-                attn.LayerCache(caches["rest"][i], None, active), ctx)
-            new_rest.append(view.tree)
+        x, new["rest"], counts = unscanned("rest", cfg.remainder_pattern, x,
+                                           counts)
+        if cfg.first_k_dense:
+            new["lead"] = lead
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = ee.logits(x, params["embed"])[..., :cfg.vocab_size]
-        return logits, {"scan": new_scan, "rest": tuple(new_rest)}
+        logits = ee.logits(x, self.head(params))[..., :cfg.vocab_size]
+        return logits, new, counts
 
     def wave_step(self, params, tokens, lens, caches, batch_ctx=None):
         """One serving *wave*: a fused ``lax.scan`` of ``tokens.shape[1]``
@@ -585,33 +647,42 @@ class LM:
         no whole cache, not even at its entry or exit, and the only
         whole-layer access is decode attention's read.
 
-        Returns ``(logits (B,1,V) at each slot's last valid token, caches)``.
+        Returns ``(logits (B,1,V) at each slot's last valid token, caches)``,
+        and for a model with experts a third value, the wave's expert
+        counters (2,) int32: the token-expert assignments that fell on
+        held experts, and the (layer, micro-step, held expert) triples
+        that received at least one token, over the slots each micro-step
+        feeds.
         """
         b, c = tokens.shape
         lens = lens.astype(jnp.int32)
 
         def micro(carry, xs):
-            caches, logits_last = carry
+            caches, logits_last, counts = carry
             tok, t = xs
             active = t < lens
-            logits, caches = self.decode_step(params, tok[:, None], caches,
-                                              batch_ctx=batch_ctx,
-                                              active=active)
+            logits, caches, n = self._decode(params, tok[:, None], caches,
+                                             batch_ctx, active)
             logits_last = jnp.where(active[:, None, None], logits,
                                     logits_last)
-            return (caches, logits_last), None
+            if n is not None:
+                counts = counts + n
+            return (caches, logits_last, counts), None
 
         init = (caches,
-                jnp.zeros((b, 1, self.cfg.vocab_size), jnp.float32))
-        (caches, logits_last), _ = jax.lax.scan(
+                jnp.zeros((b, 1, self.cfg.vocab_size), jnp.float32),
+                jnp.zeros((2,), jnp.int32) if self.has_experts else None)
+        (caches, logits_last, counts), _ = jax.lax.scan(
             micro, init, (tokens.T, jnp.arange(c, dtype=jnp.int32)))
-        return logits_last, caches
+        if counts is None:
+            return logits_last, caches
+        return logits_last, caches, counts
 
     def reset_slots(self, caches, keep):
         """Zero the cache state of retired slots (``keep`` (B,) bool) so a
         recycled slot starts from position 0 with no stale KV.  Scan-stacked
-        leaves carry batch at axis 1 (leading axis is n_super), ``rest``
-        leaves at axis 0."""
+        leaves carry batch at axis 1 (leading axis is n_super), ``lead``
+        and ``rest`` leaves at axis 0."""
         def mask_at(axis):
             def f(leaf):
                 shape = [1] * leaf.ndim
@@ -619,5 +690,5 @@ class LM:
                 return jnp.where(keep.reshape(shape), leaf,
                                  jnp.zeros_like(leaf))
             return f
-        return {"scan": jax.tree.map(mask_at(1), caches["scan"]),
-                "rest": jax.tree.map(mask_at(0), caches["rest"])}
+        return {part: jax.tree.map(mask_at(1 if part == "scan" else 0), c)
+                for part, c in caches.items()}

@@ -1,15 +1,27 @@
-"""Mixture-of-Experts layer with expert-parallel (EP) dispatch.
+"""Mixture-of-Experts layer: the expert share, and expert-parallel (EP)
+dispatch.
+
+The router is a softmax over all ``num_experts`` and keeps the top
+``experts_per_tok`` (renormalised only under ``norm_topk_prob``).  A device
+holds experts ``first .. first + held - 1`` (``ModelConfig.experts_held``,
+all by default): :func:`moe_share` computes those experts' part of the
+layer for every token, dropless, as a dense pass over the held experts
+weighted by each token's gate (0 for an expert it did not pick).  On one
+device that share is the layer's routed part; under a mesh whose tokens are
+replicated (decode) each rank computes its share and one ``psum`` adds them
+(:func:`_replicated_token_ep`).  Shared experts are added once, outside the
+share.
 
 MoE dispatch *is* an embedding operation in the paper's taxonomy: tokens are
 gathered into per-expert capacity buffers by irregular indices (an SLS-class
-scatter/gather, DESIGN.md §4), so the dispatch path is built on the same
-sort-and-slot structure emberc generates for SLS — realized here at cluster
-scale with a shard_map: local sort-based slotting (access), all-to-all over
-the expert/model axis (the queue), expert FFN (execute), reverse all-to-all
-and weighted combine.
-
-Capacity-based dropping keeps every shape static (required for pjit); the
-aux load-balance loss keeps the router from collapsing.
+scatter/gather, DESIGN.md §4).  :func:`moe_ffn_local` keeps that form for
+the one path that splits tokens over the EP axis — training and prefill
+under a mesh whose EP axis divides the sequence: local sort-based slotting
+(access), all-to-all over the expert/model axis (the queue), expert FFN
+(execute), reverse all-to-all and weighted combine.  Its capacity buffers
+keep every shape static, and drop the tokens past an expert's capacity;
+it is the only path that drops.  The aux load-balance loss keeps the
+router from collapsing.
 """
 from __future__ import annotations
 
@@ -50,10 +62,12 @@ def undispatch_program(cfg: ModelConfig, tokens: int, name=None):
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
-    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+    """The router over all ``num_experts``, the ``held_experts`` experts
+    this device holds, and the shared experts."""
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.held_experts
     ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], (d, e), jnp.float32),
+        "router": dense_init(ks[0], (d, cfg.num_experts), jnp.float32),
         "wi_gate": dense_init(ks[1], (e, d, f), dtype),
         "wi_up": dense_init(ks[2], (e, d, f), dtype),
         "wo": dense_init(ks[3], (e, f, d), dtype),
@@ -67,6 +81,65 @@ def init_moe(key, cfg: ModelConfig, dtype):
             "wo": dense_init(k3, (fs, d), dtype),
         }
     return p
+
+
+def route(p, x2d, cfg: ModelConfig):
+    """Softmax over all ``num_experts`` in float32 (the router's matmul at
+    full precision, as the published gate computes it), then the top
+    ``experts_per_tok``: ``(probs (T,E), weights (T,k), experts (T,k))``.
+    The weights are renormalised to sum to 1 only under
+    ``cfg.norm_topk_prob``."""
+    logits = jnp.dot(x2d.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    topw, tope = jax.lax.top_k(probs, cfg.experts_per_tok)
+    if cfg.norm_topk_prob:
+        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    return probs, topw, tope
+
+
+def _aux_loss(probs, tope, e):
+    """Load-balance loss: E · Σ_e frac_e · mean prob_e (≈ 1 when even)."""
+    frac = jnp.mean(jax.nn.one_hot(tope, e, dtype=jnp.float32), axis=(0, 1))
+    return e * jnp.sum(frac * jnp.mean(probs, axis=0))
+
+
+def moe_share(p, x2d, cfg: ModelConfig, first=0, active=None):
+    """The routed part that experts ``first .. first + held - 1`` (``held``
+    = the experts ``p`` holds) give every token of x2d (T, D), with no
+    capacity and nothing dropped.  Each held expert runs densely over all
+    tokens, and its output is weighted by the token's gate: the router
+    weight where the token picked it, else 0.  Returns ``(part (T, D), aux
+    loss, counts)``; ``counts`` (2,) int32 are, over the tokens ``active``
+    (T,) marks (all by default), the token-expert assignments that fall on
+    held experts and the held experts that received at least one."""
+    held = p["wi_gate"].shape[0]
+    act = _ACTS[cfg.act]
+    probs, topw, tope = route(p, x2d, cfg)
+    picked = jax.nn.one_hot(tope - first, held, dtype=jnp.float32)  # T,k,e
+    gate = (topw[..., None] * picked).sum(1)                   # (T, held)
+    h = act(jnp.einsum("td,edf->etf", x2d, p["wi_gate"])) * \
+        jnp.einsum("td,edf->etf", x2d, p["wi_up"])
+    y = jnp.einsum("etf,efd->etd", h, p["wo"])
+    out = jnp.einsum("etd,te->td", y.astype(jnp.float32), gate,
+                     precision=jax.lax.Precision.HIGHEST)
+    hits = picked.sum(1)                                       # (T, held)
+    if active is not None:
+        hits = hits * active[:, None]
+    per_expert = hits.sum(0)
+    counts = jnp.stack([per_expert.sum(), (per_expert > 0).sum()]
+                       ).astype(jnp.int32)
+    return out.astype(x2d.dtype), _aux_loss(probs, tope, cfg.num_experts), \
+        counts
+
+
+def shared_experts(p, x2d, cfg: ModelConfig):
+    """The shared experts' output (0 without them)."""
+    if "shared" not in p:
+        return jnp.zeros_like(x2d)
+    sp = p["shared"]
+    act = _ACTS[cfg.act]
+    return (act(x2d @ sp["wi_gate"]) * (x2d @ sp["wi_up"])) @ sp["wo"]
 
 
 def _slot_assignments(expert_ids, num_experts, capacity):
@@ -92,16 +165,10 @@ def moe_ffn_local(p, x2d, cfg: ModelConfig, ep_axis=None):
     shard_map and experts are sharded over it (EP all-to-all dispatch)."""
     t, d = x2d.shape
     e, k = cfg.num_experts, cfg.experts_per_tok
+    assert cfg.held_experts == e, "the all-to-all path holds every expert"
     act = _ACTS[cfg.act]
-
-    logits = (x2d.astype(jnp.float32) @ p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, tope = jax.lax.top_k(probs, k)                   # (T,k)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
-
-    # aux load-balance loss (replicated; mean of frac_e * prob_e * E)
-    frac = jnp.mean(jax.nn.one_hot(tope, e, dtype=jnp.float32), axis=(0, 1))
-    aux = e * jnp.sum(frac * jnp.mean(probs, axis=0))
+    probs, topw, tope = route(p, x2d, cfg)
+    aux = _aux_loss(probs, tope, e)
 
     flat_e = tope.reshape(-1)                              # (T*k,)
     capacity = int(t * k / e * cfg.capacity_factor) + 1
@@ -138,82 +205,53 @@ def moe_ffn_local(p, x2d, cfg: ModelConfig, ep_axis=None):
     gathered = jnp.where(keep[:, None], out_buf[slot], 0.0)  # (T*k, D)
     out = jnp.sum(gathered.reshape(t, k, d) *
                   topw[..., None].astype(x2d.dtype), axis=1)
-
-    if "shared" in p:
-        sp = p["shared"]
-        out = out + (act(x2d @ sp["wi_gate"]) * (x2d @ sp["wi_up"])) @ sp["wo"]
-    return out, aux
+    return out + shared_experts(p, x2d, cfg), aux
 
 
-def _replicated_token_ep(p, x2d, cfg: ModelConfig, ep_axis):
+def _replicated_token_ep(p, x2d, cfg: ModelConfig, ep_axis, active=None):
     """Decode-path EP: tokens too few to split over the EP axis — every rank
-    routes the (replicated) tokens, processes only its local experts, and the
-    outputs combine with one psum.  No all-to-all; collective bytes are
+    routes the (replicated) tokens, computes its own experts' share, and
+    the shares combine with one psum.  No all-to-all; collective bytes are
     O(tokens·D), ideal for serve steps."""
-    t, d = x2d.shape
-    e, k = cfg.num_experts, cfg.experts_per_tok
-    act = _ACTS[cfg.act]
-    n = jax.lax.axis_size(ep_axis)
-    rank = jax.lax.axis_index(ep_axis)
-    e_loc = e // n
-
-    logits = x2d.astype(jnp.float32) @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, tope = jax.lax.top_k(probs, k)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
-    frac = jnp.mean(jax.nn.one_hot(tope, e, dtype=jnp.float32), axis=(0, 1))
-    aux = e * jnp.sum(frac * jnp.mean(probs, axis=0))
-
-    flat_e = tope.reshape(-1)
-    capacity = int(t * k / e * cfg.capacity_factor) + 1
-    slot, keep = _slot_assignments(flat_e, e, capacity)
-    src = jnp.repeat(x2d, k, axis=0)
-    buf = jnp.zeros((e * capacity, d), x2d.dtype)
-    buf = buf.at[jnp.where(keep, slot, e * capacity)].set(src, mode="drop")
-
-    my = jax.lax.dynamic_slice_in_dim(buf, rank * e_loc * capacity,
-                                      e_loc * capacity).reshape(
-                                          e_loc, capacity, d)
-    h = act(jnp.einsum("ecd,edf->ecf", my, p["wi_gate"])) * \
-        jnp.einsum("ecd,edf->ecf", my, p["wi_up"])
-    out_loc = jnp.einsum("ecf,efd->ecd", h, p["wo"]).reshape(-1, d)
-    out_buf = jnp.zeros((e * capacity, d), x2d.dtype)
-    out_buf = jax.lax.dynamic_update_slice_in_dim(
-        out_buf, out_loc, rank * e_loc * capacity, axis=0)
-    out_buf = jax.lax.psum(out_buf, ep_axis)
-
-    gathered = jnp.where(keep[:, None], out_buf[slot], 0.0)
-    out = jnp.sum(gathered.reshape(t, k, d) *
-                  topw[..., None].astype(x2d.dtype), axis=1)
-    if "shared" in p:
-        sp = p["shared"]
-        out = out + (act(x2d @ sp["wi_gate"]) * (x2d @ sp["wi_up"])) @ sp["wo"]
-    return out, aux
+    first = jax.lax.axis_index(ep_axis) * p["wi_gate"].shape[0]
+    out, aux, counts = moe_share(p, x2d, cfg, first, active)
+    out = jax.lax.psum(out, ep_axis) + shared_experts(p, x2d, cfg)
+    return out, aux, jax.lax.psum(counts, ep_axis)
 
 
 def moe_ffn(p, x, cfg: ModelConfig, mesh=None, ep_axis="model",
-            data_axes=("data",)):
-    """x (B,S,D) -> (B,S,D). With a mesh: shard_map EP dispatch."""
+            data_axes=("data",), active=None):
+    """x (B,S,D) -> ``(out (B,S,D), aux loss, counts)``; ``counts`` as
+    :func:`moe_share` gives them, over the batch rows ``active`` (B,)
+    marks.  Without a mesh: this device's share plus the shared experts.
+    With a mesh: shard_map EP dispatch."""
     b, s, d = x.shape
+    mask = jnp.broadcast_to((jnp.ones((b,), bool) if active is None
+                             else active)[:, None], (b, s))
     if mesh is None or ep_axis is None:
-        out, aux = moe_ffn_local(p, x.reshape(-1, d), cfg)
-        return out.reshape(b, s, d), aux
+        out, aux, counts = moe_share(p, x.reshape(-1, d), cfg,
+                                     active=mask.reshape(-1))
+        out = out + shared_experts(p, x.reshape(-1, d), cfg)
+        return out.reshape(b, s, d), aux, counts
 
     n_ep = mesh.shape[ep_axis]
     seq_split = s % n_ep == 0 and s >= n_ep   # decode (s==1): can't split
 
-    def body(p_, x_):
+    def body(p_, x_, m_):
         t = x_.shape[0] * x_.shape[1]
         if seq_split:
+            # training and prefill count nothing
             out, aux = moe_ffn_local(p_, x_.reshape(t, d), cfg,
                                      ep_axis=ep_axis)
+            counts = jnp.zeros((2,), jnp.int32)
         else:
-            out, aux = _replicated_token_ep(p_, x_.reshape(t, d), cfg,
-                                            ep_axis)
+            out, aux, counts = _replicated_token_ep(
+                p_, x_.reshape(t, d), cfg, ep_axis, m_.reshape(t))
         aux = jax.lax.pmean(aux, ep_axis)
         for ax in data_axes:
             aux = jax.lax.pmean(aux, ax)
-        return out.reshape(x_.shape), aux
+            counts = jax.lax.psum(counts, ax)
+        return out.reshape(x_.shape), aux, counts
 
     dp = tuple(data_axes) if data_axes else None
     p_specs = jax.tree.map(lambda _: P("model", None, None), p)
@@ -223,7 +261,6 @@ def moe_ffn(p, x, cfg: ModelConfig, mesh=None, ep_axis="model",
     # tokens split over data axes on batch and (train/prefill) over the EP
     # axis on sequence
     x_spec = P(dp, ep_axis, None) if seq_split else P(dp, None, None)
-    out, aux = shard_map(
-        body, mesh=mesh, in_specs=(p_specs, x_spec),
-        out_specs=(x_spec, P()), check_vma=False)(p, x)
-    return out, aux
+    return shard_map(
+        body, mesh=mesh, in_specs=(p_specs, x_spec, P(*x_spec[:2])),
+        out_specs=(x_spec, P(), P()), check_vma=False)(p, x, mask)

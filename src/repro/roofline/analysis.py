@@ -146,8 +146,10 @@ def analytic_flops(cfg, seq: int, batch: int, kind: str) -> float:
     mult = 3.0 if kind == "train" else 1.0   # fwd+bwd vs fwd
     b, s = batch, seq
     attn = 0.0
-    for k in (cfg.block_pattern * cfg.n_super) + cfg.remainder_pattern:
-        if k in ("dense", "moe", "mla", "shared_attn", "enc_dense", "xdec"):
+    for k in cfg.lead_pattern + (cfg.block_pattern * cfg.n_super) + \
+            cfg.remainder_pattern:
+        if k in ("dense", "moe", "mla", "mla_dense", "shared_attn",
+                 "enc_dense", "xdec"):
             attn += 4.0 * b * s * s * cfg.num_heads * cfg.hd
             if k == "xdec":
                 attn += 4.0 * b * s * s * cfg.num_heads * cfg.hd
@@ -179,7 +181,8 @@ def active_params(cfg) -> float:
     total = v * d  # embedding (tied unembedding counted once for lookups)
     per_layer = {}
     hd = cfg.hd
-    for kind in (cfg.block_pattern * cfg.n_super) + cfg.remainder_pattern:
+    for kind in cfg.lead_pattern + (cfg.block_pattern * cfg.n_super) + \
+            cfg.remainder_pattern:
         attn = d * cfg.num_heads * hd + 2 * d * cfg.num_kv_heads * hd \
             + cfg.num_heads * hd * d
         mlp = 3 * d * cfg.d_ff
@@ -188,13 +191,14 @@ def active_params(cfg) -> float:
         elif kind == "moe":
             n = attn + 3 * d * cfg.moe_d_ff * cfg.experts_per_tok \
                 + 3 * d * cfg.moe_d_ff * cfg.num_shared_experts
-        elif kind == "mla":
+        elif kind in ("mla", "mla_dense"):
             r, rd = cfg.kv_lora_rank, cfg.rope_head_dim
             n = (d * cfg.num_heads * (hd + rd) + d * r +
                  r * 2 * cfg.num_heads * hd + d * rd +
                  cfg.num_heads * hd * d)
-            n += 3 * d * cfg.moe_d_ff * (cfg.experts_per_tok +
-                                         cfg.num_shared_experts)
+            n += mlp if kind == "mla_dense" else \
+                3 * d * cfg.moe_d_ff * (cfg.experts_per_tok +
+                                        cfg.num_shared_experts)
         elif kind == "mamba":
             di = 2 * d
             n = d * (2 * di + 2 * cfg.ssm_state + cfg.num_heads) + di * d
@@ -236,12 +240,13 @@ def analytic_bytes_per_device(cfg, seq: int, batch: int, kind: str,
         return P_loc * 2 + 8 * tok_loc * d * 2 * cfg.num_layers
     # decode: params once + KV/state cache traffic
     cache = 0.0
-    for k in (cfg.block_pattern * cfg.n_super) + cfg.remainder_pattern:
+    for k in cfg.lead_pattern + (cfg.block_pattern * cfg.n_super) + \
+            cfg.remainder_pattern:
         if k in ("dense", "moe", "shared_attn", "xdec", "enc_dense"):
             cache += 2 * seq * cfg.num_kv_heads * cfg.hd * 2
         elif k == "dense_local":
             cache += 2 * min(seq, cfg.sliding_window) *                 cfg.num_kv_heads * cfg.hd * 2
-        elif k == "mla":
+        elif k in ("mla", "mla_dense"):
             cache += seq * (cfg.kv_lora_rank + cfg.rope_head_dim) * 2
         elif k == "mamba":
             cache += cfg.num_heads * (2 * d // cfg.num_heads) *                 cfg.ssm_state * 2 * 2

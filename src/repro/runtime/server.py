@@ -451,11 +451,16 @@ class DecodeServer:
                 return self._n_active()
             c, tokens, lens, emits = wave
             with tracing.span("wave.dispatch"):
-                logits, t0 = self._run_wave(tokens, lens, retired)
+                logits, counts, t0 = self._run_wave(tokens, lens, retired)
             if logits is None:
                 return self._n_active()
             with tracing.span("wave.sync"):
-                nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                nxt = jnp.argmax(logits[:, 0], axis=-1)
+                if counts is None:
+                    nxt = np.asarray(nxt)
+                else:
+                    nxt, counts = jax.device_get((nxt, counts))
+                    self._count_experts(counts)
             # the wave's time through its sync, not just its dispatch
             dt = time.perf_counter() - t0
             self._ewma_wave_s = dt if self._ewma_wave_s is None else \
@@ -508,8 +513,9 @@ class DecodeServer:
                   retired: np.ndarray):
         """The guarded wave body: LM step + pipeline feed, under the
         watchdog deadline, retried after a typed fault.  Returns the
-        wave's (unsynced) logits and the time its last attempt started,
-        or ``(None, None)`` once the retries are spent: the slots it
+        wave's (unsynced) logits, its expert counters (None for a model
+        without experts) and the time its last attempt started, or
+        ``(None, None, None)`` once the retries are spent: the slots it
         served have then failed and recycled."""
         tokens_j, lens_j = jnp.asarray(tokens), jnp.asarray(lens)
         t0 = time.perf_counter()
@@ -520,7 +526,7 @@ class DecodeServer:
                 if self.faults is not None:
                     self.faults.fire("wave", wave=self.waves)
                 if not lm_done:
-                    logits, self.caches = self._wave(
+                    logits, self.caches, *counts = self._wave(
                         self.params, tokens_j, lens_j, self.caches)
                     lm_done = True
                 if self.pipeline_group is not None:
@@ -531,7 +537,7 @@ class DecodeServer:
                         raise WaveTimeout(
                             f"wave {self.waves} took {el * 1e3:.1f}ms > "
                             f"deadline {self.wave_deadline_s * 1e3:.1f}ms")
-                return logits, t0
+                return logits, (counts[0] if counts else None), t0
             except EmberFault as e:
                 # typed faults only: anything else is a bug and propagates
                 self.serve_stats["wave_faults"] += 1
@@ -549,10 +555,18 @@ class DecodeServer:
                         self._finish(i, req, retired, status="failed",
                                      error=err)
                     self._recycle(retired)
-                    return None, None
+                    return None, None, None
                 attempt += 1
                 self.serve_stats["wave_retries"] += 1
                 t0 = time.perf_counter()   # the retry gets a fresh budget
+
+    def _count_experts(self, counts: np.ndarray) -> None:
+        """A wave's expert counters (``LM.wave_step``'s third value) into
+        ``serve_stats``: token-expert assignments on held experts, and
+        (layer, micro-step, held expert) triples that got a token."""
+        for name, n in zip(("moe_held_assignments", "moe_experts_touched"),
+                           counts):
+            self.serve_stats[name] = self.serve_stats.get(name, 0) + int(n)
 
     def _emit(self, c: int, lens: np.ndarray, nxt: np.ndarray,
               emits: np.ndarray, retired: np.ndarray) -> None:

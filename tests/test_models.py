@@ -33,8 +33,8 @@ def test_full_config_instantiates(arch):
     shapes = jax.eval_shape(lm.init, jax.random.PRNGKey(0))
     n_params = sum(np.prod(l.shape) for l in jax.tree.leaves(shapes))
     assert n_params > 1e8, (arch, n_params)  # all assigned archs are ≥1B-ish
-    assert cfg.num_layers == cfg.n_super * len(cfg.block_pattern) + \
-        len(cfg.remainder_pattern)
+    assert cfg.num_layers == len(cfg.lead_pattern) + \
+        cfg.n_super * len(cfg.block_pattern) + len(cfg.remainder_pattern)
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -247,7 +247,7 @@ def test_chunked_prefill_bit_identical(arch):
     lens = jnp.array([9, 6], jnp.int32)
     wave = jax.jit(lm.wave_step)
     lg_whole, cache_whole = wave(params, toks, lens,
-                                 lm.init_caches(b, 16))
+                                 lm.init_caches(b, 16))[:2]
     for chunk in (1, 4):
         caches = lm.init_caches(b, 16)
         lg_by_slot = [None] * b
@@ -256,7 +256,7 @@ def test_chunked_prefill_bit_identical(arch):
             n = min(chunk, L - off)
             cl = jnp.clip(lens - off, 0, n)
             part = jnp.pad(toks[:, off:off + n], ((0, 0), (0, chunk - n)))
-            lg, caches = wave(params, part, cl, caches)
+            lg, caches = wave(params, part, cl, caches)[:2]
             for i in range(b):
                 if int(cl[i]) > 0 and off + int(cl[i]) == int(lens[i]):
                     lg_by_slot[i] = lg[i]
@@ -298,11 +298,11 @@ def test_wave_step_matches_decode_step_replay(arch, kv):
     warm = jax.random.randint(jax.random.PRNGKey(1), (b, 3), 0,
                               cfg.vocab_size)
     _, start = wave(params, warm, jnp.array([3, 2, 3], jnp.int32),
-                    lm.init_caches(b, 16), ctx)
+                    lm.init_caches(b, 16), ctx)[:2]
     toks = jax.random.randint(jax.random.PRNGKey(2), (b, L), 0,
                               cfg.vocab_size)
     lens = jnp.array([6, 4, 0], jnp.int32)
-    lg_wave, cache_wave = wave(params, toks, lens, start, ctx)
+    lg_wave, cache_wave = wave(params, toks, lens, start, ctx)[:2]
     caches = start
     step = jax.jit(lm.decode_step)
     lg_by_slot = [None] * b
@@ -332,8 +332,8 @@ def test_wave_step_is_independent_of_cache_layout(arch, kv, monkeypatch):
     """The wave holds every cache leaf it writes in its device's layout (on
     a TPU, K/V with head dim 80 keep the sequence axis minor).  Held with
     every non-leading axis reversed instead, two ragged waves give the same
-    logits and caches as in the CPU's row-major layout.  (MLA up-projects
-    its latent cache with a matmul whose summation order the compiler picks
+    logits and caches as in the CPU's row-major layout.  (MLA contracts
+    its latent cache in matmuls whose summation order the compiler picks
     by the operand's layout: equal there to rounding.)"""
     import dataclasses
     from jax.experimental.layout import Layout, with_layout_constraint
@@ -357,7 +357,7 @@ def test_wave_step_is_independent_of_cache_layout(arch, kv, monkeypatch):
         caches, out = lm.init_caches(b, 16), []
         wave = jax.jit(lm.wave_step)
         for toks, lens in waves:
-            lg, caches = wave(params, toks, lens, caches, ctx)
+            lg, caches = wave(params, toks, lens, caches, ctx)[:2]
             out.append(lg)
         return out, caches
 
