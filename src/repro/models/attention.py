@@ -134,25 +134,41 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 def decode_attention(q, k_cache, v_cache, cache_len, *,
                      window: Optional[int] = None):
-    """q (B,1,H,D); caches (B,Smax,Hkv,D); cache_len (B,) per-slot valid
-    lengths incl. the new token (a scalar — legacy whole-batch caches —
-    broadcasts to the same math)."""
-    b, _, h, d = q.shape
+    """q (B,C,H,D); caches (B,Smax,Hkv,D); cache_len (B,C) the valid length
+    each query sees, incl. its own token: query t of slot b attends over
+    positions ``< cache_len[b, t]`` (and the last ``window`` of them).
+
+    Each query's scores, softmax and weighted sum run over the whole cache,
+    and a query's result does not depend on C: the G·C queries of a kv
+    head are the rows of one matmul against its cache, and a lone row is
+    padded to two, since a one-row matmul may lower as a matrix-vector
+    product that sums in another order (on the CPU and on a TPU alike).
+    So C tokens in one pass give the bits of C single-token steps."""
+    b, c, h, d = q.shape
     smax, hkv = k_cache.shape[1], k_cache.shape[2]
     g = h // hkv
-    qg = q.reshape(b, 1, hkv, g, d)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache,
+    m = g * c
+    rows = q.reshape(b, c, hkv, g, d).transpose(0, 2, 3, 1, 4) \
+        .reshape(b, hkv, m, d)
+    if m == 1:
+        rows = jnp.concatenate([rows, rows], axis=2)
+    s = jnp.einsum("bhmd,bkhd->bhmk", rows, k_cache,
                    preferred_element_type=jnp.float32) * d ** -0.5
-    cl = jnp.broadcast_to(cache_len, (b,))
     pos = jnp.arange(smax)
-    mask = pos[None, :] < cl[:, None]
+    mask = pos[None, None, :] < cache_len[:, :, None]
     if window is not None:
-        mask &= pos[None, :] >= cl[:, None] - window
-    s = jnp.where(mask[:, None, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_cache.dtype), v_cache,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, 1, h, d).astype(q.dtype)
+        mask &= pos[None, None, :] >= cache_len[:, :, None] - window
+    # row g·C + t of a kv head is query t of head group g
+    mask = jnp.broadcast_to(mask[:, None, None], (b, 1, g, c, smax)) \
+        .reshape(b, 1, m, smax)
+    s = jnp.where(mask, s[:, :, :m], NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
+    if m == 1:
+        p = jnp.concatenate([p, p], axis=2)
+    out = jnp.einsum("bhmk,bkhd->bhmd", p, v_cache,
+                     preferred_element_type=jnp.float32)[:, :, :m]
+    return out.reshape(b, hkv, g, c, d).transpose(0, 3, 1, 2, 4) \
+        .reshape(b, c, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -220,13 +236,16 @@ class LayerCache:
     axis = layer, as a scanned stack carries them) and this view is row
     ``layer`` of each; with ``layer=None`` they are the layer's own.
     ``active`` (B,) bool, or None for every slot, marks the slots this
-    micro-step feeds: an inactive slot's cache stays bit-identical.
+    micro-step feeds: an inactive slot's cache stays bit-identical.  A
+    (B,C) ``active`` marks a *block* of C tokens per slot instead, slot b's
+    valid tokens being a prefix of its row.
 
     :meth:`write` picks how to write by the cache's structure: a positional
     cache (one with a per-slot ``len`` counter over a sequence axis — K/V,
-    int8 K/V with scales, the MLA latent) takes one row per slot at its own
-    position, in place, and keeps its device's layout (:func:`_keep_layout`);
-    a fixed-size recurrent state is written whole."""
+    int8 K/V with scales, the MLA latent) takes each slot's rows at its own
+    position by one ``dynamic_update_slice`` per slot (:func:`_write_block`),
+    in place, and keeps its device's layout (:func:`_keep_layout`); a
+    fixed-size recurrent state is written whole."""
     tree: dict
     layer: Optional[jax.Array] = None
     active: Optional[jax.Array] = None
@@ -252,51 +271,75 @@ class LayerCache:
                                                name: child.tree})
 
     def write(self, values: dict) -> "LayerCache":
-        """Positional cache: ``values[name]`` (B,1,...) is each slot's new
-        row, written at its position ``len`` (an inactive slot writes back
-        the row it holds there), and ``len`` advances for active slots.
+        """Positional cache: ``values[name]`` (B,C,...) holds C rows per
+        slot, slot b's valid rows landing at ``len[b] + t``
+        (:func:`_write_block`), and ``len`` advances by their count; a
+        (B,) or None ``active`` feeds one row per slot (C=1).
         Recurrent state: ``values`` is the whole new state."""
         tree = dict(self.tree)
         lead = () if self.layer is None else (self.layer,)
+        active = self.active
         if "len" in self.tree:
             length = self["len"]
-            b = next(iter(values.values())).shape[0]
-            pos = jnp.broadcast_to(length, (b,))
+            if active is None:
+                active = jnp.ones(next(iter(values.values())).shape[:2], bool)
+            elif active.ndim == 1:
+                active = active[:, None]
             for name, rows in values.items():
-                leaf = tree[name]
-                for slot in range(b):
-                    start = lead + (slot, pos[slot]) + (0,) * (rows.ndim - 2)
-                    row = rows[slot:slot + 1]
-                    row = row.reshape((1,) * len(lead) + row.shape)
-                    if self.active is not None:
-                        row = jnp.where(self.active[slot], row,
-                                        jax.lax.dynamic_slice(leaf, start,
-                                                              row.shape))
-                    leaf = jax.lax.dynamic_update_slice(leaf, row, start)
-                tree[name] = _keep_layout(leaf)
-            values = {"len": length + 1}
+                tree[name] = _keep_layout(_write_block(tree[name], rows,
+                                                       length, active, lead))
+            values = {"len": length + active.sum(-1, dtype=length.dtype)}
+            active = None
         for name, new in values.items():
-            if self.active is not None:
-                new = jnp.where(self.active.reshape(
-                    self.active.shape + (1,) * (new.ndim - 1)), new,
-                    self[name])
+            if active is not None:
+                new = jnp.where(active.reshape(
+                    active.shape + (1,) * (new.ndim - 1)), new, self[name])
             tree[name] = new if self.layer is None else \
                 jax.lax.dynamic_update_index_in_dim(tree[name], new,
                                                     self.layer, 0)
         return dataclasses.replace(self, tree=tree)
 
 
+def _write_block(leaf, rows, length, active, lead):
+    """``rows`` (B,C,...) into ``leaf`` by one C-row ``dynamic_update_slice``
+    per slot; ``active`` (B,C) marks each slot's valid rows, a prefix.  Slot
+    b's block starts at ``min(len[b], Smax - C)``: a block that would run
+    past the cache's end starts early, so the compiler never shifts it onto
+    valid rows, and its valid rows still land at ``len[b] + t``.  A block
+    row that takes no valid token writes back the row it holds."""
+    b, c = rows.shape[:2]
+    smax = leaf.shape[len(lead) + 1]
+    w = min(c, smax)
+    for slot in range(b):
+        first = jnp.minimum(length[slot], smax - w)
+        start = lead + (slot, first) + (0,) * (rows.ndim - 2)
+        shape = (1,) * len(lead) + (1, w) + rows.shape[2:]
+        held = jax.lax.dynamic_slice(leaf, start, shape)
+        # block row j holds position first + j, which is token t
+        t = jnp.arange(w) + first - length[slot]
+        tc = jnp.maximum(t, 0)
+        take = (t >= 0) & active[slot][tc]
+        new = rows[slot][tc].reshape(shape)
+        take = take.reshape((1,) * (len(lead) + 1) + (w,) +
+                            (1,) * (rows.ndim - 2))
+        leaf = jax.lax.dynamic_update_slice(
+            leaf, jnp.where(take, new, held), start)
+    return leaf
+
+
 def attn_decode(p, x, cfg: ModelConfig, cache: LayerCache, *, window=None):
-    """x (B,1,D); ``cache`` a :class:`LayerCache` over {k,v:(B,Smax,Hkv,hd),
-    len:(B,) per-slot position counters} (self-attn).  The new K/V rows are
-    written first, then attention reads the layer's cache."""
-    b = x.shape[0]
+    """x (B,C,D), C tokens per slot at positions ``len + t``; ``cache`` a
+    :class:`LayerCache` over {k,v:(B,Smax,Hkv,hd), len:(B,) per-slot
+    position counters} (self-attn).  The new K/V rows are written first,
+    then each token attends over the layer's cache up to its own position:
+    C=1 is a decode micro-step, C>1 a block of a prompt in one pass."""
+    b, c = x.shape[:2]
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    pos = jnp.broadcast_to(cache["len"], (b,))
-    q = (x @ p["wq"]).reshape(b, 1, h, hd)
-    k = (x @ p["wk"]).reshape(b, 1, hkv, hd)
-    v = (x @ p["wv"]).reshape(b, 1, hkv, hd)
-    cos, sin = rope_freqs(pos[:, None].astype(jnp.float32), hd,
+    pos = cache["len"][:, None] + jnp.arange(c)
+    q = (x @ p["wq"]).reshape(b, c, h, hd)
+    k = (x @ p["wk"]).reshape(b, c, hkv, hd)
+    v = (x @ p["wv"]).reshape(b, c, hkv, hd)
+    cos, sin = rope_freqs(pos.astype(jnp.float32), hd,
                           cfg.rope_theta, cfg.rotary_pct)
     q = apply_rope(q, cos, sin, cfg.rotary_pct)
     k = apply_rope(k, cos, sin, cfg.rotary_pct)
@@ -311,7 +354,7 @@ def attn_decode(p, x, cfg: ModelConfig, cache: LayerCache, *, window=None):
         cache = cache.write({"k": k, "v": v})
         kd, vd = cache["k"], cache["v"]
     o = decode_attention(q, kd, vd, pos + 1, window=window)
-    return o.reshape(b, 1, h * hd) @ p["wo"], cache
+    return o.reshape(b, c, h * hd) @ p["wo"], cache
 
 
 def init_kv_cache(cfg: ModelConfig, batch, max_len, dtype):
@@ -333,7 +376,7 @@ def init_kv_cache(cfg: ModelConfig, batch, max_len, dtype):
 
 
 def _quant_kv(x):
-    """x (b,1,h,d) -> int8 values + per-(token,head) scale."""
+    """x (b,c,h,d) -> int8 values + per-(token,head) scale."""
     scale = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1) / 127.0
     scale = jnp.maximum(scale, 1e-8)
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]),

@@ -32,6 +32,10 @@ from .common import ModelConfig, init_mlp, init_rms, gated_mlp, rms_norm
 
 ATTN_KINDS = ("dense", "dense_local", "moe", "shared_attn", "enc_dense",
               "xdec")
+#: blocks whose serving step takes a block of C tokens per slot in one pass
+#: (GQA attention + gated MLP: each token's result depends only on the
+#: cache and itself, so a pass computes what C micro-steps compute)
+PASS_KINDS = ("dense", "dense_local")
 
 
 # ---------------------------------------------------------------------------
@@ -247,6 +251,15 @@ class LM:
     def __init__(self, cfg: ModelConfig, shard: Optional[ShardCtx] = None):
         self.cfg = cfg
         self.shard = shard or ShardCtx()
+
+    @property
+    def prefills_in_one_pass(self) -> bool:
+        """Whether :meth:`wave_step` feeds a wave's tokens in one pass: every
+        block of ``lead``, ``scan`` and ``rest`` is a :data:`PASS_KINDS`
+        block.  Other stacks replay the wave micro-step by micro-step."""
+        cfg = self.cfg
+        return all(k in PASS_KINDS for k in cfg.lead_pattern +
+                   tuple(cfg.block_pattern) + tuple(cfg.remainder_pattern))
 
     @property
     def has_experts(self) -> bool:
@@ -537,10 +550,9 @@ class LM:
         return caches
 
     def prefill(self, params, batch: dict, caches):
-        """Run the full-seq forward and (for simplicity of the runtime) fill
-        caches by replaying tokens through decode in the serving loop; the
-        dry-run lowers `serve_step` = one decode step, which is the shape
-        that matters.  Here: returns last-position hidden state."""
+        """The full-sequence forward's last-position hidden state (B,1,D).
+        ``caches`` are neither read nor written: serving fills them through
+        :meth:`wave_step`."""
         x, _ = self.forward(params, batch)
         return x[:, -1:]
 
@@ -573,21 +585,36 @@ class LM:
         over the MoE layers (:func:`~repro.models.moe.moe_share`'s, over
         the active slots), or None for a model without experts."""
         cfg = self.cfg
-        sh = self.shard
         if active is not None:
             # zero the fed token so inactive slots compute on a
             # deterministic input (their results are discarded)
             tokens_new = jnp.where(active[:, None], tokens_new, 0)
-        if sh.mesh is not None and sh.use_shard_map_embed:
-            x = ee.lookup(params["embed"], tokens_new, mesh=sh.mesh,
-                          vocab_axis=sh.model_axis,
-                          strategy=cfg.embed_strategy,
-                          data_axes=self._batch_axes(tokens_new.shape[0]))
-        else:
-            x = ee.lookup(params["embed"], tokens_new, strategy="take")
+        x = self._embed_fed(params, tokens_new)
         ctx = self._ctx(params, None, batch_size=tokens_new.shape[0])
         if cfg.enc_layers:
             ctx["enc_out"] = batch_ctx["enc_out"]
+        x, new, counts = self._decode_stack(params, x, caches, ctx, active)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = ee.logits(x, self.head(params))[..., :cfg.vocab_size]
+        return logits, new, counts
+
+    def _embed_fed(self, params, tokens):
+        """The embeddings (B,C,D) of the tokens a wave feeds."""
+        cfg = self.cfg
+        sh = self.shard
+        if sh.mesh is not None and sh.use_shard_map_embed:
+            return ee.lookup(params["embed"], tokens, mesh=sh.mesh,
+                             vocab_axis=sh.model_axis,
+                             strategy=cfg.embed_strategy,
+                             data_axes=self._batch_axes(tokens.shape[0]))
+        return ee.lookup(params["embed"], tokens, strategy="take")
+
+    def _decode_stack(self, params, x, caches, ctx, active):
+        """``x`` through every block, each writing its layer of ``caches``
+        through a :class:`~repro.models.attention.LayerCache` view with
+        ``active`` — (B,) for a micro-step, (B,C) for a block of C tokens
+        per slot.  Returns (x, caches, expert counters or None)."""
+        cfg = self.cfg
         pattern = cfg.block_pattern
         counts = jnp.zeros((2,), jnp.int32) if self.has_experts else None
 
@@ -626,36 +653,42 @@ class LM:
                                            counts)
         if cfg.first_k_dense:
             new["lead"] = lead
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = ee.logits(x, self.head(params))[..., :cfg.vocab_size]
-        return logits, new, counts
+        return x, new, counts
 
     def wave_step(self, params, tokens, lens, caches, batch_ctx=None):
-        """One serving *wave*: a fused ``lax.scan`` of ``tokens.shape[1]``
-        masked decode micro-steps.  ``tokens`` (B,C) ragged-right with
-        per-slot valid counts ``lens`` (B,); slot b consumes
-        ``tokens[b, :lens[b]]`` and idles (caches untouched) afterwards.
+        """One serving *wave*.  ``tokens`` (B,C) ragged-right with per-slot
+        valid counts ``lens`` (B,); slot b consumes ``tokens[b, :lens[b]]``
+        at positions ``len[b] + t``, and a slot with ``lens[b] == 0`` keeps
+        its caches bit-identical.
 
-        Because each micro-step is exactly :meth:`decode_step` with the
-        ``active = t < lens`` mask, splitting a prompt across waves of any
-        chunk size replays the *same* micro-step sequence as one big wave —
-        prompt-chunked prefill is bit-identical to whole-prompt prefill.
+        A stack of :data:`PASS_KINDS` blocks (:attr:`prefills_in_one_pass`)
+        feeds the whole (B,C) block in one pass (:meth:`_pass`): each layer
+        projects the block, writes each slot's valid rows into its cache
+        and attends, so a wave streams the weights and the cache once.
+        Every other stack runs a fused ``lax.scan`` of C masked decode
+        micro-steps, each exactly :meth:`decode_step` with the ``active =
+        t < lens`` mask.  Either way token t sees the same cache and takes
+        the same reductions as micro-step t, so splitting a prompt across
+        waves of any chunk size gives bit-identical logits and caches —
+        prompt-chunked prefill equals whole-prompt prefill.
 
-        The micro-step scan carries ``caches``, in their device's layout,
-        and each micro-step writes one row per slot per layer into them in
-        place (:meth:`decode_step`): with ``caches`` donated a wave copies
-        no whole cache, not even at its entry or exit, and the only
-        whole-layer access is decode attention's read.
+        ``caches`` stay in their device's layout and are written in place,
+        a row (a micro-step) or a block of rows (a pass) per slot per
+        layer: with ``caches`` donated a wave copies no whole cache, not
+        even at its entry or exit, and the only whole-layer access is
+        attention's read.
 
-        Returns ``(logits (B,1,V) at each slot's last valid token, caches)``,
-        and for a model with experts a third value, the wave's expert
-        counters (2,) int32: the token-expert assignments that fell on
-        held experts, and the (layer, micro-step, held expert) triples
-        that received at least one token, over the slots each micro-step
-        feeds.
+        Returns ``(logits (B,1,V) at each slot's last valid token, zero
+        for a slot fed nothing, caches)``, and for a model with experts a
+        third value, the wave's expert counters (2,) int32: the
+        token-expert assignments that fell on held experts, and the
+        (layer, micro-step, held expert) triples that received at least
+        one token, over the slots each micro-step feeds.
         """
         b, c = tokens.shape
         lens = lens.astype(jnp.int32)
+        if self.prefills_in_one_pass:
+            return self._pass(params, tokens, lens, caches)
 
         def micro(carry, xs):
             caches, logits_last, counts = carry
@@ -677,6 +710,21 @@ class LM:
         if counts is None:
             return logits_last, caches
         return logits_last, caches, counts
+
+    def _pass(self, params, tokens, lens, caches):
+        """:meth:`wave_step` in one pass over the (B,C) block: the head
+        runs only at each slot's last valid row."""
+        cfg = self.cfg
+        b, c = tokens.shape
+        active = jnp.arange(c, dtype=jnp.int32)[None, :] < lens[:, None]
+        x = self._embed_fed(params, jnp.where(active, tokens, 0))
+        ctx = self._ctx(params, None, batch_size=b)
+        x, caches, _ = self._decode_stack(params, x, caches, ctx, active)
+        last = jnp.maximum(lens - 1, 0)
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = ee.logits(x, self.head(params))[..., :cfg.vocab_size]
+        return jnp.where(lens[:, None, None] > 0, logits, 0.0), caches
 
     def reset_slots(self, caches, keep):
         """Zero the cache state of retired slots (``keep`` (B,) bool) so a

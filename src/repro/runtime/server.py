@@ -8,12 +8,16 @@ The serving loop the Ember steady-state machine is graded under
   prefill and retirement are per-slot operations, never whole-batch
   drains.
 * **Prompt-chunked prefill** — an admitted prompt is consumed in
-  ``prefill_chunk``-token waves (:meth:`~repro.models.lm.LM.wave_step`, a
-  fused ``lax.scan`` of masked decode micro-steps) interleaved with the
-  decode waves of the already-running slots.  Because a wave is exactly
-  the masked micro-step sequence, chunked prefill is **bit-identical** to
+  ``prefill_chunk``-token waves (:meth:`~repro.models.lm.LM.wave_step`)
+  interleaved with the decode waves of the already-running slots.  A stack
+  of GQA attention + MLP blocks feeds a wave's (slots, chunk) token block
+  in one pass; other stacks replay it as a fused ``lax.scan`` of masked
+  decode micro-steps.  Either way each token sees the same cache and takes
+  the same reductions, so chunked prefill is **bit-identical** to
   whole-prompt prefill at any chunk size (tests/test_server.py asserts
   it), and only two traces exist: C=1 (pure decode) and C=prefill_chunk.
+  ``serve_stats`` counts the prefill waves run as one pass
+  (``prefill_passes``) and as a replay (``prefill_replays``).
 * **Prioritized admission + slot recycling** — requests queue on a
   priority heap (lower ``Request.priority`` first, FIFO within a class);
   a slot that hits EOS / max-new / max-len retires *mid-wave*: its cache
@@ -171,7 +175,10 @@ class DecodeServer:
         self._wave = jax.jit(lm.wave_step, donate_argnums=(3,))
         self._reset = jax.jit(lm.reset_slots, donate_argnums=(0,))
         self.waves = 0
+        self._prefill_kind = "prefill_passes" \
+            if lm.prefills_in_one_pass else "prefill_replays"
         self.serve_stats = {"waves": 0, "prefill_waves": 0,
+                            "prefill_passes": 0, "prefill_replays": 0,
                             "decode_waves": 0, "admitted": 0, "finished": 0,
                             "slot_resets": 0, "queue_peak": 0,
                             "shed": 0, "expired": 0, "failed": 0,
@@ -575,7 +582,11 @@ class DecodeServer:
         self._pos += lens
         self.waves += 1
         self.serve_stats["waves"] += 1
-        self.serve_stats["prefill_waves" if c > 1 else "decode_waves"] += 1
+        if c > 1:
+            self.serve_stats["prefill_waves"] += 1
+            self.serve_stats[self._prefill_kind] += 1
+        else:
+            self.serve_stats["decode_waves"] += 1
         if self.artifact_dir is not None and not self._artifact_saved \
                 and self.emb_executor is not None:
             # first wave done: re-save so the artifact carries the AOT

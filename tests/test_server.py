@@ -20,8 +20,10 @@ from repro.runtime.server import DecodeServer, Request
 
 class EchoLM:
     """argmax(logits) == last fed token + 1 (mod vocab); the cache is the
-    per-slot position counter, matching the LM cache tree layout."""
+    per-slot position counter, matching the LM cache tree layout.  It takes
+    a wave's whole token block at once."""
     vocab = 64
+    prefills_in_one_pass = True
 
     def init_caches(self, batch, max_len):
         return {"scan": (),
@@ -325,6 +327,93 @@ def test_wave_step_matches_decode_step_replay(arch, kv):
                           jax.tree.leaves(start[part])):
             np.testing.assert_array_equal(np.take(np.asarray(lw), 2, axis),
                                           np.take(np.asarray(l0), 2, axis))
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+def test_one_pass_block_past_max_len_writes_only_valid_rows(kv):
+    """A one-pass wave whose (slots, chunk) block runs past the cache's
+    end: slot 0 sits 3 rows short of ``max_len`` and feeds 3 of its 8
+    tokens.  Its valid rows land at ``len + t``, exactly as the replayed
+    micro-steps put them, and every other row of every leaf — slot 0's
+    earlier rows, slot 1 past its own valid rows — stays bit-identical."""
+    import dataclasses
+    cfg = dataclasses.replace(get_reduced("stablelm-3b"), kv_cache_dtype=kv)
+    lm = LM(cfg)
+    assert lm.prefills_in_one_pass
+    params = lm.init(jax.random.PRNGKey(0))
+    b, max_len, c = 2, 16, 8
+    wave = jax.jit(lm.wave_step)
+    start = lm.init_caches(b, max_len)
+    for key, lens in ((1, [8, 3]), (2, [5, 0])):
+        warm = jax.random.randint(jax.random.PRNGKey(key), (b, c), 0,
+                                  cfg.vocab_size)
+        _, start = wave(params, warm, jnp.array(lens, jnp.int32), start)
+    pos = np.asarray(start["scan"][0]["len"][0])
+    assert pos.tolist() == [13, 3]
+    toks = jax.random.randint(jax.random.PRNGKey(3), (b, c), 0,
+                              cfg.vocab_size)
+    lens = np.array([3, 5], np.int32)
+    lg, cache = wave(params, toks, jnp.asarray(lens), start)
+    step = jax.jit(lm.decode_step)
+    replay = start
+    for t in range(c):
+        lg_t, replay = step(params, toks[:, t:t + 1], replay, None,
+                            jnp.asarray(t < lens))
+        for slot in range(b):
+            if t == lens[slot] - 1:
+                np.testing.assert_array_equal(np.asarray(lg[slot]),
+                                              np.asarray(lg_t[slot]))
+    for lw, lr in zip(jax.tree.leaves(cache), jax.tree.leaves(replay)):
+        np.testing.assert_array_equal(np.asarray(lw), np.asarray(lr))
+    layer = cache["scan"][0]
+    assert np.asarray(layer["len"][0]).tolist() == [16, 8]
+    for name, leaf in layer.items():
+        if name == "len":
+            continue
+        new, old = np.asarray(leaf), np.asarray(start["scan"][0][name])
+        for slot in range(b):
+            keep = np.ones(max_len, bool)
+            keep[pos[slot]:pos[slot] + lens[slot]] = False
+            np.testing.assert_array_equal(new[:, slot, keep],
+                                          old[:, slot, keep])
+            assert not np.array_equal(new[:, slot, ~keep],
+                                      old[:, slot, ~keep])
+
+
+def test_one_pass_engages_for_dense_stacks_only():
+    """The one-pass wave follows the stack's block kinds: exactly the
+    architectures built of GQA attention + MLP blocks take it, at full
+    and at reduced size; MoE, MLA, recurrent, hybrid and encoder-decoder
+    stacks replay their waves."""
+    from repro.configs import get_config, list_archs
+    dense = {"stablelm-3b", "chatglm3-6b", "gemma3-4b", "h2o-danube-1.8b",
+             "llava-next-34b"}
+    for make in (get_config, get_reduced):
+        assert {a for a in list_archs()
+                if LM(make(a)).prefills_in_one_pass} == dense
+
+
+@pytest.mark.parametrize("arch,kind", [
+    ("stablelm-3b", "prefill_passes"),
+    ("deepseek-v2-lite-16b", "prefill_replays")])
+def test_server_counts_prefill_passes_and_replays(arch, kind):
+    """After a drained run every prefill wave is counted once, as a pass
+    (a dense stack) or as a replay (the MLA + MoE stack)."""
+    cfg = get_reduced(arch)
+    lm = LM(cfg)
+    params = lm.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(2)
+    srv = DecodeServer(lm, params, batch_slots=2, max_len=32,
+                       prefill_chunk=4)
+    for n in (7, 3, 5):
+        srv.submit(_req(rng.integers(0, cfg.vocab_size, n),
+                        max_new_tokens=2))
+    srv.run_until_drained()
+    st = srv.serve_stats
+    assert st["finished"] == 3 and st["prefill_waves"] > 0
+    assert st["prefill_passes"] + st["prefill_replays"] == \
+        st["prefill_waves"]
+    assert st[kind] == st["prefill_waves"]
 
 
 @pytest.mark.parametrize("arch,kv", _decode_cases())

@@ -103,17 +103,12 @@ def test_fusedmm_compiles_for_v5e(one_chip):
     _check(text, temp, nodes * width * 4)
 
 
-@pytest.mark.parametrize("kv", ["model", "int8"])
-def test_serving_wave_writes_cache_in_place_on_v5e(topo, one_chip,
-                                                   monkeypatch, kv):
-    """stablelm-3b widths (2 of its 32 layers), 4 slots x 2048 positions,
-    an 8-token prefill wave over a donated cache in the chip's default
-    layout (sequence minor: head dim 80 is no multiple of 128): every op
-    that yields a whole layer-stacked cache leaf is a row write in place —
-    no relayout copy at the wave's entry or exit, no whole-layer slice,
-    select or copy."""
+def _stablelm_wave(topo, one_chip, monkeypatch, kv):
+    """The compiled text of an 8-token prefill wave at stablelm-3b widths
+    (2 of its 32 layers), 4 slots x 2048 positions, over a donated cache
+    in the chip's default layout (sequence minor: head dim 80 is no
+    multiple of 128).  Returns (cfg, slots, max_len, text)."""
     import dataclasses
-    import re
     from repro.configs import get_config
     from repro.models import LM
     from repro.models import attention
@@ -133,6 +128,22 @@ def test_serving_wave_writes_cache_in_place_on_v5e(topo, one_chip,
         params, on_chip(jax.ShapeDtypeStruct((slots, 8), jnp.int32)),
         on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),
         caches).compile().as_text()
+    return cfg, slots, max_len, text
+
+
+#: an op's result name, shape and opcode in the compiled text
+_OP = r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)"
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+def test_serving_wave_writes_cache_in_place_on_v5e(topo, one_chip,
+                                                   monkeypatch, kv):
+    """Every op that yields a whole layer-stacked cache leaf of the
+    8-token prefill wave is a write in place — no relayout copy at the
+    wave's entry or exit, no whole-layer slice, select or copy."""
+    import re
+    cfg, slots, max_len, text = _stablelm_wave(topo, one_chip, monkeypatch,
+                                               kv)
     # a layer-stacked leaf with a sequence axis
     stack = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[(" +
                        f"{cfg.n_super},{slots}," + r"[\d,]+)\]\S* ([\w-]+)\(")
@@ -145,3 +156,30 @@ def test_serving_wave_writes_cache_in_place_on_v5e(topo, one_chip,
     assert set(moved) == {"dynamic-update-slice"}, \
         [line for op, line in ops if op not in
          ("parameter", "get-tuple-element", "tuple", "dynamic-update-slice")]
+
+
+@pytest.mark.parametrize("kv,leaves", [("model", 2), ("int8", 4)])
+def test_one_pass_wave_writes_a_block_per_slot_on_v5e(topo, one_chip,
+                                                      monkeypatch, kv,
+                                                      leaves):
+    """The prefill wave of a dense stack runs in one pass: no op copies a
+    whole K/V leaf (a layer's or the stack's), and the cache takes one
+    8-row write per slot per leaf per layer — one ``dynamic-update-slice``
+    of a (1, 1, 8, ...) block per slot and leaf in the layer loop — not a
+    row per token."""
+    import re
+    cfg, slots, max_len, text = _stablelm_wave(topo, one_chip, monkeypatch,
+                                               kv)
+    ops = [m.groups() for m in map(re.compile(_OP).match, text.splitlines())
+           if m]
+    shape = {name: dims.split(",") for name, dims, _, _ in ops}
+    leaf = lambda dims: f"{slots},{max_len}" in ",".join(dims)
+    copies = [name for name, dims, op, _ in ops
+              if op.startswith("copy") and leaf(dims.split(","))]
+    assert not copies, copies
+    writes = [args.split(", ")[1].lstrip("%") for name, dims, op, args in ops
+              if op == "dynamic-update-slice" and
+              dims.startswith(f"{cfg.n_super},{slots},{max_len}")]
+    assert len(writes) == slots * leaves, writes
+    for update in writes:
+        assert shape[update][:3] == ["1", "1", "8"], (update, shape[update])
