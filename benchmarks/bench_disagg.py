@@ -15,15 +15,15 @@ Three legs:
   in CI with a loose per-metric tolerance (wall-clock ratio of two small
   numbers is noisy).
 
-* **Kill a replica mid-load** — a continuous-batching ``DecodeServer``
-  (pipeline=True) serving open-loop Poisson arrivals from a 2-replica
-  pool with the heartbeat monitor armed; one replica gets SIGKILL mid
-  load.  Required: every request reaches a terminal status and
-  ``failed_requests == 0`` (in-wave failover + the wave watchdog's
-  reset+retry absorb the crash), the pool recovers the replica via
+* **Kill a replica mid-load** — a model's embedding program
+  (``lm.embedding_program(slots, 1)``) stepped through a disaggregated
+  executor over a 2-replica pool with the heartbeat monitor armed; one
+  replica gets SIGKILL mid load.  Required: every step returns the
+  in-process executor's outputs and ``failed_steps == 0`` (the pool's
+  in-step failover absorbs the crash), the pool recovers the replica via
   respawn + checkpoint re-warm, and ``recovery_s`` is recorded from the
-  pool's breaker-open→probe-pass timestamps.  ``failed_requests`` is
-  gated absolutely: the baseline is 0, any failure trips CI.
+  pool's breaker-open→probe-pass timestamps.  ``failed_steps`` is gated
+  absolutely: the baseline is 0, any failure trips CI.
 
 Writes ``BENCH_disagg.json``; registered in ``benchmarks/run.py`` as
 ``disagg``.
@@ -39,7 +39,7 @@ import numpy as np
 
 DEFAULT_OUT = Path(__file__).resolve().parent.parent / "BENCH_disagg.json"
 
-ARCH = "zamba2-7b"              # single embed program: cheapest real wave
+ARCH = "zamba2-7b"              # no experts: two token gathers a step
 
 POOL_KW = dict(rpc_timeout_s=30.0, backoff_s=0.01)
 
@@ -92,45 +92,45 @@ def _identity_and_steady(pool, fast: bool) -> tuple:
 def _kill_leg(fast: bool) -> dict:
     import jax
     from repro.configs import get_reduced
+    from repro.core.access_plan import EmberFault
+    from repro.core.executor import executor_for
     from repro.models import LM
     from repro.runtime.embedding_service import ServicePool
-    from repro.runtime.server import DecodeServer, Request
 
     cfg = get_reduced(ARCH)
     lm = LM(cfg)
-    params = lm.init(jax.random.PRNGKey(0))
-    n_req, max_new, slots = (10, 4, 2) if fast else (24, 8, 4)
+    table = lm.init(jax.random.PRNGKey(0))["embed"]
+    n_steps, slots = (24, 2) if fast else (64, 4)
+    prog = lm.embedding_program(slots, 1)
     rng = np.random.default_rng(0)
-    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, 6).astype(
-        np.int32), max_new_tokens=max_new) for _ in range(n_req)]
+    steps = []
+    for _ in range(n_steps):
+        toks = rng.integers(0, cfg.vocab_size, slots).astype(np.int32)
+        steps.append({name: {"table": table, "idxs": toks}
+                      for name, _ in prog.ops})
+    ref = executor_for(prog, backend="jax").run_steps(steps)
 
     with ServicePool(2, heartbeat_interval_s=0.05, **POOL_KW) as pool:
-        srv = DecodeServer(lm, params, batch_slots=slots, max_len=32,
-                           pipeline=True, service="disagg",
-                           service_pool=pool)
-        # warm the wave traces + service bind before the clock starts
-        warm = Request(prompt=np.zeros(4, np.int32), max_new_tokens=2)
-        srv.submit(warm)
-        srv.run_until_drained()
+        ex = executor_for(prog, backend="jax", service="disagg",
+                          service_pool=pool)
+        ex.step(steps[0])               # warm the service bind
 
-        # open loop: Poisson arrivals, one replica SIGKILLed mid-load
-        arrivals = np.cumsum(rng.exponential(0.01, size=n_req))
-        t0 = time.perf_counter()
-        kill_at = arrivals[n_req // 3]
-        killed = False
-        i = 0
-        while i < n_req or any(not r.done for r in reqs):
-            now = time.perf_counter() - t0
-            while i < n_req and arrivals[i] <= now:
-                srv.submit(reqs[i])
-                i += 1
-            if not killed and now >= kill_at:
+        # one replica SIGKILLed a third of the way through the steps
+        kill_at = n_steps // 3
+        failed = mismatched = 0
+        for i, ins in enumerate(steps):
+            if i == kill_at:
                 victim = next(j for j, r in enumerate(pool.replicas)
                               if r.state == "live")
                 pool.kill_replica(victim)
-                killed = True
-            srv.step()
-        assert killed, "load drained before the kill point"
+            try:
+                out = ex.step(ins)
+            except EmberFault:
+                failed += 1
+                continue
+            mismatched += not all(
+                np.array_equal(np.asarray(out[k]), np.asarray(ref[i][k]))
+                for k in ref[i])
 
         # the monitor thread drives respawn + artifact re-warm; wait for
         # the pool to be whole again so recovery_s lands in the record
@@ -141,17 +141,10 @@ def _kill_leg(fast: bool) -> dict:
                 "replica never recovered"
         stats = pool.stats()
 
-    statuses = {s: sum(1 for r in reqs if r.status == s)
-                for s in ("ok", "shed", "expired", "failed")}
-    non_terminal = sum(1 for r in reqs if not r.done)
-    assert non_terminal == 0, \
-        f"{non_terminal} requests left without a terminal status"
-    return {"requests": n_req,
-            "statuses": statuses,
-            "failed_requests": statuses["failed"],
-            "non_terminal": non_terminal,
-            "wave_faults": srv.serve_stats["wave_faults"],
-            "wave_retries": srv.serve_stats["wave_retries"],
+    assert mismatched == 0, \
+        f"{mismatched} steps diverged from the in-process executor"
+    return {"steps": n_steps,
+            "failed_steps": failed,
             "recovery_s": round(stats["recoveries_s"][-1], 3)
             if stats["recoveries_s"] else None,
             "rewarm_source": stats["warm_sources"][-1],
@@ -166,8 +159,8 @@ def run_disagg(fast: bool) -> dict:
     with ServicePool(2, **POOL_KW) as pool:
         identity, steady = _identity_and_steady(pool, fast)
     kill = _kill_leg(fast)
-    assert kill["failed_requests"] == 0, \
-        f"replica kill failed {kill['failed_requests']} requests"
+    assert kill["failed_steps"] == 0, \
+        f"replica kill failed {kill['failed_steps']} steps"
     assert kill["rewarm_source"] == "artifact", \
         "respawned replica did not re-warm from the checkpoint artifact"
     assert kill["compile_source"] == "artifact", \
@@ -188,9 +181,8 @@ def run(report, fast: bool = True, out_path: Path = DEFAULT_OUT) -> dict:
            f"ratio={ss['overhead_ratio']}")
     k = rec["disagg"]
     report("disagg/kill_recovery_s", 0,
-           f"recovery={k['recovery_s']}s failed={k['failed_requests']} "
+           f"recovery={k['recovery_s']}s failed={k['failed_steps']} "
            f"rewarm={k['rewarm_source']} compile={k['compile_source']}")
-    report("disagg/kill_statuses", 0, k["statuses"])
     out_path.write_text(json.dumps(rec, indent=2))
     report("disagg/json", 0, str(out_path))
     return rec
@@ -208,7 +200,7 @@ def main() -> None:
 
     rec = run(report, fast=args.fast, out_path=args.out)
     print(f"disagg overhead {rec['steady_state']['overhead_ratio']}x; "
-          f"kill leg: {rec['disagg']['failed_requests']} failed, "
+          f"kill leg: {rec['disagg']['failed_steps']} failed, "
           f"recovered in {rec['disagg']['recovery_s']}s "
           f"({rec['disagg']['rewarm_source']})")
 

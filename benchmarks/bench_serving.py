@@ -1,30 +1,15 @@
 """Open-loop serving benchmark: the continuous-batching DecodeServer graded
 as a *service* (PR-6 tentpole).
 
-Two measurements:
-
-* **Open-loop sweep** — Poisson arrivals at ≥2 target QPS points (derived
-  from a closed-loop capacity calibration, so the sweep is
-  machine-portable), Zipf-distributed prompt token ids, mixed prompt
-  lengths.  Reports p50/p99 time-to-first-token, p50/p99 inter-token
-  latency, and generated tokens/sec at each point.  The server runs with
-  ``pipeline=True``: every wave's access streams feed the
-  :class:`~repro.core.executor.PipelineGroup` whose per-program in-flight
-  and pool hit/miss counters land in the record.  The measured points run
-  with SLO admission armed at a generous budget (so ``shed_rate`` is 0.0
-  unless the server regresses — gated absolutely in CI), and a 16x
-  **overload** point with a tight budget asserts the server sheds the
-  excess instead of queueing it unboundedly while every request still
-  reaches a terminal status.
-
-* **Cross-program pipeline ablation** — at saturating load (back-to-back
-  waves), the wave's two compiled programs (decode embed + MoE un-dispatch)
-  run (a) sequentially through two standalone executors (synchronous
-  step/step — the two-program baseline) and (b) through ``pipeline_group``
-  (wave W+1's embed marshals against the shared pool while wave W's
-  un-dispatch executes).  The pipelined path is REQUIRED to beat the
-  sequential baseline on tokens/sec — asserted here, gated in CI via
-  ``scripts/check_bench_regression.py``.
+**Open-loop sweep** — Poisson arrivals at ≥2 target QPS points (derived
+from a closed-loop capacity calibration, so the sweep is machine-portable),
+Zipf-distributed prompt token ids, mixed prompt lengths.  Reports p50/p99
+time-to-first-token, p50/p99 inter-token latency, and generated tokens/sec
+at each point.  The measured points run with SLO admission armed at a
+generous budget (so ``shed_rate`` is 0.0 unless the server regresses —
+gated absolutely in CI), and a 16x **overload** point with a tight budget
+asserts the server sheds the excess instead of queueing it unboundedly
+while every request still reaches a terminal status.
 
 Writes ``BENCH_serving.json``; registered in ``benchmarks/run.py`` as
 ``serving``.
@@ -40,7 +25,7 @@ import numpy as np
 
 DEFAULT_OUT = Path(__file__).resolve().parent.parent / "BENCH_serving.json"
 
-ARCH = "qwen3-moe-235b-a22b"     # MoE: the wave has both pipeline programs
+ARCH = "qwen3-moe-235b-a22b"     # MoE: the wave routes over experts
 
 
 def _percentiles(xs, scale=1e3) -> dict:
@@ -119,79 +104,11 @@ def _open_loop(make_server, reqs, qps: float, seed: int):
             if i >= len(reqs):
                 break
             time.sleep(max(0.0, arrivals[i] - (time.perf_counter() - t0)))
-    srv.run_until_drained()             # settle the pipeline group + stats
+    srv.run_until_drained()
     dt = time.perf_counter() - t0
     offered = len(reqs) / float(arrivals[-1])
     return {"target_qps": round(qps, 2), "offered_qps": round(offered, 2),
             **_serve_metrics(reqs, dt)}, srv
-
-
-def _pipeline_ablation(lm, wave_batch: int, n_waves: int, fast: bool):
-    """Sequential two-program baseline vs pipeline_group at saturating load
-    (back-to-back waves, interleaved best-of-N timing)."""
-    import jax.numpy as jnp
-    from repro.core.executor import ProgramExecutor, pipeline_group
-    from repro.core.pipeline import compile_program
-    from repro.models import moe as moe_mod
-    try:
-        from . import bench_steady_state as bss
-    except ImportError:                 # run as a script, not a package
-        import bench_steady_state as bss
-    _time_variants = bss._time_variants
-
-    cfg = lm.cfg
-    prog_a = lm.decode_embed_program(wave_batch)
-    prog_b = moe_mod.undispatch_program(cfg, wave_batch)
-    pres_a = compile_program(prog_a, "O3")
-    pres_b = compile_program(prog_b, "O3")
-    undisp = prog_b.op("moe_undispatch")
-    emb_tbl = jnp.zeros((cfg.padded_vocab, cfg.d_model), jnp.float32)
-    cap_tbl = jnp.zeros((undisp.num_embeddings, undisp.emb_len), jnp.float32)
-    rng = np.random.default_rng(7)
-    waves = []
-    for _ in range(n_waves):
-        toks = ((rng.zipf(1.3, size=wave_batch) - 1)
-                % cfg.padded_vocab).astype(np.int32)
-        slots = rng.integers(0, undisp.num_embeddings,
-                             undisp.num_segments).astype(np.int32)
-        waves.append((
-            {"tok_embed": {"table": emb_tbl, "idxs": toks},
-             "label_gather": {"table": emb_tbl, "idxs": toks}},
-            {"moe_undispatch": {"table": cap_tbl, "idxs": slots}}))
-
-    ex_a_seq = ProgramExecutor(pres_a, backend="jax", depth=2)
-    ex_b_seq = ProgramExecutor(pres_b, backend="jax", depth=2)
-
-    def sequential(batch):
-        for ins_a, ins_b in batch:
-            ex_a_seq.step(ins_a)
-            ex_b_seq.step(ins_b)
-
-    grp = pipeline_group([ProgramExecutor(pres_a, backend="jax", depth=2),
-                          ProgramExecutor(pres_b, backend="jax", depth=2)])
-    name_a, name_b = grp.names
-
-    def pipelined(batch):
-        for ins_a, ins_b in batch:
-            grp.submit_wave({name_a: ins_a, name_b: ins_b})
-        grp.drain()
-
-    out = _time_variants({"sequential": sequential,
-                          "pipelined": pipelined}, waves)
-    # the acceptance bar: cross-program pipelining must beat the
-    # sequential two-program baseline on tokens/sec at saturating load
-    # (fast smoke sizes get 5% noise grace, like bench_steady_state)
-    grace = 1.05 if fast else 1.0
-    assert out["pipelined"] <= out["sequential"] * grace, \
-        (f"pipeline_group lost to the sequential baseline: "
-         f"{out['pipelined']:.1f}us vs {out['sequential']:.1f}us per wave")
-    tps = {k: round(wave_batch / v * 1e6, 1) for k, v in out.items()}
-    return {"wave_batch": wave_batch, "waves": n_waves,
-            "us_per_wave": {k: round(v, 1) for k, v in out.items()},
-            "sequential_tokens_per_sec": tps["sequential"],
-            "pipelined_tokens_per_sec": tps["pipelined"],
-            "speedup": round(out["sequential"] / out["pipelined"], 3),
-            "group_stats": grp.group_stats()}
 
 
 def run_serving(fast: bool) -> dict:
@@ -205,24 +122,24 @@ def run_serving(fast: bool) -> dict:
     params = lm.init(jax.random.PRNGKey(0))
     if fast:
         slots, n_req, max_new, len_hi, max_len, chunk = 4, 12, 5, 8, 32, 4
-        wave_batch, n_waves = 64, 10
     else:
         slots, n_req, max_new, len_hi, max_len, chunk = 8, 40, 10, 16, 64, 4
-        wave_batch, n_waves = 512, 20
 
     def make_server(capacity=None, slo=None):
         return DecodeServer(lm, params, batch_slots=slots, max_len=max_len,
-                            prefill_chunk=chunk, pipeline=True,
-                            capacity_rps=capacity, ttft_slo_s=slo)
+                            prefill_chunk=chunk, capacity_rps=capacity, ttft_slo_s=slo)
 
     def fresh_reqs(seed):
         return _workload(cfg, n_req, seed, max_new=max_new, len_lo=2,
                          len_hi=len_hi)
 
-    # warm both wave traces (C=prefill_chunk and C=1) and the executor
-    # marshaling caches so the calibration measures steady state, not compile
-    _closed_loop(make_server, _workload(cfg, 3, 9, max_new=max_new,
-                                        len_lo=2, len_hi=len_hi))
+    # warm both wave traces (C=prefill_chunk and C=1) so the calibration
+    # and every open-loop point measure steady state, not compile.  Each
+    # server jits the model's wave afresh; JAX's trace and compile caches
+    # find the earlier executable only while a server that holds an equal
+    # wave function is alive, so the warm server lives through the run
+    _, warm_srv = _closed_loop(make_server, _workload(
+        cfg, 3, 9, max_new=max_new, len_lo=2, len_hi=len_hi))
     calib, _ = _closed_loop(make_server, fresh_reqs(0))
     capacity = max(calib["requests_per_sec"], 1e-3)
     # SLO machinery armed at the measured points with a generous budget
@@ -260,20 +177,15 @@ def run_serving(fast: bool) -> dict:
         (f"overload TTFT p99 {ov['ttft_ms']['p99']}ms exceeds the "
          f"{tight * 1e3:.0f}ms budget — admitted work queued past its SLO")
 
-    pipe = _pipeline_ablation(lm, wave_batch, n_waves, fast)
     return {
         "config": {"fast": fast, "arch": ARCH, "slots": slots,
                    "requests": n_req, "max_new": max_new,
-                   "prefill_chunk": chunk, "max_len": max_len,
-                   "wave_batch": wave_batch},
+                   "prefill_chunk": chunk, "max_len": max_len},
         "calibration": {"capacity_rps": capacity,
                         "closed_loop_tokens_per_sec":
                             calib["tokens_per_sec"]},
         "open_loop": open_loop,
-        "pipeline": pipe,
         "server_stats": dict(last_srv.serve_stats),
-        "server_pipeline_group":
-            last_srv.compile_stats.get("pipeline_group", {}),
     }
 
 
@@ -287,18 +199,6 @@ def run(report, fast: bool = True, out_path: Path = DEFAULT_OUT) -> dict:
                f"tok/s={m['tokens_per_sec']}")
         report(f"serving/{point}_shed_rate", 0,
                f"shed_rate={m['shed_rate']} statuses={m['statuses']}")
-    pipe = rec["pipeline"]
-    report("serving/pipeline_speedup", pipe["us_per_wave"]["pipelined"],
-           pipe["speedup"])
-    # the pipeline-group's own accounting: per-program in-flight peaks and
-    # the shared staging pool's hit/miss/grown counters
-    gs = pipe["group_stats"]
-    for prog, n in gs["max_in_flight"].items():
-        report(f"serving/group_max_inflight/{prog}", 0, n)
-    pool = gs["pool"]
-    report("serving/group_pool", 0,
-           f"hits={pool['hits']} misses={pool['misses']} "
-           f"grown={pool['grown']} forced_drains={pool['forced_drains']}")
     out_path.write_text(json.dumps(rec, indent=2))
     report("serving/json", 0, str(out_path))
     return rec
@@ -317,8 +217,7 @@ def main() -> None:
     rec = run(report, fast=args.fast, out_path=args.out)
     sat = rec["open_loop"]["saturating"]
     print(f"saturating: {sat['tokens_per_sec']} tok/s, "
-          f"TTFT p99 {sat['ttft_ms']['p99']}ms; pipeline speedup "
-          f"{rec['pipeline']['speedup']}x over sequential")
+          f"TTFT p99 {sat['ttft_ms']['p99']}ms")
 
 
 if __name__ == "__main__":

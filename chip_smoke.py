@@ -297,8 +297,9 @@ def phase_lm(clock: CompileClock, *, cfg=None, seed=0):
     assert all(len(r.out) == LM_NEW for r in reqs), [len(r.out) for r in reqs]
     serve_compile_s = clock.take()
 
-    # the server's Ember executor: token rows through the Pallas gather
-    ex = srv.emb_executor
+    # the model's Ember embedding program: token rows through the Pallas
+    # gather (the server itself embeds inside its jitted wave)
+    ex = lm.embedding_executor(LM_SLOTS, 1)
     assert ex.backend == "pallas" and not ex.interpret
     ids = np.array([r.prompt[0] for r in reqs], np.int32)
     gathered = ex.step({n: {"table": params["embed"], "idxs": ids}

@@ -67,25 +67,23 @@ METRICS = (
     ("BENCH_locality.json", "non_stationary.post_drift_hot_hit_rate",
      "higher"),
     # serving loop (PR 6): p99 service latency must not inflate, and
-    # neither open-loop throughput nor the cross-program pipeline's
-    # tokens/sec may fall behind the committed baseline
+    # open-loop throughput may not fall behind the committed baseline
     ("BENCH_serving.json", "open_loop.saturating.ttft_ms.p99", "lower"),
     ("BENCH_serving.json", "open_loop.saturating.token_latency_ms.p99",
      "lower"),
     ("BENCH_serving.json", "open_loop.saturating.tokens_per_sec",
      "higher"),
-    ("BENCH_serving.json", "pipeline.pipelined_tokens_per_sec", "higher"),
     # fault tolerance (PR 7): the saturating point must not start shedding
     # where the baseline didn't — a shed-rate jump >0.10 absolute means
     # the server got slower and the SLO admission is covering for it
     ("BENCH_serving.json", "open_loop.saturating.shed_rate", "lower",
      "abs"),
     # disaggregated tier (PR 8): killing a replica mid-load must fail
-    # ZERO requests (baseline 0; abs mode means any failure trips), and
+    # ZERO steps (baseline 0; abs mode means any failure trips), and
     # the steady-state RPC overhead ratio must stay bounded — gated with
     # a loose per-metric tolerance (5th element) since it is a wall-clock
     # ratio of two small numbers
-    ("BENCH_disagg.json", "disagg.failed_requests", "lower", "abs"),
+    ("BENCH_disagg.json", "disagg.failed_steps", "lower", "abs"),
     ("BENCH_disagg.json", "steady_state.overhead_ratio", "lower", "rel",
      0.5),
     # AOT serving artifact (PR 10): the artifact-loaded boot's TTFT may

@@ -44,14 +44,12 @@ if [[ "$FAST" == 1 ]]; then
   python benchmarks/bench_locality.py --fast
   # open-loop serving smoke: continuous-batching server under Poisson load
   # at 2 QPS points + a 16x overload point (asserts the SLO admission
-  # sheds instead of queueing unboundedly) + the cross-program pipeline
-  # ablation (asserts pipeline_group beats the sequential two-program
-  # baseline), refreshes BENCH_serving.json
+  # sheds instead of queueing unboundedly), refreshes BENCH_serving.json
   python benchmarks/bench_serving.py --fast
   # disaggregated embedding tier smoke: asserts disagg outputs are
   # bit-identical to in-process, measures the steady-state RPC overhead
   # ratio, and runs the kill-a-replica-mid-load leg (failover + respawn +
-  # checkpoint re-warm; failed_requests==0 required), refreshes
+  # checkpoint re-warm; failed_steps==0 required), refreshes
   # BENCH_disagg.json
   python benchmarks/bench_disagg.py --fast
   # cold-start smoke: boots the same program three ways in subprocesses

@@ -31,8 +31,8 @@ call-site key, the cache deserializes the stored executable (~ms)
 instead of tracing + XLA-compiling (~100s of ms); a payload that fails
 to deserialize (version skew the fingerprint could not see) falls back
 to a live ``fn.lower(...).compile()`` for that key alone.  Call sites
-inside a live jax trace (the serving wave executable, shard_map bodies)
-cannot host an AOT-compiled callable and keep the plain jit path — for
+inside a live jax trace (shard_map bodies) cannot host an AOT-compiled
+callable and keep the plain jit path — for
 them the artifact still saves the PassManager re-run via the hydrated
 compile cache, and the docs call the residual trace-on-load out.
 """

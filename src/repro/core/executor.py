@@ -107,53 +107,6 @@ class StepHandle:
         return self.outputs
 
 
-class _TxnRef:
-    """Placeholder for one host array riding a :class:`TransferBatch`."""
-    __slots__ = ("i",)
-
-    def __init__(self, i: int):
-        self.i = i
-
-
-class TransferBatch:
-    """One serving wave's coalesced host→device transfer.
-
-    :meth:`PipelineGroup.submit_wave` hands every member executor the same
-    batch: gather-kind jax units stage their per-step streams into it
-    instead of issuing individual transfers, and defer their dispatch as a
-    pure ``run(dev_inputs) -> {op name: output}`` function.  :meth:`flush`
-    ships every collected array in one batched ``jax.device_put`` and runs
-    the deferred dispatches; the pipeline group goes further and traces
-    all of them into a single jitted wave executable
-    (:meth:`PipelineGroup.submit_wave`).  Per-wave transfer and dispatch
-    overhead is paid once per *wave* instead of once per *array/op* — the
-    structural edge of the pipelined serving path over stepping the
-    programs sequentially (benchmarks/bench_serving.py's ablation)."""
-
-    def __init__(self):
-        self._host: list = []
-        # (handle outputs dict, run fn, staged inputs with _TxnRefs)
-        self.fills: list = []
-        self.n_arrays = 0
-
-    def put(self, arr: np.ndarray) -> _TxnRef:
-        self._host.append(arr)
-        self.n_arrays += 1
-        return _TxnRef(len(self._host) - 1)
-
-    def defer(self, outs: dict, run, staged: dict) -> None:
-        self.fills.append((outs, run, staged))
-
-    def flush(self) -> None:
-        """One batched device_put, then the deferred unit dispatches
-        (eagerly — the group's jitted wave path is in submit_wave)."""
-        devs = jax.device_put(self._host) if self._host else []
-        fills, self.fills, self._host = self.fills, [], []
-        for outs, run, staged in fills:
-            outs.update(run({k: devs[v.i] if isinstance(v, _TxnRef) else v
-                             for k, v in staged.items()}))
-
-
 class BufferPool:
     """Rotating host staging buffers behind the per-step marshaling.
 
@@ -162,39 +115,17 @@ class BufferPool:
     :meth:`ProgramExecutor.submit`), so a slot is never rewritten while its
     transfer may still be in flight.  Acquisition scans the ring for a free
     slot; when every slot is busy the ring **grows** (up to ``max_slots``)
-    instead of stalling — with a *shared* pool a forced drain would block
-    program A's marshal on program B's execute, exactly the serialization
-    the pipeline group exists to avoid.  Only a full ring at ``max_slots``
-    pays a ``forced_drains`` stall.
-
-    ``shared=False`` (each executor's private default) keys entries by
-    ``(executor, unit, capacity bucket)`` — the legacy double-buffer
-    layout.  ``shared=True`` (:func:`pipeline_group`) keys by the canonical
-    *buffer spec signature* alone, so same-shaped staging of different
-    compiled programs draws from one ring: the device-buffer pool that lets
-    two programs pipeline against each other.  Sharing is safe because
-    every marshal path fully overwrites what its kernel reads (CSR tails
-    are padded in-bounds per step).
+    instead of stalling.  Only a full ring at ``max_slots`` pays a
+    ``forced_drains`` stall.  Each executor owns its pool, and keys its
+    entries by ``(unit, capacity bucket)``.
     """
 
-    def __init__(self, n_slots: int = 2, max_slots: Optional[int] = None,
-                 shared: bool = False):
+    def __init__(self, n_slots: int = 2, max_slots: Optional[int] = None):
         self.n_slots = max(2, n_slots)
         self.max_slots = max(self.n_slots, max_slots or self.n_slots * 4)
-        self.shared = shared
         self._entries: dict = {}
         self.stats = {"entries": 0, "hits": 0, "misses": 0, "grown": 0,
                       "forced_drains": 0, "bytes": 0}
-
-    @staticmethod
-    def spec_sig(spec: dict) -> tuple:
-        return tuple(sorted((k, tuple(shape), np.dtype(dt).str)
-                            for k, (shape, dt) in spec.items()))
-
-    def key_for(self, owner_tag, bucket, spec: dict):
-        if self.shared:
-            return self.spec_sig(spec)
-        return (owner_tag, bucket)
 
     @staticmethod
     def _alloc(spec: dict) -> dict:
@@ -324,7 +255,6 @@ class ProgramExecutor:
                  shard_axis: str = "model", hot_rows=None,
                  exchange: Optional[str] = None,
                  replicate_outputs: Optional[bool] = None,
-                 pool: Optional[BufferPool] = None,
                  index_policy: str = "strict",
                  faults=None, service: str = "inproc",
                  service_pool=None, degrade_policy: str = "fail",
@@ -419,12 +349,8 @@ class ProgramExecutor:
         self._units = [_UnitState(u) for u in compiled.units]
         for u in self._units:
             u.plan = self._plan_for(u)
-        # host staging: private ring pool by default, or a shared pool
-        # handed in by pipeline_group (same entries serve every member)
-        self.pool = pool or BufferPool(n_slots=max(2, depth + 1))
-        self._pool_tag = object()         # private-pool key namespace
+        self.pool = BufferPool(n_slots=max(2, depth + 1))  # host staging
         self._slots_packed: list = []     # slots the current dispatch used
-        self._txn: Optional[TransferBatch] = None   # wave-coalesced puts
         self._inflight: deque = deque()
         self._steps = 0
         # input hardening of the per-step offset streams (every marshaling
@@ -617,15 +543,12 @@ class ProgramExecutor:
     def _scratch_for(self, unit_idx: int, bucket: tuple, spec: dict):
         """Rotating host scratch per (unit, shape bucket), drawn from the
         executor's :class:`BufferPool` (``depth + 1`` slots min 2 keep the
-        steady-state private pipeline from ever stalling on a busy slot; a
-        shared pool grows its ring instead — see :class:`BufferPool`).
+        steady-state pipeline from ever stalling on a busy slot).
         Slot-owner accounting (recorded by :meth:`submit`) guarantees
         packing step N+k never races an in-flight transfer, regardless of
-        how ``submit`` and ``step`` calls interleave across the programs
-        sharing the pool."""
+        how ``submit`` and ``step`` calls interleave."""
         self._fire("marshal")
-        key = self.pool.key_for((self._pool_tag, unit_idx), bucket, spec)
-        entry, turn, created = self.pool.acquire(key, spec)
+        entry, turn, created = self.pool.acquire((unit_idx, bucket), spec)
         self.stats["marshal_misses" if created else "marshal_hits"] += 1
         self._slots_packed.append((entry, turn))
         return entry["slots"][turn]
@@ -924,8 +847,7 @@ class ProgramExecutor:
     # ------------------------------------------------------------------
 
     def _execute(self, u: _UnitState, ins: dict, ml, aot=None):
-        """``aot`` is only ever passed at *eager* call sites: run-closures
-        traced into the wave executable (:meth:`_unit_run`) and shard_map
+        """``aot`` is only ever passed at *eager* call sites: shard_map
         bodies cannot invoke an AOT-compiled callable mid-trace, so they
         keep the plain jit path (trace-on-load fallback, see
         :mod:`repro.core.artifact`)."""
@@ -933,40 +855,6 @@ class ProgramExecutor:
             if self.backend == "jax":
                 return bj.execute(u.res.op, ins, aot=aot)
             return bp.execute(u.res, ins, max_lookups=ml, aot=aot)
-
-    def _txn_defer(self, outs: dict, dev: dict, run) -> None:
-        """Stage a gather-kind unit's per-step host arrays on the wave's
-        :class:`TransferBatch` and defer its dispatch to the batched flush
-        (device-resident values ride through untouched).  ``run`` must be a
-        *stable* (cached per unit) pure function of the device inputs — the
-        pipeline group traces the wave's runs into one jitted executable
-        and reuses it across waves keyed on those function identities."""
-        txn = self._txn
-        staged = {k: txn.put(v) if isinstance(v, np.ndarray) else v
-                  for k, v in dev.items()}
-        txn.defer(outs, run, staged)
-
-    def _unit_run(self, u: _UnitState):
-        """The unit's deferred-dispatch function (memoized on the unit so
-        jitted wave executables can be cached on its identity)."""
-        run = getattr(u, "txn_run", None)
-        if run is not None:
-            return run
-        if u.group is None:
-            name, op = u.unit.names[0], u.res.op
-
-            def run(d):
-                return {name: bj.execute(op, d)}
-        else:
-            members = tuple(zip(u.group.members, u.group.member_ops,
-                                u.group.seg_offsets))
-
-            def run(d, u=u, members=members):
-                fused = self._execute(u, d, None)
-                return {name: fused[off:off + mop.num_segments]
-                        for name, mop, off in members}
-        u.txn_run = run
-        return run
 
     def _harden_unit(self, u: _UnitState, inputs: dict) -> dict:
         """Validate the unit's offset streams against its AccessPlan under
@@ -1001,15 +889,6 @@ class ProgramExecutor:
                     name = u.unit.names[0]
                     key = "x" if u.res.op.kind == "fusedmm" else "table"
                     ins = {**uin[name], key: u.table}
-                    if self._txn is not None and \
-                            u.res.op.kind in ("gather", "kg"):
-                        # CSR-kind jax units derive segment ids on the host
-                        # from these streams — only pure-device gathers ride
-                        # the batched transfer
-                        norm = {k: v if isinstance(v, jax.Array)
-                                else np.asarray(v) for k, v in ins.items()}
-                        self._txn_defer(outs, norm, self._unit_run(u))
-                        continue
                     with tracing.span("submit.dispatch"):
                         outs[name] = bj.execute(u.res.op, ins,
                                                 aot=self.aot)
@@ -1032,9 +911,6 @@ class ProgramExecutor:
                          else self._run_csr_sharded(idx, u, uin))
             elif u.group.op.kind == "gather":
                 dev, ml = self._marshal_gather(idx, u, uin)
-                if self._txn is not None and self.backend == "jax":
-                    self._txn_defer(outs, dev, self._unit_run(u))
-                    continue
                 fused = self._execute(u, dev, ml, aot=self.aot)
             else:
                 dev, ml = self._marshal_csr(idx, u, uin)
@@ -1046,17 +922,11 @@ class ProgramExecutor:
                     outs[name] = fused[off:off + mop.num_segments]
         return outs
 
-    def submit(self, inputs: dict, txn: Optional[TransferBatch] = None
-               ) -> StepHandle:
+    def submit(self, inputs: dict) -> StepHandle:
         """Dispatch one step asynchronously: marshal + launch now, block
         never.  At ``depth`` steps in flight the oldest is drained first
         (backpressure), so step N+1's access stream is prepared while step
-        N's execute phase runs — the cross-step DAE overlap.
-
-        With ``txn`` (:meth:`PipelineGroup.submit_wave`), gather-kind units
-        stage their streams on the shared :class:`TransferBatch` and their
-        dispatch is deferred to its flush; the handle's outputs materialize
-        then.  Sharded executors route their own exchange and ignore it."""
+        N's execute phase runs — the cross-step DAE overlap."""
         with tracing.span("submit", self._steps):
             self._fire("dispatch")
             with tracing.span("submit.wait"):
@@ -1070,12 +940,7 @@ class ProgramExecutor:
             if self.service == "disagg":
                 outs, pending = self._submit_disagg(inputs)
             else:
-                pending = None
-                self._txn = txn if self.shards == 1 else None
-                try:
-                    outs = self._dispatch(inputs)
-                finally:
-                    self._txn = None
+                outs, pending = self._dispatch(inputs), None
             h = StepHandle(outs, self._steps, faults=self.faults,
                            pending=pending)
             for entry, turn in self._slots_packed:
@@ -1216,15 +1081,8 @@ class ProgramExecutor:
             h.done = True
         self._inflight.clear()
         self._slots_packed = []
-        self._txn = None
         self.pool.release_all()
         self.stats["resets"] += 1
-
-    def use_pool(self, pool: BufferPool) -> None:
-        """Re-home host staging onto ``pool`` (the pipeline-group join).
-        Slots of the old pool still owned by in-flight handles stay alive
-        through those handles; new marshals draw from the shared rings."""
-        self.pool = pool
 
     # ------------------------------------------------------------------
     # Adaptive locality: windowed counters, drift detection, slab swap
@@ -1478,224 +1336,6 @@ class ProgramExecutor:
                 r.duration_s for r in self.compiled.pass_records()
                 if r.name == "plan-access" and r.ran), 6),
         }
-
-
-# ---------------------------------------------------------------------------
-# Pipeline group: two (or more) compiled programs overlapped through one
-# shared staging pool — cross-PROGRAM access/execute overlap.
-# ---------------------------------------------------------------------------
-
-class PipelineGroup:
-    """Cross-program pipelining over a shared :class:`BufferPool`.
-
-    :meth:`ProgramExecutor.submit` already overlaps step N+1's access-side
-    marshal with step N's execute *within* one program.  A serving wave is
-    two programs back to back — the decode embed of wave W+1 and the MoE
-    un-dispatch of wave W — and running them through separate executors
-    serializes at each program's own backpressure.  The group re-homes every
-    member onto one shared pool (entries keyed by buffer-spec signature, so
-    same-shaped staging is one ring) and accounts in-flight steps per
-    program, so program A's marshal proceeds while program B executes.
-
-    ``depth`` is the group-level backpressure bound (default: the sum of
-    the members' depths — members throttle themselves first; pass a smaller
-    value to cap total in-flight work across programs)."""
-
-    def __init__(self, executors, names=None, depth: Optional[int] = None,
-                 n_slots: Optional[int] = None,
-                 max_slots: Optional[int] = None):
-        assert executors, "pipeline_group needs at least one executor"
-        self.executors = list(executors)
-        self.names = list(names) if names is not None else [
-            ex.compiled.program.name for ex in self.executors]
-        assert len(set(self.names)) == len(self.names), \
-            f"ambiguous program names: {self.names}"
-        self._by_name = dict(zip(self.names, self.executors))
-        slots = n_slots or max(max(2, ex.depth + 1)
-                               for ex in self.executors)
-        self.pool = BufferPool(n_slots=slots, max_slots=max_slots,
-                               shared=True)
-        for ex in self.executors:
-            ex.drain()                  # old-pool slots settle before rehome
-            ex.use_pool(self.pool)
-        self.depth = depth or sum(ex.depth for ex in self.executors)
-        self._inflight: deque = deque()   # (name, StepHandle)
-        self._wave_fns: dict = {}         # wave signature -> jitted fn
-        # group-level chaos injector (sites: dispatch at submit_wave,
-        # transfer at the wave flush, result on the wave's handles); set by
-        # the server so cached member executors stay untouched
-        self.faults = None
-        self.stats = {
-            "submitted": {n: 0 for n in self.names},
-            "in_flight": {n: 0 for n in self.names},
-            "max_in_flight": {n: 0 for n in self.names},
-            "waves": 0,
-            "batched_arrays": 0,
-            "resets": 0,
-        }
-
-    def _fire(self, site: str) -> None:
-        if self.faults is not None:
-            self.faults.fire(site, group=tuple(self.names))
-
-    def executor(self, name: str) -> ProgramExecutor:
-        return self._by_name[name]
-
-    def _gc(self) -> None:
-        """Drop handles resolved elsewhere (member backpressure, caller
-        ``result()``) from the group ledger."""
-        live: deque = deque()
-        for n, h in self._inflight:
-            if h.done:
-                self.stats["in_flight"][n] -= 1
-            else:
-                live.append((n, h))
-        self._inflight = live
-
-    def submit(self, name: str, inputs: dict) -> StepHandle:
-        """Dispatch one step of member ``name`` asynchronously, under both
-        the member's own depth bound and the group bound."""
-        self._gc()
-        while len(self._inflight) >= self.depth:
-            n0, h0 = self._inflight.popleft()
-            h0.result()
-            self.stats["in_flight"][n0] -= 1
-        h = self._by_name[name].submit(inputs)
-        self._inflight.append((name, h))
-        st = self.stats
-        st["submitted"][name] += 1
-        st["in_flight"][name] += 1
-        st["max_in_flight"][name] = max(st["max_in_flight"][name],
-                                        st["in_flight"][name])
-        return h
-
-    def step(self, name: str, inputs: dict) -> dict:
-        """Synchronous convenience: group submit + block on the result."""
-        return self.submit(name, inputs).result()
-
-    def submit_wave(self, wave: dict) -> dict:
-        """Submit one serving wave — ``{program name: inputs}`` — across
-        members as ONE co-scheduled dispatch: every member marshals its
-        access streams onto a shared :class:`TransferBatch`, one batched
-        ``jax.device_put`` ships them all, and the members' deferred unit
-        dispatches are traced into a single jitted wave executable (cached
-        on the wave's unit/shape signature, so steady-state waves never
-        retrace).  Returns ``{name: StepHandle}``."""
-        self._fire("dispatch")
-        self._gc()
-        while len(self._inflight) > max(0, self.depth - len(wave)):
-            n0, h0 = self._inflight.popleft()
-            h0.result()
-            self.stats["in_flight"][n0] -= 1
-        txn = TransferBatch()
-        handles = {}
-        for name, inputs in wave.items():
-            handles[name] = self._by_name[name].submit(inputs, txn=txn)
-        self._flush_wave(txn)
-        if self.faults is not None:
-            for h in handles.values():
-                h.faults = self.faults
-        st = self.stats
-        st["waves"] += 1
-        st["batched_arrays"] += txn.n_arrays
-        for name, h in handles.items():
-            self._inflight.append((name, h))
-            st["submitted"][name] += 1
-            st["in_flight"][name] += 1
-            st["max_in_flight"][name] = max(st["max_in_flight"][name],
-                                            st["in_flight"][name])
-        return handles
-
-    def _flush_wave(self, txn: TransferBatch) -> None:
-        """Flush the wave's deferred dispatches through one jitted wave
-        executable.  The trace closes over nothing: device-resident
-        constants (stacked tables, fused row offsets) and the batched
-        per-wave streams are both arguments, so a table rebind is just a
-        different argument and the cache key only carries unit identities
-        and array shapes."""
-        self._fire("transfer")
-        if not txn.fills:
-            txn.flush()                   # nothing deferred: transfers only
-            return
-        host, txn._host = txn._host, []
-        fills, txn.fills = txn.fills, []
-        consts: list = []
-        plan: list = []
-        for _, _, staged in fills:
-            spec = []
-            for k, v in staged.items():
-                if isinstance(v, _TxnRef):
-                    spec.append((k, True, v.i))
-                else:
-                    spec.append((k, False, len(consts)))
-                    consts.append(v)
-            plan.append(tuple(spec))
-        key = (tuple(run for _, run, _ in fills), tuple(plan),
-               tuple((a.shape, a.dtype.str) for a in host),
-               tuple((tuple(c.shape), str(c.dtype)) for c in consts))
-        fn = self._wave_fns.get(key)
-        if fn is None:
-            runs = [run for _, run, _ in fills]
-            splan = tuple(plan)
-
-            def wave_fn(consts, devs):
-                return [run({k: devs[i] if is_dev else consts[i]
-                             for k, is_dev, i in spec})
-                        for run, spec in zip(runs, splan)]
-            fn = jax.jit(wave_fn)
-            self._wave_fns[key] = fn
-        devs = jax.device_put(host) if host else []
-        for (outs, _, _), res in zip(fills, fn(consts, devs)):
-            outs.update(res)
-
-    def drain(self) -> None:
-        for ex in self.executors:
-            ex.drain()
-        for n, h in self._inflight:
-            h.result()
-        self._gc()
-
-    def reset(self) -> None:
-        """Fault recovery across the whole group: abandon every member's
-        in-flight steps (a faulted wave may have left partially staged
-        transfers), clear the group ledger, and release the shared pool's
-        slot owners.  The next :meth:`submit_wave` starts clean — jitted
-        wave executables and bound tables survive."""
-        for n, h in self._inflight:
-            h.done = True
-        self._inflight.clear()
-        for n in self.names:
-            self.stats["in_flight"][n] = 0
-        for ex in self.executors:
-            ex.reset()
-        self.stats["resets"] += 1
-
-    def group_stats(self) -> dict:
-        """Per-program in-flight accounting + the shared pool's counters
-        (what benchmarks/run.py surfaces)."""
-        self._gc()
-        return {
-            "programs": list(self.names),
-            "depth": self.depth,
-            "submitted": dict(self.stats["submitted"]),
-            "in_flight": dict(self.stats["in_flight"]),
-            "max_in_flight": dict(self.stats["max_in_flight"]),
-            "waves": self.stats["waves"],
-            "batched_arrays": self.stats["batched_arrays"],
-            "resets": self.stats["resets"],
-            "pool": dict(self.pool.stats),
-        }
-
-
-def pipeline_group(executors, names=None, depth: Optional[int] = None,
-                   n_slots: Optional[int] = None,
-                   max_slots: Optional[int] = None) -> PipelineGroup:
-    """Join ``executors`` into a :class:`PipelineGroup` sharing one staging
-    pool: ``group.submit("decode-embed", ...)`` marshals wave W+1's embed
-    stream while ``"moe-undispatch"``'s wave-W execute is still in flight.
-    ``names`` defaults to each executor's program name."""
-    return PipelineGroup(executors, names=names, depth=depth,
-                         n_slots=n_slots, max_slots=max_slots)
 
 
 # ---------------------------------------------------------------------------

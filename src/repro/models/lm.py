@@ -289,39 +289,6 @@ class LM:
             vocab_size=cfg.padded_vocab, d_model=cfg.d_model, tokens=tokens,
             extra_ops=tuple(extra), name=f"{cfg.name}-step")
 
-    def decode_embed_program(self, batch: int, seq: int = 1):
-        """The *embed side* of one decode wave as its own program (token
-        embed + label gather over the shared table, no MoE op) — the first
-        member of the serving pipeline group.  Splitting the wave's lookups
-        into two compiled programs is what lets wave W+1's embed marshal
-        overlap wave W's MoE un-dispatch execute."""
-        cfg = self.cfg
-        return ee.model_embedding_program(
-            vocab_size=cfg.padded_vocab, d_model=cfg.d_model,
-            tokens=batch * seq, name=f"{cfg.name}-decode-embed")
-
-    def embedding_pipeline(self, batch: int, seq: int = 1,
-                           opt_level: str = "O3", depth: int = 2,
-                           **kw):
-        """The serving :class:`~repro.core.executor.PipelineGroup`: the
-        decode-embed program plus (for MoE models) the un-dispatch program,
-        joined over one shared staging pool.  Non-MoE models get a
-        single-member group (same API, no second program to overlap).
-
-        Defaults to the jax backend: that is the path whose gather
-        dispatches ride ``submit_wave``'s coalesced transfer + jitted wave
-        executable (differential-tested identical to pallas)."""
-        from ..core.executor import executor_for, pipeline_group
-        kw.setdefault("backend", "jax")
-        cfg = self.cfg
-        members = [executor_for(self.decode_embed_program(batch, seq),
-                                opt_level, depth=depth, **kw)]
-        if self.has_experts:
-            members.append(executor_for(
-                moe_mod.undispatch_program(cfg, batch * seq), opt_level,
-                depth=depth, **kw))
-        return pipeline_group(members)
-
     def compile_embeddings(self, batch: int, seq: int,
                            opt_level: str = "O3"):
         """Compile this model's embedding program (compile-cache backed)."""

@@ -50,17 +50,6 @@ def dispatch_op(cfg: ModelConfig, tokens: int) -> EmbeddingOp:
                        num_embeddings=e * capacity, emb_len=cfg.d_model)
 
 
-def undispatch_program(cfg: ModelConfig, tokens: int, name=None):
-    """The MoE un-dispatch as a standalone one-op
-    :class:`~repro.core.ops.EmbeddingProgram` — the second member of the
-    serving :func:`~repro.core.executor.pipeline_group`: wave W's expert
-    outputs gather back to token order while wave W+1's decode embed
-    marshals against the shared staging pool."""
-    from ..core.ops import EmbeddingProgram
-    return EmbeddingProgram(name or f"{cfg.name}-moe-undispatch",
-                            (("moe_undispatch", dispatch_op(cfg, tokens)),))
-
-
 def init_moe(key, cfg: ModelConfig, dtype):
     """The router over all ``num_experts``, the ``held_experts`` experts
     this device holds, and the shared experts."""

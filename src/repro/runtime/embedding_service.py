@@ -692,10 +692,11 @@ class ServicePool:
             try:
                 if self.faults is not None:
                     self.faults.fire("heartbeat", replica=r.idx)
-                if r.hb is None:
-                    r.hb = RpcClient(_HOST, r.port,
-                                     timeout_s=self.rpc_timeout_s)
-                r.hb.call("ping")
+                with self._lock:         # one call at a time on r.hb
+                    if r.hb is None:
+                        r.hb = RpcClient(_HOST, r.port,
+                                         timeout_s=self.rpc_timeout_s)
+                    r.hb.call("ping")
                 r.misses = 0
                 self.pool_stats["heartbeats"] += 1
             except TRANSPORT_FAULTS:
@@ -818,8 +819,10 @@ class ServicePool:
                                      timeout_s=self.rpc_timeout_s)
                 if self.faults is not None:
                     self.faults.fire("rpc_send", kind=kind)
-                r.hb.call(kind, meta, arrays,
-                          deadline_s=max(self.rpc_timeout_s, 60.0))
+                # the heartbeat thread calls on the same connection
+                with self._lock:
+                    r.hb.call(kind, meta, arrays,
+                              deadline_s=max(self.rpc_timeout_s, 60.0))
                 sent += 1
             except TRANSPORT_FAULTS:
                 # a replica that missed the broadcast re-warms from the

@@ -19,7 +19,7 @@ Error taxonomy (all subclass :class:`EmberFault`):
 * :class:`StragglerTimeout` — the trainer's per-step watchdog deadline
   (hung collectives on a multi-host mesh).
 * :class:`WaveTimeout` — the serving-side analogue: a wave exceeding the
-  server's ``wave_deadline_s`` around ``submit_wave``/``StepHandle.result``.
+  server's ``wave_deadline_s``.
 * :class:`RequestError` — a per-request serving failure carrying the
   request's terminal status; never escapes :meth:`DecodeServer.step`.
 * :class:`RpcError` — the disaggregated embedding tier's transport fault
@@ -35,7 +35,7 @@ them)::
 
     marshal        host index packing (ProgramExecutor._marshal_*/route_*)
     transfer       host->device operand placement (ProgramExecutor._put*)
-    dispatch       step/wave launch (ProgramExecutor.submit)
+    dispatch       step launch (ProgramExecutor.submit)
     result         the consume point (StepHandle.result)
     wave           the serving wave body (DecodeServer.step)
     step           the training step (Trainer.run)

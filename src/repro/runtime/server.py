@@ -24,14 +24,9 @@ The serving loop the Ember steady-state machine is graded under
   region is zeroed (:meth:`~repro.models.lm.LM.reset_slots`) and the next
   queued request is admitted in the same serving iteration, so a freed
   slot never idles a wave.
-* **Cross-program pipelining** (``pipeline=True``) — the wave's access
-  streams are mirrored into the model's
-  :meth:`~repro.models.lm.LM.embedding_pipeline`
-  (:class:`~repro.core.executor.PipelineGroup`): the decode-embed program
-  of wave W+1 marshals against the shared staging pool while the MoE
-  un-dispatch of wave W executes; ``compile_stats["pipeline_group"]``
-  surfaces the per-program in-flight accounting and pool hit/miss
-  counters.
+* **Token embeddings inside the wave** — ``wave_step`` embeds its tokens
+  with a ``take`` on the embedding table, traced into the same jitted
+  program as the blocks.  The server compiles no Ember program of its own.
 
 Per-request service metrics (submit/admit/first-token/done wall-clock
 stamps and per-token times) are recorded on the :class:`Request` itself —
@@ -42,9 +37,7 @@ per-process.
 
 * **Input hardening** — prompts validate against the model vocab under
   ``index_policy`` ("strict" fails the request with a typed error,
-  "clamp"/"drop" repair it and count), and the same policy flows into the
-  pipeline group's executors, whose AccessPlans harden every offset
-  stream they marshal.
+  "clamp"/"drop" repair it and count).
 * **SLO-aware admission** — a request carries a TTFT budget
   (``Request.deadline_s``, or the server-wide ``ttft_slo_s``): submit-time
   shedding predicts queue wait from the calibrated ``capacity_rps`` the
@@ -53,11 +46,11 @@ per-process.
   retired with status ``expired`` — under overload the queue sheds
   instead of growing unboundedly.
 * **Wave watchdog + bounded retry** — ``wave_deadline_s`` bounds the
-  whole wave (LM step + pipeline feed + handle results); a hung or
-  faulted wave resets the pipeline group (abandoning its in-flight
-  steps and staging slots) and retries up to ``wave_retries`` times
-  before failing ONLY the implicated requests; every other slot and all
-  later waves proceed bit-identically to a fault-free run.
+  wave's dispatch; a wave that timed out or raised a typed fault is
+  retried up to ``wave_retries`` times (the LM step itself runs once: it
+  donates the caches) before failing ONLY the implicated requests; every
+  other slot and all later waves proceed bit-identically to a fault-free
+  run.
 
 Each request ends in exactly one terminal ``status``: ``ok`` | ``shed`` |
 ``expired`` | ``failed``.
@@ -108,24 +101,16 @@ _EMPTY = np.zeros(0, np.int32)
 class DecodeServer:
     def __init__(self, lm, params, *, batch_slots: int = 4,
                  max_len: int = 256, eos_id: Optional[int] = None,
-                 prefill_chunk: int = 8, pipeline: bool = False,
+                 prefill_chunk: int = 8,
                  index_policy: str = "strict",
                  capacity_rps=None,
                  capacity_warmup_waves: int = 5,
                  ttft_slo_s: Optional[float] = None,
                  wave_deadline_s: Optional[float] = None,
                  wave_retries: int = 1,
-                 faults=None, service: str = "inproc",
-                 service_pool=None, degrade_policy: str = "fail",
-                 artifact_dir=None):
+                 faults=None):
         assert index_policy in INDEX_POLICIES, index_policy
         self.lm = lm
-        # serving artifact (core/artifact.py): boot hydrates the compile
-        # cache + AOT executables from here instead of compiling; a fresh
-        # compile saves at build and re-saves after the first wave (the
-        # captured executables of the shapes actually served)
-        self.artifact_dir = artifact_dir
-        self._artifact_saved = False
         self.params = params
         self.slots = batch_slots
         self.max_len = max_len
@@ -149,17 +134,6 @@ class DecodeServer:
         self.wave_deadline_s = wave_deadline_s
         self.wave_retries = max(0, int(wave_retries))
         self.faults = faults            # chaos injector (site "wave" here)
-        # disaggregated embedding tier: every member executor routes its
-        # steps to the service pool (cache-keyed on the pool's identity);
-        # a ServiceUnavailable surfacing from a wave is an EmberFault, so
-        # the wave watchdog's reset+retry already covers replica failover
-        assert service in ("inproc", "disagg"), service
-        self.service = service
-        self.service_pool = service_pool
-        self.degrade_policy = degrade_policy
-        self._svc_kw = ({"service": service, "service_pool": service_pool,
-                         "degrade_policy": degrade_policy}
-                        if service == "disagg" else {})
         self._ewma_wave_s: Optional[float] = None   # measured wave time
         # prompt-validation bound: stub LMs expose `vocab`, real ones cfg
         self._vocab = getattr(lm, "vocab", None) or getattr(
@@ -185,80 +159,6 @@ class DecodeServer:
                             "oob_prompt_tokens": 0, "wave_faults": 0,
                             "wave_retries": 0, "watchdog_timeouts": 0,
                             "capacity_rps_live": None}
-        # Ember steady-state path: the decode step's irregular lookups
-        # compile ONCE per (slots, 1) signature and the ProgramExecutor's
-        # marshaling cache (device-resident stacked tables + roff streams)
-        # is memoized alongside — every later wave is a double cache hit.
-        # A model whose ShardCtx mesh has a >1-wide `model` axis gets the
-        # vocab-sharded executor (stacked tables partitioned over the axis).
-        self.emb_compiled = None
-        self.emb_executor = None
-        self.compile_stats: Optional[dict] = None
-        if hasattr(lm, "embedding_program"):
-            from ..core import executor as emb_exec
-            from ..core import pipeline as emberc
-            self._emberc = emberc
-            self._emb_exec = emb_exec
-            self.emb_executor = self._resolve_executor()
-            self.emb_compiled = self.emb_executor.compiled
-        self.pipeline_group = None
-        self._undispatch_name = None
-        if pipeline and hasattr(lm, "embedding_pipeline"):
-            # the server's index policy flows into every member executor
-            # (cache-keyed), so the pipeline's marshaling paths harden the
-            # mirrored streams under the same policy as the prompts
-            self.pipeline_group = lm.embedding_pipeline(
-                batch_slots, 1, index_policy=index_policy, **self._svc_kw)
-            if faults is not None:
-                # group-level attach: cached member executors stay clean
-                self.pipeline_group.faults = faults
-            names = self.pipeline_group.names
-            self._embed_name = names[0]
-            if len(names) > 1:
-                self._undispatch_name = names[1]
-                op = self.pipeline_group.executor(names[1]) \
-                    .compiled.program.op("moe_undispatch")
-                self._cap_buf = jnp.zeros((op.num_embeddings, op.emb_len),
-                                          lm.cfg.jdtype)
-                self._undisp_segments = op.num_segments
-                self._undisp_rows = op.num_embeddings
-        if self.emb_executor is not None:
-            self.compile_stats = self._gather_compile_stats()
-
-    def _resolve_executor(self):
-        kw = dict(self._svc_kw)
-        if self.artifact_dir is not None:
-            kw["artifact_dir"] = self.artifact_dir
-        if hasattr(self.lm, "embedding_executor"):
-            return self.lm.embedding_executor(self.slots, 1, **kw)
-        return self._emb_exec.executor_for(
-            self.lm.embedding_program(self.slots, 1), **kw)
-
-    def _gather_compile_stats(self) -> dict:
-        s = self._emberc.compile_cache_stats()
-        s["executor_cache"] = self._emb_exec.executor_cache_stats()
-        s["executor"] = dict(self.emb_executor.stats)
-        s["executor"]["shards"] = self.emb_executor.shards
-        # sharded serving observability: which exchange moves the offset
-        # streams (host scatter vs device all_to_all) and whether pooled
-        # outputs are reduce-scattered or replicated — with host_syncs in
-        # the stats dict above, the per-step transfer count it saves
-        s["executor"]["exchange"] = self.emb_executor.exchange
-        s["executor"]["replicate_outputs"] = \
-            self.emb_executor.replicate_outputs
-        # the compiled access side, observable: hot/cold layout, exchange
-        # bytes est. vs. actual, per-pass plan-build time (plan-access)
-        s["access_plans"] = self.emb_executor.access_plan_stats()
-        if self.artifact_dir is not None:
-            # where this boot's compile came from + the process-wide
-            # load/reject counters (the version-skew runbook observable)
-            from ..core.artifact import artifact_stats
-            s["artifact"] = {
-                "compile_source": self.emb_executor.compile_source,
-                **artifact_stats()}
-        if self.pipeline_group is not None:
-            s["pipeline_group"] = self.pipeline_group.group_stats()
-        return s
 
     # ------------------------------------------------------------------
     # Admission
@@ -415,31 +315,6 @@ class DecodeServer:
     # Wave loop
     # ------------------------------------------------------------------
 
-    def _feed_pipeline(self, tokens: np.ndarray):
-        """Mirror this wave's access streams into the pipeline group: the
-        decode-embed lookups of THIS wave marshal while the previous wave's
-        un-dispatch gather may still be executing (shared staging pool,
-        per-program in-flight accounting)."""
-        grp = self.pipeline_group
-        toks = np.ascontiguousarray(tokens[:, 0], np.int32)
-        emb = self.params["embed"]
-        wave = {self._embed_name:
-                {"tok_embed": {"table": emb, "idxs": toks},
-                 "label_gather": {"table": emb, "idxs": toks}}}
-        if self._undispatch_name is not None:
-            idxs = (np.arange(self._undisp_segments, dtype=np.int64) *
-                    (int(toks[0]) + 1)) % self._undisp_rows
-            wave[self._undispatch_name] = \
-                {"moe_undispatch": {"table": self._cap_buf,
-                                    "idxs": idxs.astype(np.int32)}}
-        handles = grp.submit_wave(wave)
-        if self.wave_deadline_s is not None:
-            # the watchdog needs a bounded observation point: consume this
-            # wave's handles now (trades the cross-wave overlap for an
-            # enforceable deadline — only paid when a deadline is set)
-            for h in handles.values():
-                h.result()
-
     def step(self) -> int:
         """One serving iteration: admit → one wave (chunked prefill and/or
         decode) → retire + recycle + same-iteration admit.  Returns the
@@ -518,10 +393,10 @@ class DecodeServer:
 
     def _run_wave(self, tokens: np.ndarray, lens: np.ndarray,
                   retired: np.ndarray):
-        """The guarded wave body: LM step + pipeline feed, under the
-        watchdog deadline, retried after a typed fault.  Returns the
-        wave's (unsynced) logits, its expert counters (None for a model
-        without experts) and the time its last attempt started, or
+        """The guarded wave body: the LM step under the watchdog deadline,
+        retried after a typed fault.  Returns the wave's (unsynced)
+        logits, its expert counters (None for a model without experts)
+        and the time its last attempt started, or
         ``(None, None, None)`` once the retries are spent: the slots it
         served have then failed and recycled."""
         tokens_j, lens_j = jnp.asarray(tokens), jnp.asarray(lens)
@@ -536,8 +411,6 @@ class DecodeServer:
                     logits, self.caches, *counts = self._wave(
                         self.params, tokens_j, lens_j, self.caches)
                     lm_done = True
-                if self.pipeline_group is not None:
-                    self._feed_pipeline(tokens)
                 if self.wave_deadline_s is not None:
                     el = time.perf_counter() - t0
                     if el > self.wave_deadline_s:
@@ -550,8 +423,6 @@ class DecodeServer:
                 self.serve_stats["wave_faults"] += 1
                 if isinstance(e, WaveTimeout):
                     self.serve_stats["watchdog_timeouts"] += 1
-                if self.pipeline_group is not None:
-                    self.pipeline_group.reset()
                 if attempt >= self.wave_retries:
                     # fail ONLY the implicated requests (the slots served
                     # by this wave); their slots recycle, the loop lives
@@ -587,15 +458,6 @@ class DecodeServer:
             self.serve_stats[self._prefill_kind] += 1
         else:
             self.serve_stats["decode_waves"] += 1
-        if self.artifact_dir is not None and not self._artifact_saved \
-                and self.emb_executor is not None:
-            # first wave done: re-save so the artifact carries the AOT
-            # executables captured while serving it (idempotent publish)
-            self._artifact_saved = True
-            try:
-                self.emb_executor.save_artifact()
-            except OSError:
-                pass                     # a failed save never fails a wave
         now = time.perf_counter()
         # mid-wave expiry: a slot still waiting on its first token whose
         # TTFT budget lapsed during service retires here (terminal), so an
@@ -648,8 +510,4 @@ class DecodeServer:
                 steps < max_steps:
             self.step()
             steps += 1
-        if self.pipeline_group is not None:
-            self.pipeline_group.drain()
-        if self.emb_executor is not None:
-            self.compile_stats = self._gather_compile_stats()
         return steps

@@ -417,36 +417,3 @@ def test_respawned_replica_skips_recompilation():
             "respawned replica recompiled instead of loading the artifact"
         for _ in range(3):              # the loaded program serves
             _assert_outputs_equal(ref, ex.step(ins))
-
-
-# ---------------------------------------------------------------------------
-# DecodeServer wiring
-# ---------------------------------------------------------------------------
-
-def test_decode_server_boots_from_artifact(tmp_path):
-    """--artifact-dir end to end: the first server saves on its first
-    wave, the second boots with compile_source == "artifact" and surfaces
-    the stats under compile_stats["artifact"]."""
-    import jax
-    from repro.configs import get_reduced
-    from repro.models import LM
-    from repro.runtime.server import DecodeServer, Request
-    cfg = get_reduced("zamba2-7b")
-    lm = LM(cfg)
-    params = lm.init(jax.random.PRNGKey(0))
-
-    def serve_once():
-        clear_executor_cache()
-        clear_compile_cache()
-        srv = DecodeServer(lm, params, batch_slots=2, max_len=16,
-                           artifact_dir=str(tmp_path))
-        r = Request(prompt=np.arange(4, dtype=np.int32), max_new_tokens=2)
-        srv.submit(r)
-        srv.run_until_drained()
-        assert r.done and r.status == "ok"
-        return srv
-
-    srv = serve_once()
-    assert srv.compile_stats["artifact"]["compile_source"] == "fresh"
-    srv2 = serve_once()
-    assert srv2.compile_stats["artifact"]["compile_source"] == "artifact"

@@ -195,7 +195,7 @@ def test_double_buffer_ragged_sequence():
                                        rtol=1e-4, atol=1e-4,
                                        err_msg=f"step {s} op {n}")
     # ragged nnz produced more than one capacity bucket for the fused unit
-    # (private pool keys are ((executor tag, unit), bucket))
+    # (pool keys are (unit, bucket))
     assert len({k[1] for k in ex.pool._entries}) >= 2
 
 
@@ -226,102 +226,10 @@ def test_interleaved_submit_and_step_keep_slots_safe():
                                    err_msg=f"stale submit clobbered {n}")
 
 
-def test_pipeline_group_shares_pool_and_accounts_in_flight():
-    """Two different compiled programs joined by pipeline_group: shared
-    staging rings (same-shaped buffers pool across programs), per-program
-    in-flight accounting, and numerics identical to standalone executors."""
-    from repro.core.executor import pipeline_group
-    prog_a = EmbeddingProgram("pg-a", (
-        ("a1", EmbeddingOp("sls", 6, 12, 8, avg_lookups=2)),
-        ("a2", EmbeddingOp("sls", 5, 9, 8, avg_lookups=2)),
-    ))
-    prog_b = EmbeddingProgram("pg-b", (
-        ("b1", EmbeddingOp("sls", 6, 12, 8, avg_lookups=2)),
-    ))
-    ex_a = ProgramExecutor(compile_program(prog_a, "O3", vlen=4,
-                                           use_cache=False), depth=2)
-    ex_b = ProgramExecutor(compile_program(prog_b, "O3", vlen=4,
-                                           use_cache=False), depth=2)
-    grp = pipeline_group([ex_a, ex_b])
-    assert ex_a.pool is grp.pool and ex_b.pool is grp.pool
-    assert grp.pool.shared
-    base_a = make_program_inputs(prog_a, seed=0)
-    base_b = make_program_inputs(prog_b, seed=1)
-    handles, wants = [], []
-    for seed in range(4):
-        ins_a = _step_inputs(prog_a, 200 + seed, base_a)
-        ins_b = _step_inputs(prog_b, 300 + seed, base_b)
-        handles.append(grp.submit("pg-a", ins_a))
-        handles.append(grp.submit("pg-b", ins_b))
-        wants.append(program_reference(prog_a, ins_a))
-        wants.append(program_reference(prog_b, ins_b))
-    gs = grp.group_stats()
-    assert gs["submitted"] == {"pg-a": 4, "pg-b": 4}
-    assert max(gs["max_in_flight"].values()) >= 2  # overlap across programs
-    for h, want in zip(handles, wants):
-        got = h.result()
-        for n in want:
-            np.testing.assert_allclose(np.asarray(got[n]), want[n],
-                                       rtol=1e-4, atol=1e-4, err_msg=n)
-    grp.drain()
-    assert grp.group_stats()["in_flight"] == {"pg-a": 0, "pg-b": 0}
-    # the same-shaped fused CSR staging of the two programs pooled: fewer
-    # entries than two private pools would allocate, and cross-program
-    # reuse shows up as hits
-    assert grp.pool.stats["hits"] > 0
-    assert grp.pool.stats["forced_drains"] == 0
-
-
-def test_pipeline_group_submit_wave_coalesced_dispatch():
-    """submit_wave co-schedules the wave's programs: the members' gather
-    streams ride one batched transfer and their dispatches trace into a
-    single jitted wave executable, cached across waves (no per-wave
-    retrace).  Outputs must match the members' own step() path exactly."""
-    from repro.core.executor import pipeline_group
-    prog_a = EmbeddingProgram("wv-a", (
-        ("g1", EmbeddingOp("gather", 16, 64, 8)),
-        ("g2", EmbeddingOp("gather", 16, 64, 8)),
-    ))
-    prog_b = EmbeddingProgram("wv-b", (
-        ("g3", EmbeddingOp("gather", 24, 32, 8)),
-    ))
-    pres_a = compile_program(prog_a, "O3", use_cache=False)
-    pres_b = compile_program(prog_b, "O3", use_cache=False)
-    grp = pipeline_group([ProgramExecutor(pres_a, backend="jax", depth=2),
-                          ProgramExecutor(pres_b, backend="jax", depth=2)])
-    ref_a = ProgramExecutor(pres_a, backend="jax", depth=2)
-    ref_b = ProgramExecutor(pres_b, backend="jax", depth=2)
-    base_a = make_program_inputs(prog_a, seed=0)
-    base_b = make_program_inputs(prog_b, seed=1)
-    rng = np.random.default_rng(2)
-    for wave in range(5):
-        ins_a = {n: {**base_a[n],
-                     "idxs": rng.integers(0, 64, 16).astype(np.int32)}
-                 for n in ("g1", "g2")}
-        ins_b = {"g3": {**base_b["g3"],
-                        "idxs": rng.integers(0, 32, 24).astype(np.int32)}}
-        handles = grp.submit_wave({"wv-a": ins_a, "wv-b": ins_b})
-        want_a, want_b = ref_a.step(ins_a), ref_b.step(ins_b)
-        got_a, got_b = handles["wv-a"].result(), handles["wv-b"].result()
-        for n in want_a:
-            np.testing.assert_array_equal(np.asarray(got_a[n]),
-                                          np.asarray(want_a[n]), err_msg=n)
-        np.testing.assert_array_equal(np.asarray(got_b["g3"]),
-                                      np.asarray(want_b["g3"]))
-    gs = grp.group_stats()
-    assert gs["waves"] == 5
-    assert gs["batched_arrays"] > 0           # streams rode the batch
-    assert gs["submitted"] == {"wv-a": 5, "wv-b": 5}
-    # steady state never retraces: one cached wave executable
-    assert len(grp._wave_fns) == 1
-    grp.drain()
-    assert grp.group_stats()["in_flight"] == {"wv-a": 0, "wv-b": 0}
-
-
 def test_buffer_pool_grows_instead_of_draining_when_shared():
-    """A shared pool must not serialize one program on another: exhausting
-    every slot of a ring grows it (up to max_slots) rather than draining an
-    in-flight owner."""
+    """Exhausting every slot of a ring grows it (up to max_slots) rather
+    than draining an in-flight owner; only a full ring drains its oldest
+    owner."""
     from repro.core.executor import BufferPool
 
     class _FakeHandle:
@@ -332,9 +240,9 @@ def test_buffer_pool_grows_instead_of_draining_when_shared():
             self.done = True
             _FakeHandle.drained += 1
 
-    pool = BufferPool(n_slots=2, max_slots=3, shared=True)
+    pool = BufferPool(n_slots=2, max_slots=3)
     spec = {"idxs": ((8,), np.int32)}
-    key = pool.key_for(None, (), spec)
+    key = ("owner", ())
     taken = []
     for _ in range(3):
         entry, turn, _ = pool.acquire(key, spec)

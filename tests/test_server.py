@@ -493,6 +493,29 @@ def test_wave_step_writes_cache_in_place(kv):
     assert t8 - t2 < (c8 - c2) / 4, (t2, t8, c2, c8)
 
 
+@pytest.mark.parametrize("arch", ["stablelm-3b", "qwen3-moe-235b-a22b",
+                                  "deepseek-v2-lite-16b"])
+def test_decode_server_compiles_no_embedding_program(arch):
+    """The server embeds tokens inside the jitted wave: building it and
+    serving a few waves compiles no Ember program and builds no
+    executor."""
+    from repro.core.executor import executor_cache_stats
+    from repro.core.pipeline import compile_cache_stats
+    cfg = get_reduced(arch)
+    lm = LM(cfg)
+    params = lm.init(jax.random.PRNGKey(0))
+    before = (compile_cache_stats(), executor_cache_stats())
+    srv = DecodeServer(lm, params, batch_slots=2, max_len=32,
+                       prefill_chunk=4)
+    rng = np.random.default_rng(4)
+    for n in (5, 2):
+        srv.submit(_req(rng.integers(0, cfg.vocab_size, n),
+                        max_new_tokens=2))
+    srv.run_until_drained()
+    assert srv.serve_stats["finished"] == 2
+    assert (compile_cache_stats(), executor_cache_stats()) == before
+
+
 # ---------------------------------------------------------------------------
 # Staggered admission / slot isolation (real LM, through the server)
 # ---------------------------------------------------------------------------

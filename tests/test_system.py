@@ -81,13 +81,6 @@ def test_decode_server_drains(tmp_path):
     srv.run_until_drained()
     assert all(r.done for r in reqs)
     assert all(len(r.out) == 5 for r in reqs)
-    # the compiled access side is observable through compile_stats
-    aps = srv.compile_stats["access_plans"]
-    assert aps["units"] >= 1 and aps["shards"] == srv.emb_executor.shards
-    assert aps["plan_build_s"] >= 0
-    for k in ("hot_rows", "hot_slab_bytes", "exchange_index_bytes",
-              "exchange_index_bytes_est", "exchange_savings_bytes"):
-        assert k in aps
 
 
 def test_elastic_checkpoint_reshard(tmp_path):
