@@ -6,6 +6,13 @@ the rows a step touches into a ring of VMEM buffers, each guarded by its
 own DMA semaphore, and the vector unit reads them as they land.  Nothing
 copies or relays out the whole table per call.
 
+How deep the ring is depends on the kernel.  The SLS row stream sizes its
+queue from the ring's bytes (:func:`stream_depth`): as many slots as fit
+``RING_BYTES``, so a 512-byte float32 row gets a deep queue and a 16-row
+bf16 sublane group a shallow one; it never drains between output tiles
+(``kernels/sls.py``).  The gather and FusedMM kernels keep a queue of
+``DEPTH`` rows per grid step.
+
 Two layout facts of the TPU shape this module:
 
 * A DMA source must be whole tiles of the table's HBM layout.  A 32-bit
@@ -26,8 +33,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: row copies in flight per kernel (the DMA queue depth)
+#: row copies in flight in the gather and FusedMM rings, and the fewest
+#: the SLS stream keeps
 DEPTH = 8
+#: VMEM bytes of the SLS stream's ring; its depth is the slots that fit
+RING_BYTES = 1 << 15
 #: SMEM bytes one launch may spend on scalar-prefetched streams (of 1 MiB;
 #: the rest is the compiler's own)
 SMEM_BUDGET = 3 << 18
@@ -46,13 +56,27 @@ def row_granule(dtype, width: int, interpret: bool) -> int:
     return sublane_rows(dtype)
 
 
-def ring_scratch(granule: int, col_tile: int, dtype) -> list:
-    return [pltpu.VMEM((DEPTH, granule, col_tile), dtype),
-            pltpu.SemaphoreType.DMA((DEPTH,))]
+def slot_bytes(granule: int, col_tile: int, dtype) -> int:
+    """VMEM bytes of one ring slot: ``granule`` rows of ``col_tile`` lanes,
+    padded to whole 128-lane tiles as VMEM lays them out."""
+    return granule * -(-col_tile // 128) * 128 * np.dtype(dtype).itemsize
+
+
+def stream_depth(granule: int, col_tile: int, dtype) -> int:
+    """Slots of the SLS ring: the largest power of two whose slots fit
+    ``RING_BYTES`` (a power of two, so a slot is a mask of the lookup's
+    position), and never fewer than ``DEPTH``."""
+    fit = RING_BYTES // slot_bytes(granule, col_tile, dtype)
+    return max(DEPTH, 1 << max(fit.bit_length() - 1, 0))
+
+
+def ring_scratch(granule: int, col_tile: int, dtype, depth: int = DEPTH):
+    return [pltpu.VMEM((depth, granule, col_tile), dtype),
+            pltpu.SemaphoreType.DMA((depth,))]
 
 
 class RowRing:
-    """``DEPTH`` VMEM slots, each filled by one row DMA from ``table``."""
+    """VMEM slots, each filled by one row DMA from ``table``."""
 
     def __init__(self, table, buf, sems, *, granule: int, col, col_tile: int):
         self.table, self.buf, self.sems = table, buf, sems
@@ -102,15 +126,6 @@ def pad_ptrs(ptrs, num_segments: int, seg_tile: int):
         return ptrs, padded
     tail = jnp.broadcast_to(ptrs[num_segments], (padded - num_segments,))
     return jnp.concatenate([ptrs, tail]), padded
-
-
-def local_segment(ptrs, s0, p, seg_tile: int):
-    """Index within the tile ``[s0, s0+seg_tile)`` of the segment holding
-    lookup position ``p`` (scalar compares on the access unit)."""
-    k = jnp.int32(0)
-    for i in range(1, seg_tile):
-        k = k + (ptrs[s0 + i] <= p).astype(jnp.int32)
-    return k
 
 
 def csr_chunks(ptrs, idxs, per_lookup: tuple, per_segment: tuple, *,

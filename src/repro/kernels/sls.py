@@ -4,20 +4,27 @@ TPU-native realization of the Ember-compiled DLC program (DESIGN.md §2):
 
 * **access unit** ≙ the scalar core: the CSR ``ptrs``/``idxs`` arrays are
   scalar-prefetched into SMEM, and the kernel computes *which table row to
-  DMA next* and issues that copy ``DEPTH`` lookups ahead of compute —
-  running ahead exactly like the TMU traversal engine;
+  DMA next* and issues that copy a queue's depth of lookups ahead of
+  compute — running ahead exactly like the TMU traversal engine.  An issue
+  cursor carries the segment of the next row to issue, and finds the next
+  one by stepping over the segments the row stream has passed;
 * **queues** ≙ the :class:`~repro.kernels.rowdma.RowRing` of VMEM row
-  buffers: while the VPU reduces lookup ``q``, the copies of the next
-  lookups are in flight;
+  buffers: while the VPU reduces lookup ``p``, the copies of the next
+  lookups are in flight.  The queue is as deep as the slots that fit the
+  ring's bytes (``rowdma.stream_depth``): 64 of a 512-byte float32 row, 8
+  of a 16-row bf16 group (the gather and FusedMM kernels keep their
+  8-deep ring, ``rowdma.DEPTH``);
 * **execute unit** ≙ the kernel body (vector ⊕/⊗ on 8×128 vregs).
 
 The table stays in HBM and only the rows a bag touches are copied.  The
-grid is ``(segment tiles, column tiles)``: one step owns ``seg_tile``
-consecutive segments — a sublane-aligned output block — whose lookups are
-contiguous in CSR order, and streams them through the ring with no padded
-grid slots.  The compiler's KernelPlan chooses the column tile (``vlen`` →
-lane tile; without bufferization the kernel walks 128-lane column tiles,
-one DMA descriptor per tile ≙ more queue traffic).
+grid walks column tiles; one step streams every lookup of the launch in
+CSR order through the ring, which stays full from the first lookup to the
+last: an output tile of ``seg_tile`` segments, once pooled, is copied out
+to HBM from a two-slot staging buffer while the stream runs on.  Each
+segment's rows are added in CSR order into a float32 accumulator.  The
+compiler's KernelPlan chooses the column tile (``vlen`` → lane tile;
+without bufferization the kernel walks 128-lane column tiles, one DMA
+descriptor per tile ≙ more queue traffic).
 """
 from __future__ import annotations
 
@@ -29,87 +36,113 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .rowdma import (DEPTH, RowRing, csr_chunks, local_segment, pad_ptrs,
-                     place_row, ring_scratch, row_granule, sublane_rows)
+from .rowdma import (RowRing, csr_chunks, pad_ptrs, place_row, ring_scratch,
+                     row_granule, stream_depth, sublane_rows)
 
 _INIT = {"add": 0.0, "max": -jnp.inf, "min": jnp.inf}
 _COMBINE = {"add": jnp.add, "max": jnp.maximum, "min": jnp.minimum}
 
 
-def _sls_kernel(ptrs, idxs, seg_base, table, weights, out, buf, sems, *,
-                add_op, mul_op, weighted, seg_tile, col_tile, granule):
-    """One grid step = segments ``[s0, s0+seg_tile)`` × one column tile."""
-    s0 = pl.program_id(0) * seg_tile
-    ring = RowRing(table, buf, sems, granule=granule, col_tile=col_tile,
-                   col=pl.multiple_of(pl.program_id(1) * col_tile, col_tile))
-    p0 = ptrs[s0]
-    total = ptrs[s0 + seg_tile] - p0
+def _sls_kernel(ptrs, idxs, seg_base, table, weights, out, buf, sems, stage,
+                flushes, *, add_op, mul_op, weighted, seg_tile, col_tile,
+                granule, depth, num_tiles):
+    """One grid step = every segment of the launch × one column tile."""
+    col = pl.multiple_of(pl.program_id(0) * col_tile, col_tile)
+    ring = RowRing(table, buf, sems, granule=granule, col=col,
+                   col_tile=col_tile)
+    total = ptrs[num_tiles * seg_tile]
     last_base = seg_base.shape[0] - 1
 
-    def row_in(seg, q):
+    def row_at(seg, p):
         # fused multi-table rebase onto the stacked table (§ program fusion)
-        return idxs[p0 + q] + seg_base[jnp.minimum(seg, last_base)]
+        return idxs[p] + seg_base[jnp.minimum(seg, last_base)]
 
-    def row_of(q):                  # a lookup of a segment not yet reached
-        return row_in(s0 + local_segment(ptrs, s0, p0 + q, seg_tile), q)
+    def issue(p, seg):
+        """Start the copy of lookup ``p``, if there is one, and return the
+        segment holding it (the last lookup's, past the end), walked to
+        from ``seg``, the segment of an earlier lookup."""
+        at = jnp.minimum(p, total - 1)
+        seg = jax.lax.while_loop(lambda s: ptrs[s + 1] <= at,
+                                 lambda s: s + 1, seg)
 
-    def prime(q, carry):                        # fill the queue
-        @pl.when(q < total)
+        @pl.when(p < total)
         def _start():
-            ring.start(q, row_of(q))
-        return carry
+            ring.start(p & (depth - 1), row_at(seg, p))
+        return seg
 
-    jax.lax.fori_loop(0, DEPTH, prime, 0)
+    def flush(slot, s0):
+        return pltpu.make_async_copy(
+            stage.at[slot], out.at[pl.ds(s0, seg_tile), pl.ds(col, col_tile)],
+            flushes.at[slot])
 
-    def segment(k, tile):
-        def lookup(q, acc):
-            slot = q % DEPTH
-            ring.wait(slot)
-            row = ring.read(slot, row_in(s0 + k, q))
-            if weighted:
-                w = weights[p0 + q]
-                row = row * w if mul_op == "mul" else row + w
-            acc = _COMBINE[add_op](acc, row)
+    def tile(t, seg):
+        s0 = pl.multiple_of(t * seg_tile, seg_tile)
 
-            @pl.when(q + DEPTH < total)         # refill the freed slot
-            def _next():
-                ring.start(slot, row_of(q + DEPTH))
-            return acc
+        def segment(k, carry):
+            rows, seg = carry
 
-        beg = ptrs[s0 + k] - p0
-        end = ptrs[s0 + k + 1] - p0
-        acc = jax.lax.fori_loop(
-            beg, end, lookup,
-            jnp.full((1, col_tile), _INIT[add_op], jnp.float32))
-        if add_op != "add":
-            # SLS convention: empty segments produce 0 even for max/min
-            acc = jnp.where(end > beg, acc, 0.0)
-        return place_row(tile, k, acc)
+            def lookup(p, carry):
+                acc, seg = carry
+                slot = p & (depth - 1)
+                ring.wait(slot)
+                row = ring.read(slot, row_at(s0 + k, p) if granule > 1
+                                else None)
+                if weighted:
+                    w = weights[p]
+                    row = row * w if mul_op == "mul" else row + w
+                return _COMBINE[add_op](acc, row), issue(p + depth, seg)
 
-    out[...] = jax.lax.fori_loop(
-        0, seg_tile, segment,
-        jnp.zeros((seg_tile, col_tile), jnp.float32)).astype(out.dtype)
+            beg, end = ptrs[s0 + k], ptrs[s0 + k + 1]
+            acc, seg = jax.lax.fori_loop(
+                beg, end, lookup,
+                (jnp.full((1, col_tile), _INIT[add_op], jnp.float32), seg))
+            if add_op != "add":
+                # SLS convention: empty segments produce 0 even for max/min
+                acc = jnp.where(end > beg, acc, 0.0)
+            return place_row(rows, k, acc), seg
+
+        rows, seg = jax.lax.fori_loop(
+            0, seg_tile, segment,
+            (jnp.zeros((seg_tile, col_tile), jnp.float32), seg))
+        slot = t % 2
+
+        @pl.when(t >= 2)                # the slot's last tile has landed
+        def _free():
+            flush(slot, 0).wait()
+        stage[slot] = rows.astype(stage.dtype)
+        flush(slot, s0).start()
+        return seg
+
+    # fill the queue, then stream the launch's lookups through it
+    seg = jax.lax.fori_loop(0, jnp.minimum(depth, total), issue, 0)
+    jax.lax.fori_loop(0, num_tiles, tile, seg)
+    for t in range(max(num_tiles - 2, 0), num_tiles):
+        flush(t % 2, 0).wait()
 
 
 def _sls_launch(table, ptrs, idxs, seg_base, weights, *, num_segments,
                 add_op, mul_op, weighted, col_tile, granule, interpret):
     seg_tile = sublane_rows(table.dtype)
     ptrs, padded = pad_ptrs(ptrs, num_segments, seg_tile)
+    depth = stream_depth(granule, col_tile, table.dtype)
     kernel = functools.partial(
         _sls_kernel, add_op=add_op, mul_op=mul_op, weighted=weighted,
-        seg_tile=seg_tile, col_tile=col_tile, granule=granule)
+        seg_tile=seg_tile, col_tile=col_tile, granule=granule, depth=depth,
+        num_tiles=padded // seg_tile)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(padded // seg_tile, table.shape[1] // col_tile),
+            grid=(table.shape[1] // col_tile,),
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),       # table stays in HBM
                 pl.BlockSpec(memory_space=pltpu.SMEM),   # weights (scalar)
             ],
-            out_specs=pl.BlockSpec((seg_tile, col_tile),
-                                   lambda s, c, *_: (s, c)),
-            scratch_shapes=ring_scratch(granule, col_tile, table.dtype),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),  # tiles copied out
+            scratch_shapes=ring_scratch(granule, col_tile, table.dtype,
+                                        depth) + [
+                pltpu.VMEM((2, seg_tile, col_tile), table.dtype),
+                pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=jax.ShapeDtypeStruct((padded, table.shape[1]),
                                        table.dtype),

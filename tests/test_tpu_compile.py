@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import rowdma
 from repro.kernels.fusedmm import fusedmm_pallas
 from repro.kernels.gather import block_gather_pallas
 from repro.kernels.sls import sls_pallas
@@ -49,12 +50,39 @@ def _check(text: str, temp: int, table_bytes: int):
     assert temp < TEMP_FRACTION * table_bytes, (temp, table_bytes)
 
 
+def _rings(fn, *shapes):
+    """``(slots, slot_shape, dtype)`` of each kernel's row ring: its first
+    VMEM scratch, one DMA semaphore per slot."""
+    rings = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                refs = [v.aval for v in eqn.params["jaxpr"].invars]
+                rings.append(next(
+                    (buf.shape[0], buf.shape[1:], buf.dtype)
+                    for buf, sems in zip(refs, refs[1:])
+                    if str(buf.memory_space) == "vmem"
+                    and str(sems.memory_space) == "semaphore_mem"
+                    and sems.shape == buf.shape[:1]))
+            for param in eqn.params.values():
+                inner = getattr(param, "jaxpr", param)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    return rings
+
+
 @pytest.mark.parametrize("dtype,rows,batch,nnz,max_lookups,weighted", [
     (jnp.float32, 1 << 22, 2048, 1 << 15, 64, True),
     (jnp.bfloat16, 1 << 20, 2048, 1 << 15, 64, True),
     # chip_smoke.py's fused step: 8 tables of 2^20 rows, 8 x 2048 bags,
     # 337920 lookups — more CSR stream than one launch's SMEM holds
     (jnp.float32, 8 << 20, 8 * 2048, 337920, 128, False),
+    # dlrm-v2.zipf's fused group: 26 tables stacked, 2048 bags each,
+    # 438,272 lookups (bucket 2^19), the 100-hot table's bucket of 128
+    (jnp.float32, 7408088, 26 * 2048, 1 << 19, 128, False),
 ])
 def test_sls_compiles_for_v5e(one_chip, dtype, rows, batch, nnz,
                               max_lookups, weighted):
@@ -73,6 +101,13 @@ def test_sls_compiles_for_v5e(one_chip, dtype, rows, batch, nnz,
         args.append(s((nnz,), dtype))
     text, temp = _compile(step, *args)
     _check(text, temp, rows * width * jnp.dtype(dtype).itemsize)
+    # the row stream's queue is as deep as the ring's bytes allow
+    rings = _rings(step, *args)
+    assert rings
+    for slots, (granule, col_tile), dt in rings:
+        assert slots == rowdma.stream_depth(granule, col_tile, dt)
+        assert slots * rowdma.slot_bytes(granule, col_tile, dt) \
+            <= rowdma.RING_BYTES
 
 
 def test_block_gather_compiles_for_v5e(one_chip):
